@@ -609,8 +609,8 @@ def _lock_regions(ctx: ModuleContext) -> List[ast.AST]:
 @_rule("BCG-LOCK-CALL")
 def rule_lock_call(ctx: ModuleContext) -> Iterable[Finding]:
     """Engine/device calls made while holding a scheduler/collective
-    lock: the inner call can block for a full device batch (seconds on a
-    remote-attached TPU) while every other participant spins on the
+    lock: the inner call can block for a full device batch (seconds)
+    while every other participant spins on the
     lock — and any completion path that needs the same lock deadlocks.
     Copy queue state under the lock, release it, then dispatch
     (bcg_tpu/serve/scheduler.py is the reference shape)."""

@@ -190,8 +190,8 @@ class EngineConfig:
     disable_qwen3_thinking: bool = True
     # Run the layer stack as ONE lax.scan over stacked weights instead of
     # unrolling every layer into the HLO.  Program size becomes O(1) in
-    # depth — required where compile infrastructure rejects 36-layer
-    # unrolled 8B programs (this environment's remote-compile helper).
+    # depth, so an 8B-class compile costs one layer's worth instead of
+    # 36 unrolled copies.
     scan_layers: bool = False
     # Finer suffix-length buckets (adds 1536/3072 rungs): decode streams
     # every allocated suffix slot per step, and measured vote suffixes

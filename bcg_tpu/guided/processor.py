@@ -85,7 +85,7 @@ def compile_schema(
 # schemas in the batch.  The game re-uses the same schema combos every
 # round (honest+Byzantine decide, honest+Byzantine vote); without this
 # cache each LLM call re-uploads the [dfas, states, vocab] table — tens
-# of MB per call, which dominates wall-clock on a remote-attached TPU.
+# of MB of host-to-device traffic per call.
 _table_cache: "OrderedDict[Tuple, Tuple]" = OrderedDict()
 _table_cache_lock = threading.Lock()
 # The stacked tables are tens of MB of device memory each; bound the

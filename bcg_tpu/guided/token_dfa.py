@@ -9,15 +9,20 @@ Two builders:
 
 * C++ (``native/token_dfa.cpp``), compiled on first use with g++ and
   called via ctypes — the production path for 150K-token vocabularies.
-* A vectorised numpy fallback (used automatically when no compiler is
-  available), identical output.
+  The library is named after the hash of its source, so only a binary
+  built from the source on disk is ever loaded.
+* A vectorised numpy builder with identical output, used when no
+  compiler is available.  :func:`builder_in_use` says which one runs,
+  and the first use says so once on stderr.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import tempfile
 from dataclasses import dataclass
 from typing import Optional, Sequence
@@ -101,17 +106,22 @@ def completion_paths(
 
 
 def _load_native() -> Optional[ctypes.CDLL]:
-    """Compile-on-first-use the C++ builder; cache the .so next to the
-    source.  Returns None when no toolchain is available."""
+    """Compile-on-first-use the C++ builder; the .so lives next to the
+    source under a name that carries the source's hash (a stale or
+    foreign binary can never match it).  Returns None when no toolchain
+    is available."""
     global _lib, _lib_tried
     if _lib is not None or _lib_tried:
         return _lib
     _lib_tried = True
     src = os.path.join(_NATIVE_DIR, "token_dfa.cpp")
-    so_path = os.path.join(_NATIVE_DIR, "libtokendfa.so")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so_path = os.path.join(_NATIVE_DIR, f"libtokendfa-{digest}.so")
     tmp_path = None
+    why_not = ""
     try:
-        if not os.path.exists(so_path) or os.path.getmtime(so_path) < os.path.getmtime(src):
+        if not os.path.exists(so_path):
             with tempfile.NamedTemporaryFile(
                 suffix=".so", dir=_NATIVE_DIR, delete=False
             ) as tmp:
@@ -134,15 +144,27 @@ def _load_native() -> Optional[ctypes.CDLL]:
         ]
         lib.build_token_dfa.restype = None
         _lib = lib
-    except (OSError, subprocess.CalledProcessError):
+    except (OSError, subprocess.CalledProcessError) as e:
         _lib = None
+        why_not = f" ({type(e).__name__}: {e})"
     finally:
         if tmp_path is not None:
             try:
                 os.unlink(tmp_path)
             except OSError:
                 pass
+    sys.stderr.write(
+        f"[token_dfa] builder: native ({os.path.basename(so_path)})\n"
+        if _lib is not None
+        else f"[token_dfa] builder: numpy{why_not}\n"
+    )
     return _lib
+
+
+def builder_in_use() -> str:
+    """"native" (the C++ library, built from the source on disk) or
+    "numpy" — resolving the choice on first call."""
+    return "native" if _load_native() is not None else "numpy"
 
 
 def _build_native(char_dfa: CharDFA, token_bytes: Sequence[bytes]) -> Optional[np.ndarray]:

@@ -90,8 +90,8 @@ from bcg_tpu.runtime import envflags
 _SANITIZE_RE = re.compile(r"[^a-z0-9_]")
 
 # Per-entry compile-time histogram bounds (milliseconds).  The ladder
-# resolves both the tiny-test CPU gate's sub-second compiles and a
-# remote 8B boot's minutes-scale first compile.
+# resolves both the tiny-test CPU gate's sub-second compiles and an
+# 8B boot's minutes-scale first compile.
 COMPILE_MS_BOUNDS = (
     1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0, 1000.0, 2500.0,
     5000.0, 10000.0, 30000.0, 60000.0, 120000.0,
@@ -505,8 +505,7 @@ _TRUTHY = ("1", "true", "yes", "on")
 def _parse_flag(raw: Optional[str]) -> Tuple[bool, Optional[str]]:
     """``BCG_TPU_COMPILE_OBS`` dual-mode parse: falsy/unset = off;
     a plain truthy token = counters only; anything else = counters plus
-    the retrace-cause JSONL stream at that path (the BCG_TPU_XLA_CACHE
-    value-or-path idiom)."""
+    the retrace-cause JSONL stream at that path."""
     if raw is None:
         return False, None
     token = raw.strip()

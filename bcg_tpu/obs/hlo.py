@@ -166,8 +166,12 @@ def _step_family(step: Dict[str, int]) -> Dict[str, int]:
 # inflates op counts and proves nothing about the hardware program.
 # jax can, however, cross-LOWER a traced program for the TPU platform on
 # any host (Mosaic kernels serialize into ``tpu_custom_call`` at
-# lowering time; only the final compile needs hardware), so the fused-
-# vs-gather comparison is taken on the TPU StableHLO lowering instead:
+# lowering time).  Lowering never reaches Mosaic — a kernel it accepts
+# can still be refused by the compiler — and the compile needs no
+# hardware either: the installed TPU compiler builds for a DESCRIBED
+# chip (tests/test_tpu_compile.py), which is where the kernels are held
+# to what the chip's compiler accepts.  This census only counts ops, so
+# the fused-vs-gather comparison is taken on the TPU StableHLO lowering:
 # both arms carry the identical transformer skeleton, and the attention
 # inner region is the only difference — N gather/reshape/softmax ops per
 # layer per step versus ONE fused kernel custom-call.  Pre-fusion op

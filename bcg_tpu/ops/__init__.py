@@ -1,1 +1,29 @@
-"""TPU kernels and collective ops: Pallas attention, ring attention."""
+"""TPU kernels and collective ops: Pallas attention, ring attention.
+
+This module holds only the ``impl`` markers the kernels' callers pass
+around, so reading one never imports Pallas.
+"""
+
+from typing import NamedTuple, Optional
+
+from jax.sharding import Mesh
+
+
+class PallasTP(NamedTuple):
+    """Attention ``impl`` marker: the Pallas kernels with every call
+    wrapped in ``jax.shard_map`` over ``mesh``'s ``tp`` axis.  A Mosaic
+    kernel has no SPMD partitioning rule, so under a mesh the plain
+    ``"pallas"`` marker cannot compile; heads are independent, so each
+    device runs the kernel on its own H/tp query heads and Hkv/tp kv
+    heads.  The engine resolves this marker at boot; the transformer
+    passes it through unread."""
+
+    mesh: Mesh
+
+
+def is_pallas(impl) -> bool:
+    return impl == "pallas" or isinstance(impl, PallasTP)
+
+
+def impl_mesh(impl) -> Optional[Mesh]:
+    return impl.mesh if isinstance(impl, PallasTP) else None

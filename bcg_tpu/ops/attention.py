@@ -10,8 +10,12 @@ The reference delegates attention to vLLM's CUDA backends
   einsum attention allocates B*H*T*S f32 scores, which at 10 agents x
   2K context OOMs a single v5e chip.
 * :func:`blockwise_attention` — the same online-softmax algorithm as a
-  pure-JAX ``lax.scan`` over key blocks: memory-bounded everywhere
-  Pallas isn't available (CPU tests, head_dim not lane-aligned).
+  pure-JAX ``lax.scan`` over key blocks: memory-bounded on any backend.
+
+Which one a program runs is the CALLER's decision, made once (the
+engine resolves it at boot from the backend, the head dim and the mesh):
+:func:`flash_attention` always runs the kernel and raises on a geometry
+the kernel cannot take — it never stands in an XLA path for itself.
 
 Both compute softmax(scale * q @ k^T + mask) @ v in f32 and return the
 query dtype.  Layouts match the model code: q [B, T, H, Dh],
@@ -21,15 +25,39 @@ k/v [B, S, Hkv, Dh], mask [B, T, S] (True = attend).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
-from bcg_tpu.parallel.compat import pallas_compiler_params
 
 _NEG_INF = -1e30
+
+
+def shard_heads(kernel, mesh: Mesh, batch: int, head_ranks):
+    """``kernel(*head_operands, mask)`` over per-device head shards.
+    Head operands are ``[B, heads, ...]`` of the given ranks and shard
+    their head axis over ``tp``; the trailing mask is ``[B, rows, S]``
+    with no head axis; the output is ``[B, heads, rows, Dh]``.  Batch shards
+    over ``dp`` when it divides; other mesh axes replicate.  Callers
+    check head divisibility (the engine's boot rule)."""
+    dp = mesh.shape.get("dp", 1)
+    dp_ax = "dp" if dp > 1 and batch % dp == 0 else None
+
+    def heads(rank):
+        return P(dp_ax, "tp", *([None] * (rank - 2)))
+
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=tuple(heads(r) for r in head_ranks)
+        + (P(dp_ax, None, None),),
+        out_specs=heads(4),
+        # pallas_call results carry no varying-axes type.
+        check_vma=False,
+    )
 
 
 # ------------------------------------------------------------------ pallas
@@ -128,7 +156,7 @@ def _pallas_flash(q, k, v, mask, scale, block_q: int, block_kv: int,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, Dh), jnp.float32),  # output accumulator
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -145,20 +173,29 @@ def _pad_to(x, axis: int, multiple: int, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-def flash_attention(q, k, v, mask, scale, block_q: int = 128, block_kv: int = 256):
-    """Pallas flash attention; falls back to :func:`blockwise_attention`
-    off-TPU or when head_dim isn't lane-aligned (tiny test models)."""
-    Dh = q.shape[-1]
-    if jax.default_backend() != "tpu" or Dh % 128 != 0:
-        return blockwise_attention(q, k, v, mask, scale, block_kv=block_kv)
-
-    B, T, H, _ = q.shape
-    S = k.shape[1]
+def flash_attention(q, k, v, mask, scale, block_q: int = 128,
+                    block_kv: int = 256, mesh: Optional[Mesh] = None,
+                    interpret: bool = False):
+    """Pallas flash attention.  ``mesh``: run per ``tp`` head shard
+    (:func:`shard_heads`).  Raises on a head dim the kernel cannot tile —
+    the caller picks :func:`blockwise_attention` for those, by name."""
+    B, T, H, Dh = q.shape
+    if Dh % 128 != 0:
+        raise ValueError(
+            f"flash_attention needs head_dim % 128 == 0, got {Dh}; use "
+            "blockwise_attention (impl='blockwise') for this geometry"
+        )
     qt = _pad_to(q.transpose(0, 2, 1, 3), 2, block_q)
     kt = _pad_to(k.transpose(0, 2, 1, 3), 2, block_kv)
     vt = _pad_to(v.transpose(0, 2, 1, 3), 2, block_kv)
     mp = _pad_to(_pad_to(mask, 1, block_q), 2, block_kv)
-    out = _pallas_flash(qt, kt, vt, mp, scale, block_q, block_kv)
+    kernel = functools.partial(
+        _pallas_flash, scale=scale, block_q=block_q, block_kv=block_kv,
+        interpret=interpret,
+    )
+    if mesh is not None:
+        kernel = shard_heads(kernel, mesh, B, (4, 4, 4))
+    out = kernel(qt, kt, vt, mp)
     return out[:, :, :T].transpose(0, 2, 1, 3)
 
 

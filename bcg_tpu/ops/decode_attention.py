@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bcg_tpu.parallel.compat import pallas_compiler_params
 
 _NEG_INF = -1e30
 
@@ -163,13 +162,26 @@ def _decode_kernel_allheads(
             ).astype(o_ref.dtype)
 
 
-def _quantized_attention(qg, kp, vp, ksp, vsp, mp, scale, block_s, interpret):
+def _quantized_attention(qg, kp, vp, ksp, vsp, mp, scale, block_s, interpret,
+                         mesh=None):
     """Shared pallas_call for the int8 single-step and chunk paths.
 
     qg [B, Hkv, rows, Dh]; kp/vp [B, Hkv, Sp, Dh] int8; scales
     [B, Hkv, Sp]; mp [B, M, Sp] with M == 1 (broadcast) or rows.
-    Returns [B, Hkv, rows, Dh].
+    Returns [B, Hkv, rows, Dh].  ``mesh``: each ``tp`` device runs the
+    kernel on its own Hkv/tp heads (ops/attention.shard_heads) — every
+    operand but the mask is laid out kv-head-major for exactly this.
     """
+    if mesh is not None:
+        from bcg_tpu.ops.attention import shard_heads
+
+        return shard_heads(
+            functools.partial(
+                _quantized_attention, scale=scale, block_s=block_s,
+                interpret=interpret,
+            ),
+            mesh, qg.shape[0], (4, 4, 4, 3, 3),
+        )(qg, kp, vp, ksp, vsp, mp)
     B, Hkv, rows, Dh = qg.shape
     Sp = kp.shape[2]
     M = mp.shape[1]
@@ -197,7 +209,7 @@ def _quantized_attention(qg, kp, vp, ksp, vsp, mp, scale, block_s, interpret):
             pltpu.VMEM((Hkv, rows, 1), jnp.float32),
             pltpu.VMEM((Hkv, rows, Dh), jnp.float32),
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -227,6 +239,7 @@ def decode_attention(
     k_scale=None, v_scale=None,
     block_s=None,
     interpret: bool = False,
+    mesh=None,
 ):
     """q [B, H, Dh], mask [B, S] -> [B, H, Dh].
 
@@ -257,11 +270,16 @@ def decode_attention(
             _pad_s(k_scale, block_s, axis=2),
             _pad_s(v_scale, block_s, axis=2),
             _pad_s(mask, block_s, axis=1)[:, None, :],
-            scale, block_s, interpret,
+            scale, block_s, interpret, mesh,
         )
         if g2 != group:
             out = out[:, :, :group]
         return out.reshape(B, H, Dh)
+    if mesh is not None:
+        raise ValueError(
+            "decode_attention: only the int8 cache layout shards over a "
+            "mesh (the bf16 kernel's head axis is not block-major)"
+        )
     S, Hkv = k.shape[1], k.shape[2]
     kp = _pad_s(k, block_s)
     vp = _pad_s(v, block_s)
@@ -297,7 +315,7 @@ def decode_attention(
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, Dh), jnp.float32),
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -310,6 +328,7 @@ def chunk_decode_attention(
     k_scale=None, v_scale=None,
     block_s=None,
     interpret: bool = False,
+    mesh=None,
 ):
     """Fast-forward chunk decode over the (possibly int8) cache.
 
@@ -347,7 +366,7 @@ def chunk_decode_attention(
             _pad_s(v, block_s, axis=2),
             _pad_s(k_scale, block_s, axis=2),
             _pad_s(v_scale, block_s, axis=2),
-            mp, scale, block_s, interpret,
+            mp, scale, block_s, interpret, mesh,
         )
         out = out.reshape(B, Hkv, K, g2, Dh)
         if g2 != group:
@@ -356,6 +375,11 @@ def chunk_decode_attention(
             out
             .transpose(0, 2, 1, 3, 4)
             .reshape(B, K, H, Dh)
+        )
+    if mesh is not None:
+        raise ValueError(
+            "chunk_decode_attention: only the int8 cache layout shards "
+            "over a mesh"
         )
     Hkv = k.shape[2]
     kp = _pad_s(k, block_s)
@@ -398,7 +422,7 @@ def chunk_decode_attention(
             pltpu.VMEM((K * group, 1), jnp.float32),
             pltpu.VMEM((K * group, Dh), jnp.float32),
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -124,7 +125,9 @@ def _sampler_kernel(
     any_tok = jnp.max(allowed.astype(jnp.float32)) > 0.0
     scaled = logits_ref[0] / temp
     is_eos = vid == eos_id
-    gate = jnp.where(is_eos, eos_ok > 0, allowed)
+    # Boolean algebra, not jnp.where over two i1 vectors: Mosaic lowers
+    # that select through an i8 -> i1 truncation it does not support.
+    gate = (is_eos & (eos_ok > 0)) | (~is_eos & allowed)
     x = jnp.where(gate, scaled, _NEG_INF)
     is_forbid = (vid == forbid) & (forbid >= 0)
     vid_f = vid.astype(jnp.float32)
@@ -235,10 +238,33 @@ def vocab_rows(V: int):
     return Vp, Vp // _LANES
 
 
-def make_fused_sampler(eos_id: int, top_p: float, interpret: bool = False):
+def make_fused_sampler(eos_id: int, top_p: float, interpret: bool = False,
+                       mesh=None):
     """Fused drop-in for ``make_masked_sampler``'s closure — identical
     signature and semantics; greedy rows token-identical, sampled rows
-    distribution-preserving (see module docstring)."""
+    distribution-preserving (see module docstring).
+
+    ``mesh``: the kernel needs a row's WHOLE vocab in VMEM, so under a
+    mesh (where the lm_head leaves logits vocab-sharded over ``tp``, and
+    a bare Mosaic call cannot be partitioned) the call is shard_map'd
+    with the vocab replicated — one all-gather of ``[B, V]`` f32 per
+    step — and rows split over ``dp`` when they divide."""
+
+    def call(B, V):
+        kernel = functools.partial(
+            _sampler_call, eos_id=eos_id, top_p=float(top_p), vocab=V,
+            interpret=interpret,
+        )
+        if mesh is None:
+            return kernel
+        dp = mesh.shape.get("dp", 1)
+        rows = P("dp" if dp > 1 and B % dp == 0 else None)
+        return jax.shard_map(
+            kernel, mesh=mesh,
+            in_specs=(rows, P(), rows, rows, rows, rows), out_specs=rows,
+            # pallas_call results carry no varying-axes type.
+            check_vma=False,
+        )
 
     def masked_sample(logits, states, rng, emitted,
                       tables, accepting, min_budget, dfa_ids,
@@ -275,11 +301,9 @@ def make_fused_sampler(eos_id: int, top_p: float, interpret: bool = False):
             axis=1,
         )[:, None, :]
         meta_f = jnp.stack([safe_temp, u], axis=1)[:, None, :]
-        out = _sampler_call(
+        out = call(B, V)(
             logits3, minb4, meta_i, meta_f,
             dfa_ids.astype(jnp.int32), clamped,
-            eos_id=eos_id, top_p=float(top_p), vocab=V,
-            interpret=interpret,
         )
         tok = out[:, 0, 0]
         any_tok = out[:, 0, 1] > 0

@@ -70,7 +70,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bcg_tpu.parallel.compat import pallas_compiler_params
 
 _NEG_INF = -1e30
 
@@ -508,7 +507,7 @@ def _paged_pallas_attention(qg, entry: Dict, mp, scale, interpret: bool):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, Dh), qg.dtype),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

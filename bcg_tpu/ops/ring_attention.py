@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bcg_tpu.parallel.compat import pvary as _pvary, shard_map
 
 
 def _block_attend(q, k, v, q_pos, k_pos, scale, causal, kv_valid=None):
@@ -111,12 +110,16 @@ def _ring_body(axis_name: str, sp: int, causal: bool, scale: float,
 
     # Initial accumulators must carry the same varying-over-mesh-axes
     # type as the loop outputs (which derive from the sharded inputs and
-    # axis_index) — hence pvary over every axis the inputs are sharded
+    # axis_index) — hence pcast-to-varying over every axis the inputs are sharded
     # on (sp always; plus dp/tp on a composed mesh).
     vary = vary_axes if vary_axes is not None else (axis_name,)
-    m0 = _pvary(jnp.full((B, Hkv, group, Tq), -jnp.inf, jnp.float32), vary)
-    l0 = _pvary(jnp.zeros((B, Hkv, group, Tq), jnp.float32), vary)
-    acc0 = _pvary(jnp.zeros((B, Tq, Hkv, group, Dh), jnp.float32), vary)
+
+    def varying(x):
+        return jax.lax.pcast(x, vary, to="varying")
+
+    m0 = varying(jnp.full((B, Hkv, group, Tq), -jnp.inf, jnp.float32))
+    l0 = varying(jnp.zeros((B, Hkv, group, Tq), jnp.float32))
+    acc0 = varying(jnp.zeros((B, Tq, Hkv, group, Dh), jnp.float32))
     carry0 = (m0, l0, acc0, k0, v0) + ((kv_valid0,) if masked else ())
     out_carry = jax.lax.fori_loop(0, sp, step, carry0)
     m, l, acc = out_carry[0], out_carry[1], out_carry[2]
@@ -240,7 +243,7 @@ def sp_chunk_decode_attention(
         kv_spec = P(dp_ax, axis_name, tp_ax, None)   # [B, S, Hkv, Dh]
         extra_in = ()
         extra_args = ()
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -331,7 +334,7 @@ def ring_attention(
                           kv_valid0=rest[0] if rest else None,
                           vary_axes=vary_axes)
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec,
     )
     args = (q, k, v) + ((kv_valid,) if kv_valid is not None else ())

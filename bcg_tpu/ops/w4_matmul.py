@@ -44,10 +44,12 @@ def _w4_kernel(x_ref, q4_ref, gs_ref, o_ref, *, group, num_groups):
 
     # STATIC Python unroll over g-row groups: the earlier fori_loop
     # carried a traced index into every slice, making them dynamic —
-    # including 1-sublane-row bf16 slices of gs_ref, which the remote
-    # Mosaic compiler crashed on (tpu_compile_helper exit 1) at every
-    # real shape while the single-group tiny case passed.  Static
-    # offsets (all multiples of the 128-row group) lower cleanly; the
+    # including 1-sublane-row bf16 slices of gs_ref, which an earlier
+    # Mosaic crashed on at every real shape.  This static form compiles
+    # for a described v5e under jaxlib 0.9.0 / libtpu 0.0.34 at every
+    # 8B and 14B projection shape, M=10 and 40 (PR 22; compiled, not
+    # yet run on a chip).  Static offsets (all multiples of the 128-row
+    # group) lower cleanly; the
     # unrolled program is ~num_groups x 12 ops (<= ~900 at the 14B
     # w_down strip), well within Mosaic program limits, and the
     # in-kernel contraction still amortizes per-program overhead the
@@ -160,12 +162,11 @@ def w4a16_matmul(x, q4, gscale, block_m: int = 128, interpret: bool = False):
     for s in lead:
         M *= s
     x2 = x.reshape(M, x.shape[-1])
-    # Fallback for unsupported shapes AND for non-TPU backends: the
-    # kernel only lowers on TPU (or in interpret mode), so a direct call
-    # off-TPU must degrade to the XLA dequant path, not crash.
-    if not w4a16_supported(x2.shape, q4.shape, gscale.shape, block_m) or (
-        not interpret and jax.default_backend() != "tpu"
-    ):
+    # Shapes outside the kernel's contract take the XLA dequant path.
+    # The backend is the caller's business (``quantize.dense`` picks the
+    # kernel only on a single TPU device): a direct call off-TPU without
+    # ``interpret`` fails to lower rather than quietly running XLA.
+    if not w4a16_supported(x2.shape, q4.shape, gscale.shape, block_m):
         from bcg_tpu.models.quantize import dequantize_int4
 
         w = dequantize_int4({"q4": q4, "gscale": gscale})

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bcg_tpu.parallel.compat import shard_map
 
 
 def _masked_receive(all_vals: jax.Array, mask_rows: jax.Array) -> jax.Array:
@@ -174,7 +173,7 @@ def exchange_values(
         all_vals = jax.lax.all_gather(local_vals, axis_name, tiled=True)  # [n]
         return _masked_receive(all_vals, mask_rows)
 
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name, None)),
@@ -211,7 +210,7 @@ def exchange_proposals(
         )
         return _masked_receive_matrix(local_rows, mask_rows)
 
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, axis_name), P(axis_name, None)),
@@ -261,7 +260,7 @@ def exchange_values_global(
     # check_rep=False: the trailing all_gather DOES replicate the
     # output over dp, but shard_map's static replication checker cannot
     # see through a tiled gather to prove it.
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name, None)),
@@ -295,7 +294,7 @@ def tally_votes(
             jnp.broadcast_to(half, local_votes.shape),
         )
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis_name),),
         out_specs=(P(axis_name),) * 5,
     )
@@ -349,7 +348,7 @@ def check_consensus_spmd(
             jnp.broadcast_to(agreement, shape),
         )
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=(P(axis_name),) * 3,

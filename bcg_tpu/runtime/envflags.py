@@ -57,12 +57,6 @@ _register(
     "breakdown to stderr.",
 )
 _register(
-    "BCG_TPU_XLA_CACHE", "str", "",
-    "Persistent XLA compilation cache: 'off'/'0'/'none' disables, a "
-    "directory path overrides the default location "
-    "(~/.cache/bcg_tpu_xla; default-on only on TPU backends).",
-)
-_register(
     "BCG_TPU_CHECKPOINT_DIR", "str", None,
     "Root directory searched for local safetensors checkpoints "
     "(models/loader.find_checkpoint_dir).",
@@ -478,10 +472,6 @@ _register(
 _register("BENCH_ROUNDS", "int", 3, "Measured bench rounds.")
 _register("BENCH_WARMUP", "int", 2, "Warmup (compile) rounds before the window.")
 _register("BENCH_CONCURRENCY", "int", 1, "Concurrent games in the bench window.")
-_register(
-    "BENCH_ATTACH_TIMEOUT", "int", 900,
-    "Deadline (s) for the subprocess accelerator-attach probe.",
-)
 _register(
     "BENCH_ATTENTION_IMPL", "str", "auto",
     "Prefill attention kernel override (auto | pallas | xla).",

@@ -346,8 +346,7 @@ class BCGSimulation:
         # rows: decode is weight-bandwidth-bound, so a 3-row retry costs
         # the same device time as the full batch — but the full batch
         # reuses the already-compiled (B, L) decode loop, while a
-        # subset-shaped batch would pay a fresh tens-of-seconds remote
-        # compile (the reference re-batches only failures,
+        # subset-shaped batch would pay a fresh compile (the reference re-batches only failures,
         # main.py:293-341; on TPU static shapes win).
         row_of = {aid: i for i, (aid, _) in enumerate(agent_prompts)}
         for attempt in range(1, MAX_RETRIES + 1):
@@ -1012,6 +1011,8 @@ class BCGSimulation:
                 "entry"
             )
         if reason is None:
+            from bcg_tpu.engine.megaround import MegaroundUnsupported
+
             lo, hi = self.config.game.value_range
             try:
                 self._megaround_plan = self.engine.prepare_megaround(
@@ -1020,7 +1021,9 @@ class BCGSimulation:
                     hi=hi,
                     max_rounds=self.game.max_rounds,
                 )
-            except Exception as exc:  # MegaroundUnsupported, ValueError
+            except (MegaroundUnsupported, ValueError) as exc:
+                # Only the plan's own refusals mean "unavailable"; a
+                # compiler or runtime error is a failure and propagates.
                 reason = f"{type(exc).__name__}: {exc}"
         if self._megaround_plan is None:
             warnings.warn(

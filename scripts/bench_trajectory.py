@@ -5,10 +5,12 @@ trends vs best-known-good.
 ``python scripts/bench_trajectory.py BENCH_r*.json [--threshold 0.7]``
 ``python scripts/bench_trajectory.py <dir>``  (globs BENCH_r*.json)
 
-BENCH_r01-r05 is the cautionary tale this script exists for: three
-accelerator-attach outages (r03-r05) recorded ``vs_baseline: 0.0``
-and read as catastrophic regressions until a human noticed the
-``error`` field.  This script makes the distinction mechanical:
+The cautionary tale this script exists for: three runs that found no
+accelerator once recorded ``value: 0.0`` / ``vs_baseline: 0.0`` with
+rc=0 and read as catastrophic regressions until a human noticed the
+``error`` field (those records are gone from the tree; ``bench.py`` now
+exits non-zero instead of printing such a line).  This script makes the
+distinction mechanical for whatever records remain:
 
 * **outage** — the run measured NOTHING: no parsed payload (driver
   crash, rc != 0 with an empty ``parsed``), an ``error`` field, or a
@@ -72,11 +74,11 @@ class Run:
 def classify(parsed: Optional[dict], rc) -> Tuple[str, str]:
     """(status, note) for one run's parsed payload.
 
-    Outage detection is deliberately belt-and-braces: the checked-in
-    r03-r05 files predate the null-``vs_baseline`` convention (they
-    carry ``vs_baseline: 0.0`` WITH an error field), so an ``error``
-    field alone is already an outage; a null ``vs_baseline`` is the
-    modern marker; an empty payload is a driver crash."""
+    Outage detection is deliberately belt-and-braces: early records
+    predate the null-``vs_baseline`` convention (they carry
+    ``vs_baseline: 0.0`` WITH an error field), so an ``error`` field
+    alone is already an outage; a null ``vs_baseline`` is the later
+    marker; an empty payload is a driver crash."""
     if not parsed:
         return "outage", (
             f"no parsed payload (driver rc={rc}) — run crashed before "

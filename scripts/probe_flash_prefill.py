@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Lower and validate the Pallas flash-prefill kernel on the TPU.
 
-bench_14b's first attempt crashed in its FIRST prefill compile (remote
-helper HTTP 500 / exit 1) with the W4 kernel already disabled, leaving
+bench_14b's first attempt crashed in its FIRST prefill compile (under a
+compiler since replaced) with the W4 kernel already disabled, leaving
 two suspects: the int8 decode kernels at GQA group 5 (now excluded by
 the engine's group guard) and this flash kernel at 14B dims (H=40 —
 untested on hardware; 1B/8B ran H=16/32).  This probe lowers the kernel

@@ -238,7 +238,7 @@ class TestRepoClean:
             os.path.relpath(f, repo_root()).replace(os.sep, "/")
             for f in iter_python_files(paths)
         }
-        assert "scripts/hw_queue_report.py" in scanned
+        assert "scripts/bench_trajectory.py" in scanned
         assert "scripts/scale_sweep.py" in scanned
         assert "scripts/perf_gate.py" in scanned
         assert "scripts/microbench_prefill.py" in scanned

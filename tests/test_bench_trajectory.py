@@ -1,11 +1,12 @@
 """Cross-run bench trajectory report (scripts/bench_trajectory.py).
 
-The acceptance contract, asserted against the REAL checked-in
-BENCH_r01-r05 records: the accelerator-outage runs r03-r05 (and the
-r02 driver crash) classify as OUTAGES — excluded from regression
-analysis — and the script exits 0; a genuine measured drop below the
-threshold exits 2 naming the metric.  Kept bcg_tpu-import-free like
-the script itself.
+The acceptance contract, asserted against the checked-in BENCH_r01/r02
+records plus three synthetic runs in the shape that once entered the
+record as r03-r05 (``value: 0.0`` with rc=0 and an ``error`` field): the
+outage runs (and the r02 driver crash) classify as OUTAGES — excluded
+from regression analysis — and the script exits 0; a genuine measured
+drop below the threshold exits 2 naming the metric.  Kept
+bcg_tpu-import-free like the script itself.
 """
 
 import importlib.util
@@ -18,9 +19,7 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPT = os.path.join(REPO, "scripts", "bench_trajectory.py")
-BENCH_FILES = [
-    os.path.join(REPO, f"BENCH_r0{i}.json") for i in range(1, 6)
-]
+CHECKED_IN = [os.path.join(REPO, f"BENCH_r0{i}.json") for i in (1, 2)]
 
 
 @pytest.fixture(scope="module")
@@ -59,12 +58,30 @@ class TestImportFree:
         assert "bcg_tpu" not in tops
 
 
-class TestCheckedInTrajectory:
-    """The real BENCH_r01-r05 files — the records that motivated the
-    outage-vs-regression distinction."""
+@pytest.fixture
+def bench_files(tmp_path):
+    """r01 (measured) and r02 (driver crash) as checked in, then three
+    no-device runs that printed a zero with rc=0."""
+    outages = [
+        _write(tmp_path / f"BENCH_r0{n}.json", {
+            "n": n, "rc": 0,
+            "parsed": {
+                "metric": "agent_decisions_per_sec", "value": 0.0,
+                "unit": "decisions/sec", "vs_baseline": 0.0,
+                "error": "accelerator attach failed: TimeoutExpired "
+                         "(timeout=900s); backend unavailable",
+            },
+        })
+        for n in (3, 4, 5)
+    ]
+    return CHECKED_IN + outages
 
-    def test_r03_to_r05_classify_as_outages(self, mod):
-        runs = mod.order_runs([mod.load_run(p) for p in BENCH_FILES])
+
+class TestCheckedInTrajectory:
+    """The records that motivated the outage-vs-regression distinction."""
+
+    def test_r03_to_r05_classify_as_outages(self, mod, bench_files):
+        runs = mod.order_runs([mod.load_run(p) for p in bench_files])
         status = {r.label: r.status for r in runs}
         assert status["BENCH_r01"] == "measured"
         assert status["BENCH_r02"] == "outage"  # driver crash, rc=1
@@ -74,11 +91,11 @@ class TestCheckedInTrajectory:
         notes = {r.label: r.note for r in runs}
         assert "accelerator attach failed" in notes["BENCH_r03"]
 
-    def test_no_regression_and_rc_zero(self, mod):
-        runs = mod.order_runs([mod.load_run(p) for p in BENCH_FILES])
+    def test_no_regression_and_rc_zero(self, mod, bench_files):
+        runs = mod.order_runs([mod.load_run(p) for p in bench_files])
         assert mod.find_regressions(runs, threshold=0.7) == []
         proc = subprocess.run(
-            [sys.executable, SCRIPT] + BENCH_FILES,
+            [sys.executable, SCRIPT] + bench_files,
             capture_output=True, text=True, timeout=60, cwd=REPO,
         )
         assert proc.returncode == 0, proc.stderr
@@ -86,8 +103,8 @@ class TestCheckedInTrajectory:
         assert "excluded from regression analysis" in proc.stdout
         assert "REGRESSION" not in proc.stdout
 
-    def test_trend_table_reports_best_known_good(self, mod):
-        runs = mod.order_runs([mod.load_run(p) for p in BENCH_FILES])
+    def test_trend_table_reports_best_known_good(self, mod, bench_files):
+        runs = mod.order_runs([mod.load_run(p) for p in bench_files])
         report = mod.render_report(runs, threshold=0.7)
         assert "decisions_per_sec (best-known-good 7.292)" in report
         assert "100.0% of best" in report

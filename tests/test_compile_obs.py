@@ -578,12 +578,15 @@ class TestBenchHelper:
         monkeypatch.setattr(runtime_metrics, "LAST_COMPILE_OBS", probe)
         assert bench._compile_stats_or_none() is probe
 
-    def test_error_result_attaches_compile_block(self, monkeypatch):
+    def test_result_attaches_compile_block(self, monkeypatch, capsys):
         probe = {"cache_entries": 7}
         monkeypatch.setattr(runtime_metrics, "LAST_COMPILE_OBS", probe)
-        out = bench._error_result(RuntimeError("boom"), retried=False)
-        assert out["compile"] is probe
-        assert out["vs_baseline"] is None
+        for name, val in (("BENCH_BACKEND", "fake"), ("BENCH_ROUNDS", "1"),
+                          ("BENCH_WARMUP", "1")):
+            monkeypatch.setenv(name, val)
+        bench.main()
+        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert out["extra"]["compile"] == probe
 
     def test_flags_are_config_overrides(self):
         for flag in ("BCG_TPU_COMPILE_OBS", "BCG_TPU_PROFILE",

@@ -328,7 +328,7 @@ class TestChunkedPrefill:
     def test_chunk_offsets_share_one_compiled_program(self):
         """The single-shape chunk step (prefill_chunk_at) must serve every
         full-width chunk offset from ONE traced program — per-offset
-        shapes cost minutes of remote compiles on an 8B boot."""
+        shapes cost minutes of compiles on an 8B boot."""
         import dataclasses
 
         from bcg_tpu.config import EngineConfig
@@ -446,6 +446,11 @@ def test_int8_decode_kernel_kill_switch(monkeypatch):
     # the monkeypatched backend makes the selection logic believe it is
     # on TPU (construction only — nothing is generated).
     monkeypatch.setattr(_jax, "default_backend", lambda: "tpu")
+    # ...but must not make it persist this CPU process's compiles in the
+    # TPU backend's in-checkout cache for the rest of the session.
+    monkeypatch.setattr(
+        "bcg_tpu.engine.jax_engine._enable_compilation_cache", lambda: None
+    )
     # A pre-set ambient kill-switch (the escape hatch's own use case)
     # must not poison the default-path assertion.
     monkeypatch.delenv("BCG_TPU_DISABLE_INT8_DECODE_KERNEL", raising=False)
