@@ -98,9 +98,6 @@ _SUFFIX_BUCKETS_FINE = (256, 512, 1024, 1536, 2048, 3072, 4096, 8192)
 # and 1792 rungs).
 _PREFIX_BUCKETS = (128, 256, 512, 768, 1024, 1536, 1792, 2048, 4096, 6144, 8192)
 
-# BCG_TPU_TIMING=1 prints per-call prefill/decode wall times.
-_TIMING = env_flag("BCG_TPU_TIMING")
-
 
 class BudgetError(ValueError):
     """A request whose token budget cannot fit the context window.
@@ -164,6 +161,15 @@ def _ff_decode_slots(max_new: int) -> int:
     from bcg_tpu.guided.processor import FF_CHUNK
 
     return (3 * max_new) // 2 + 2 * FF_CHUNK
+
+
+def _named(name: str, fn, **bound):
+    """``partial(fn, **bound)`` under a name: jax names a jitted program
+    after its function's ``__name__``, and a bare partial has none (the
+    profiler then shows ``jit__unknown`` for every such program)."""
+    named = partial(fn, **bound)
+    named.__name__ = name
+    return named
 
 
 def _pad_batch(real_B: int) -> int:
@@ -238,9 +244,9 @@ class JaxEngine(InferenceEngine):
         # recorded phase (config validation, tokenizer) must not leave a
         # previous attempt's breakdown to be misattributed.  Each phase
         # records wall time + allocator readings, survives a mid-phase
-        # OOM (recorded `failed`), and is printed under BCG_TPU_TIMING /
-        # attached to bench JSON — so the next 14B boot failure names
-        # its phase instead of dying as a bare RESOURCE_EXHAUSTED.
+        # OOM (recorded `failed`), and is a `boot.<phase>` span of the
+        # tracer / attached to bench JSON — so the next 14B boot failure
+        # names its phase instead of dying as a bare RESOURCE_EXHAUSTED.
         from bcg_tpu.runtime.metrics import BootPhaseRecorder
 
         self._boot = BootPhaseRecorder()
@@ -797,12 +803,16 @@ class JaxEngine(InferenceEngine):
 
         # jit entry points (shape-polymorphic via jax.jit's trace cache).
         self._prefill_impl = self._kernel_impl(self.attention_impl)
+        # Named after their obs_hlo census entries: every program of
+        # the prefill family carries "prefill" in the profiler's trace.
         self._prefill = jax.jit(
-            partial(prefill, spec=self.spec, impl=self._prefill_impl),
+            _named("prefill", prefill, spec=self.spec,
+                   impl=self._prefill_impl),
             donate_argnames=("cache",),
         )
         self._prefill_suffix = jax.jit(
-            partial(prefill_with_prefix, spec=self.spec, impl=self._prefill_impl),
+            _named("prefill_suffix", prefill_with_prefix, spec=self.spec,
+                   impl=self._prefill_impl),
             donate_argnames=("cache",),
         )
         # Sequence-parallel full-prompt prefill (ring attention over the
@@ -834,13 +844,14 @@ class JaxEngine(InferenceEngine):
             from bcg_tpu.models.transformer import prefill_sp
 
             self._prefill_sp = jax.jit(
-                partial(prefill_sp, spec=self.spec, mesh=mesh,
-                        impl=self._prefill_impl),
+                _named("prefill_sp", prefill_sp, spec=self.spec, mesh=mesh,
+                       impl=self._prefill_impl),
                 donate_argnames=("cache",),
             )
         self._prefill_chunk_at = jax.jit(
-            partial(
-                prefill_chunk_at, spec=self.spec, impl=self._prefill_impl,
+            _named(
+                "prefill_chunk", prefill_chunk_at, spec=self.spec,
+                impl=self._prefill_impl,
                 # Chunked prefill is the LARGE size class's default; under
                 # sp it must shard, not bypass (transformer.prefill_chunk_at
                 # ring branch — the chunk attends the whole sharded cache).
@@ -1071,15 +1082,15 @@ class JaxEngine(InferenceEngine):
             # (see _paged_scratch_blocks).
             self._paged_scratch_blocks = self._paged_build_scratch_blocks()
             self._prefill_paged = jax.jit(
-                partial(prefill_paged, spec=self.spec,
-                        impl=self._prefill_impl),
+                _named("prefill_paged", prefill_paged, spec=self.spec,
+                       impl=self._prefill_impl),
                 donate_argnames=("cache",),
             )
             from bcg_tpu.models.transformer import prefill_paged_chunk_at
 
             self._prefill_paged_chunk_at = jax.jit(
-                partial(prefill_paged_chunk_at, spec=self.spec,
-                        impl=self._prefill_impl),
+                _named("prefill_paged_chunk", prefill_paged_chunk_at,
+                       spec=self.spec, impl=self._prefill_impl),
                 donate_argnames=("cache",),
             )
         # Telemetry endpoint (BCG_TPU_METRICS_PORT) + fleet metric-shard
@@ -1098,23 +1109,6 @@ class JaxEngine(InferenceEngine):
         from bcg_tpu.runtime import metrics as _boot_metrics
 
         _boot_metrics.publish_sampler(self.sampler_stats())
-        if _TIMING and self.boot_phases:
-            import sys as _sys
-
-            # stderr, not stdout: bench.py's stdout is the driver's
-            # single JSON line and must stay parseable under TIMING.
-            print(
-                "[engine] boot phases: " + "; ".join(
-                    f"{name}={p.get('seconds', 0):.2f}s"
-                    + (
-                        f" peak={p['peak_bytes_in_use'] / 1e9:.2f}GB"
-                        if p.get("peak_bytes_in_use") else ""
-                    )
-                    for name, p in self.boot_phases.items()
-                ),
-                flush=True, file=_sys.stderr,
-            )
-
     # ------------------------------------------------------------- tokenizing
 
     def _encode_leftpad(
@@ -2404,14 +2398,20 @@ class JaxEngine(InferenceEngine):
         real_B, B, parts, schemas, temps, budgets = _pad_rows(
             parts, schemas, temps, budgets, multiple=mult
         )
-        guides = [
-            compile_schema(
-                s, self._token_bytes, vocab_id=self.tokenizer.vocab_id,
-                compact=getattr(self.config, "guided_compact_json", False),
+        with obs_tracer.span("engine.guides", args={"rows": B}):
+            built = obs_counters.value("engine.guides.built")
+            guides = [
+                compile_schema(
+                    s, self._token_bytes, vocab_id=self.tokenizer.vocab_id,
+                    compact=getattr(self.config, "guided_compact_json", False),
+                )
+                for s in schemas
+            ]
+            batch = GuidedBatch(guides)
+            obs_tracer.annotate(
+                schemas=batch.num_unique,
+                built=obs_counters.value("engine.guides.built") - built,
             )
-            for s in schemas
-        ]
-        batch = GuidedBatch(guides)
         sig = (batch.num_unique, batch.tables.shape[1], batch.tables.shape[2])
         return self._decode_batch(
             parts, batch, sig, real_B, temps, budgets, top_p
@@ -2775,10 +2775,11 @@ class JaxEngine(InferenceEngine):
                 # Block-paged path: radix-shared prefix blocks + private
                 # suffix/decode blocks per row; the pool rides the jit
                 # calls via donation and is re-adopted after each.
-                (tokens, valid, Ls, cache, prefix_valid, prefix_lens,
-                 prefix_toks, P, S, _tbl) = self._prepare_paged_batch(
-                    parts, budgets, decode_slots
-                )
+                with obs_tracer.span("engine.tokenize"):
+                    (tokens, valid, Ls, cache, prefix_valid, prefix_lens,
+                     prefix_toks, P, S, _tbl) = self._prepare_paged_batch(
+                        parts, budgets, decode_slots
+                    )
                 self._paged_dirty = True
                 # time_block: a NEW prefill signature's dispatch pays
                 # trace+compile synchronously inside this call; the
@@ -2797,7 +2798,10 @@ class JaxEngine(InferenceEngine):
                 valid_mask[:, P:L] = valid
                 prompt_lens = (prefix_lens + valid.sum(axis=1)).astype(np.int32)
             elif self.prefix_caching and self._prefix_safe and all(p for p, _, _ in parts):
-                prepped = self._prepare_prefixed_batch(parts, budgets, decode_slots)
+                with obs_tracer.span("engine.tokenize"):
+                    prepped = self._prepare_prefixed_batch(
+                        parts, budgets, decode_slots
+                    )
                 if prepped is None:
                     self.prefix_fallbacks += 1
                     if not self._prefix_fallback_warned:
@@ -2830,8 +2834,11 @@ class JaxEngine(InferenceEngine):
                 prompt_lens = (prefix_lens + valid.sum(axis=1)).astype(np.int32)
             elif not paged:
                 prefix_toks = None
-                full_prompts = [p + c + t for p, c, t in parts]
-                tokens, valid, L = self._prepare_batch(full_prompts, budgets)
+                with obs_tracer.span("engine.tokenize"):
+                    full_prompts = [p + c + t for p, c, t in parts]
+                    tokens, valid, L = self._prepare_batch(
+                        full_prompts, budgets
+                    )
                 S = L + decode_slots
                 S += (-S) % self._kv_align  # see _kv_align
                 cache = self._init_cache_sharded(B, S)
@@ -2911,6 +2918,16 @@ class JaxEngine(InferenceEngine):
             # few ms against multi-hundred-ms phases).
             obs_hostsync.note("prefill_barrier", entry="prefill")
             first_logits.block_until_ready()
+            cached = prepped is not None or (paged and P)
+            window = Ls if (prepped is not None or paged) else L
+            chunk = self.prefill_chunk
+            obs_tracer.annotate(
+                prompt_window=window, cache_len=S,
+                chunks=-(-window // chunk) if chunk and window > chunk else 1,
+                prompt_max=int(prompt_lens.max()),
+                prefix="hit" if cached else "miss",
+                prefix_fallbacks=self.prefix_fallbacks,
+            )
         t1 = time.perf_counter()
 
         self._key, sub = jax.random.split(self._key)
@@ -3062,6 +3079,11 @@ class JaxEngine(InferenceEngine):
             del _cache_out  # dense: dropped immediately (aliasing only)
             obs_hostsync.note("decode_readback", entry=loop_entry)
             out_np = np.asarray(out)
+            # Decode-loop iterations of this call (each is one weight
+            # pass — the wall-clock unit of the decode phase).
+            obs_hostsync.note("steps_readback", entry=loop_entry)
+            steps = int(steps)
+            obs_tracer.annotate(steps=steps)
         t2 = time.perf_counter()
         if not self._first_call_recorded:
             # Boot breakdown's final phase: the first serving call pays
@@ -3069,18 +3091,15 @@ class JaxEngine(InferenceEngine):
             # execute) — recorded so a compile-time OOM names itself.
             self._boot.note("first_compile", t2 - t0)
             self._first_call_recorded = True
-        # Observability: decode-loop iterations of the last call (each is
-        # one weight pass — the wall-clock unit of the decode phase).
-        obs_hostsync.note("steps_readback", entry=loop_entry)
-        self.last_decode_steps = int(steps)
-        self.total_decode_steps += int(steps)
+        self.last_decode_steps = steps
+        self.total_decode_steps += steps
         if self._sampler_loop_impl != "xla":
             # Fused-kernel invocations: one sampler program per loop
             # iteration.  Keys created only when the kernel actually
             # ran, so an xla-sampler engine's counter namespace stays
             # byte-identical to HEAD's.
-            self._sampler_fused_calls += int(steps)
-            obs_counters.inc("engine.sampler.fused_calls", int(steps))
+            self._sampler_fused_calls += steps
+            obs_counters.inc("engine.sampler.fused_calls", steps)
         if use_spec:
             # Draft acceptance over REAL rows only (padding rows repeat
             # row 0 and would inflate the rate).  Counted even when 0 —
@@ -3108,28 +3127,25 @@ class JaxEngine(InferenceEngine):
         self.prefill_tokens += B * (L if (prepped is None and not paged) else Ls)
         self.prefill_seconds += t1 - t0
         self.decode_seconds += t2 - t1
-        self.decode_kv_bytes += int(steps) * B * S * slot_bytes * spec.num_layers
-        self.decode_weight_passes += int(steps)
-        if _TIMING:
-            import sys as _sys
-
-            # stderr like the boot-phase line: stdout belongs to the
-            # bench driver's single JSON line.
-            print(
-                f"[engine] decode B={B} L={L} S={S} max_new={max_new} "
-                f"steps={int(steps)} "
-                f"prompt_max={int(prompt_lens.max())} "
-                f"prefill={t1 - t0:.2f}s decode={t2 - t1:.2f}s "
-                f"prefix={'hit' if (prepped is not None or (paged and P)) else 'miss'} "
-                f"prefix_fallbacks={self.prefix_fallbacks}",
-                flush=True, file=_sys.stderr,
-            )
+        self.decode_kv_bytes += steps * B * S * slot_bytes * spec.num_layers
+        self.decode_weight_passes += steps
         texts = []
-        for i in range(real_B):
-            row = out_np[i]
-            end = np.where(row == self.tokenizer.eos_id)[0]
-            row = row[: end[0]] if end.size else row
-            texts.append(self.tokenizer.decode(row.tolist()))
+        served = 0
+        with obs_tracer.span("engine.detokenize", args={"rows": real_B}):
+            for i in range(real_B):
+                row = out_np[i]
+                end = np.where(row == self.tokenizer.eos_id)[0]
+                row = row[: end[0]] if end.size else row
+                served += len(row)
+                texts.append(self.tokenizer.decode(row.tolist()))
+        # The decode loop's yield, from what the call read back anyway:
+        # tokens served over real rows (up to each row's first EOS) per
+        # loop iteration x real rows.  1.0 when every row runs to the
+        # last step, below 1 when rows sit finished while the longest
+        # decodes, above 1 under fast-forward (forced-chain tokens ride
+        # a step).
+        obs_counters.inc("engine.decode.tokens", served)
+        obs_counters.inc("engine.decode.row_steps", steps * real_B)
         return texts
 
     def _kv_bytes_per_device(self, B: int, S: int) -> int:
@@ -3421,6 +3437,13 @@ class JaxEngine(InferenceEngine):
         cached KV prefix and only the tail prefills per row."""
         if not prompts:
             return []
+        with obs_tracer.span("engine.call", args={
+            "rows": len(prompts),
+            "max_tokens": max(_per_row(max_tokens, len(prompts), int)),
+        }):
+            return self._batch_generate_json(prompts, temperature, max_tokens)
+
+    def _batch_generate_json(self, prompts, temperature, max_tokens):
         # Chaos seam (BCG_TPU_CHAOS `crash|hang|exhaust@engine.generate`):
         # an injected engine failure surfaces exactly where a compiler/
         # runtime crash would — BEFORE the guided run, so no partial

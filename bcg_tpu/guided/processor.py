@@ -25,6 +25,8 @@ import numpy as np
 from bcg_tpu.guided.dfa import ast_to_dfa
 from bcg_tpu.guided.schema_compiler import schema_to_ast
 from bcg_tpu.guided.token_dfa import TokenDFA, build_token_dfa
+from bcg_tpu.obs import counters as obs_counters
+from bcg_tpu.obs import tracer as obs_tracer
 
 
 @dataclass
@@ -71,8 +73,16 @@ def compile_schema(
         return hit
     from bcg_tpu.guided.regex_ast import EPS
 
-    char_dfa = ast_to_dfa(schema_to_ast(schema, ws=EPS if compact else None))
-    token_dfa = build_token_dfa(char_dfa, token_bytes, force_numpy=force_numpy)
+    # Not cached: the schema's token DFA is built (seconds at a 150k
+    # vocabulary; once per schema and process, so boot's, whichever
+    # call pays it).
+    obs_counters.inc("engine.guides.built")
+    with obs_tracer.span("boot.token_dfas",
+                         args={"vocab": len(token_bytes)}):
+        char_dfa = ast_to_dfa(
+            schema_to_ast(schema, ws=EPS if compact else None))
+        token_dfa = build_token_dfa(
+            char_dfa, token_bytes, force_numpy=force_numpy)
     guide = SchemaGuide(
         token_dfa=token_dfa, schema_key=key[0], vocab_key=(vocab_id, len(token_bytes))
     )

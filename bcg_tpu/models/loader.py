@@ -22,6 +22,7 @@ import ml_dtypes
 import numpy as np
 
 from bcg_tpu.models.configs import ModelSpec
+from bcg_tpu.obs import tracer as obs_tracer
 from bcg_tpu.runtime.envflags import get_str
 
 # HF parameter name templates for the Qwen/Llama/Mistral family.
@@ -191,6 +192,7 @@ def load_checkpoint_params(
 
 # -------------------------------------------------- born-sharded random init
 
+@obs_tracer.spanned_once("boot.init_params")
 def init_random_params_sharded(
     spec: ModelSpec,
     key: jax.Array,

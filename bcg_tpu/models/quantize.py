@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from bcg_tpu.models.configs import ModelSpec
+from bcg_tpu.obs import tracer as obs_tracer
 
 # A quantized dense weight is a dict:
 #   int8: {"q": int8 [in, out], "scale": f32 [out]}
@@ -355,6 +356,7 @@ def _sharded_quantizer(mode: str, spec: ModelSpec, mesh):
     return quantize
 
 
+@obs_tracer.spanned_once("boot.quantize")
 def quantize_params(
     params: Dict, spec: ModelSpec, consume: bool = False, mode: str = "int8",
     mesh=None,

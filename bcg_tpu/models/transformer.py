@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from bcg_tpu.models.configs import ModelSpec
 from bcg_tpu.models.quantize import dense
+from bcg_tpu.obs import tracer as obs_tracer
 from bcg_tpu.ops import impl_mesh, is_pallas
 
 TransformerParams = Dict  # pytree: see init_params for the layout
@@ -152,6 +153,7 @@ def init_params(
     )
 
 
+@obs_tracer.spanned_once("boot.stack")
 def stack_layer_params(
     params: TransformerParams, consume: bool = False, mesh=None, spec=None
 ) -> TransformerParams:

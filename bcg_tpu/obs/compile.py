@@ -63,6 +63,20 @@ round/dispatch stream to reach ``a`` owns the window; it closes after
 ``b`` (or at interpreter exit, so a short run never leaves the profiler
 running).
 
+The ``jax.monitoring`` listener (:func:`install_monitoring_listener`):
+the program's one listener for JAX's own compile events.  The span
+tracer installs it when it is enabled (``BCG_TPU_TRACE``); a script may
+install it itself (``chip_smoke.py``).  Each ``jaxpr_trace_duration``,
+``jaxpr_to_mlir_module_duration`` and ``backend_compile_duration``
+event becomes a tracer ``complete()`` interval ``jax.trace`` /
+``jax.lower`` / ``jax.compile`` under the innermost open span (the
+event fires at the interval's end, on the thread that compiled), and
+adds to the ``engine.jax.*_ms`` counters; persistent-cache hits and
+misses count in ``engine.compile_cache.hits`` / ``.misses``.  A traced
+function's inner jitted calls fire events of their own inside the outer
+one's interval: a reader takes the union of the intervals, never their
+sum.
+
 Zero surface when off (the hostsync idiom, pinned byte-exact by
 tests/test_compile_obs.py): flags are read ONCE at first use, nothing
 is registered, no threads start, and every module entry point degrades
@@ -136,6 +150,74 @@ class _NullCm:
 
 
 _NULL_CM = _NullCm()
+
+
+# --------------------------------------------- jax.monitoring listener
+# JAX's event name -> kind: the tracer interval is ``jax.<kind>``, the
+# cumulative-milliseconds counter ``engine.jax.<kind>_ms``.
+_MONITORED_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_MONITORED_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "engine.compile_cache.hits",
+    "/jax/compilation_cache/cache_misses": "engine.compile_cache.misses",
+}
+_listener_lock = threading.Lock()
+_listener_installed = False
+_listener_always = False      # a script asked for the counts, tracer or no
+
+
+def _listening() -> bool:
+    return _listener_always or obs_tracer.enabled()
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    kind = _MONITORED_DURATIONS.get(event)
+    if kind is None or not _listening():
+        return
+    obs_counters.inc(f"engine.jax.{kind}_ms", duration * 1e3)
+    fun = kwargs.get("fun_name")
+    obs_tracer.complete("jax." + kind, duration,
+                        args={"fun": fun} if fun else None)
+
+
+def _on_event(event: str, **_kwargs) -> None:
+    counter = _MONITORED_EVENTS.get(event)
+    if counter is not None and _listening():
+        obs_counters.inc(counter)
+
+
+def install_monitoring_listener(always: bool = False) -> None:
+    """Register the program's ``jax.monitoring`` listener, once per
+    process.  JAX offers no way to take one listener off again, so it
+    stays, and hears nothing while the tracer is off — unless a script
+    installed it with ``always`` for the counters alone."""
+    global _listener_installed, _listener_always
+    with _listener_lock:
+        _listener_always = _listener_always or always
+        if _listener_installed:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        _listener_installed = True
+
+
+def monitored_totals() -> Dict[str, float]:
+    """What the listener has counted so far: seconds of tracing,
+    lowering and backend compile, and persistent-cache hits and
+    misses."""
+    out = {
+        kind + "_s": obs_counters.value(f"engine.jax.{kind}_ms") / 1e3
+        for kind in _MONITORED_DURATIONS.values()
+    }
+    out["cache_hits"] = int(obs_counters.value("engine.compile_cache.hits"))
+    out["cache_misses"] = int(
+        obs_counters.value("engine.compile_cache.misses"))
+    return out
 
 
 # ------------------------------------------------------- signature diffing

@@ -29,7 +29,16 @@ Mechanics:
 * **Ring buffer**: the event deque holds the last
   ``BCG_TPU_TRACE_RING`` events; a long run keeps its tail, and the
   per-name latency accumulator (:class:`SpanAggregator`) is NOT subject
-  to eviction, so ``summarize()`` covers the whole run.
+  to eviction, so ``summarize()`` covers the whole run.  The tracer
+  counts what the ring evicted (:meth:`Tracer.evicted`): a reader that
+  needs every event of an interval checks that count.
+* **One clock with the device trace**: the module-level tracer mirrors
+  every ``span()`` into ``jax.profiler.TraceAnnotation("bcg." + name)``
+  for the span's lifetime, so a ``jax.profiler`` capture carries the
+  program's spans on the host plane of the same ``.xplane.pb`` as the
+  device operations.  ``complete()`` intervals are measured after the
+  fact and stay tracer-only; a reader places them, and every other
+  event, on the host clock through :func:`epoch_perf_counter`.
 
 Enablement: ``BCG_TPU_TRACE=1`` (or setting ``BCG_TPU_TRACE_OUT``,
 which also registers an atexit export to that path).  Flags are read
@@ -39,12 +48,16 @@ module-level :func:`span` returns a shared no-op context manager whose
 cost is bounded by test (``tests/test_obs.py`` disabled-overhead
 bound); call sites therefore never need their own ``if traced:`` guard.
 
-No jax import — loadable by flag-only consumers (bench.py error path).
+No jax import at module scope — loadable by flag-only consumers
+(bench.py error path).  Jax is imported only where the tracer is
+enabled: for the mirror above, and for the one ``jax.monitoring``
+listener of ``obs/compile.py``.
 """
 
 from __future__ import annotations
 
 import atexit
+import functools
 import itertools
 import json
 import os
@@ -123,7 +136,7 @@ class SpanHandle:
     """Identity of one open (or finished) span — what cross-thread
     callers pass as ``parent=``."""
 
-    __slots__ = ("name", "span_id", "parent_id", "tid")
+    __slots__ = ("name", "span_id", "parent_id", "tid", "exit_args")
 
     def __init__(self, name: str, span_id: int, parent_id: Optional[int],
                  tid: int):
@@ -131,6 +144,8 @@ class SpanHandle:
         self.span_id = span_id
         self.parent_id = parent_id
         self.tid = tid
+        # What the body learned (:func:`annotate`); rides the E event.
+        self.exit_args: Optional[Dict] = None
 
 
 class _NullSpan:
@@ -175,7 +190,7 @@ class _SpanCm:
     when the body raises)."""
 
     __slots__ = ("_tracer", "_name", "_parent", "_args", "_aggregate",
-                 "_handle", "_t0")
+                 "_handle", "_t0", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str,
                  parent: Optional[SpanHandle], args: Optional[Dict],
@@ -187,6 +202,11 @@ class _SpanCm:
         self._aggregate = aggregate
 
     def __enter__(self) -> SpanHandle:
+        annotation = self._tracer._annotation
+        self._mirror = None
+        if annotation is not None:
+            self._mirror = annotation("bcg." + self._name)
+            self._mirror.__enter__()
         self._t0 = time.perf_counter()
         self._handle = self._tracer._begin(
             self._name, self._parent, self._args, self._t0
@@ -196,6 +216,8 @@ class _SpanCm:
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
         self._tracer._end(self._handle, t1, failed=exc_type is not None)
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         seconds = t1 - self._t0
         if self._aggregate is not None:
             self._aggregate.add(self._name, seconds)
@@ -206,14 +228,19 @@ class _SpanCm:
 class Tracer:
     """Thread-safe span recorder over a bounded event ring."""
 
-    def __init__(self, ring_capacity: int = 65536):
+    def __init__(self, ring_capacity: int = 65536, annotation=None):
+        """``annotation`` is a context-manager factory taking a name
+        (``jax.profiler.TraceAnnotation``): every span is mirrored into
+        it.  None (a tracer built by hand) mirrors nothing."""
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=max(16, int(ring_capacity)))
+        self._evicted = 0
         self._ids = itertools.count(1)
         self._local = threading.local()
         self._epoch = time.perf_counter()
         self._agg = SpanAggregator()
         self._thread_names: Dict[int, str] = {}
+        self._annotation = annotation
 
     # ------------------------------------------------------------- recording
 
@@ -233,6 +260,12 @@ class Tracer:
         if tid not in self._thread_names:
             self._thread_names[tid] = threading.current_thread().name
 
+    def _append(self, event: Tuple) -> None:
+        """Under ``self._lock``: a full ring drops its oldest event."""
+        if len(self._events) == self._events.maxlen:
+            self._evicted += 1
+        self._events.append(event)
+
     def _begin(self, name: str, parent: Optional[SpanHandle],
                args: Optional[Dict], t0: float) -> SpanHandle:
         tid = threading.get_ident()
@@ -247,7 +280,7 @@ class Tracer:
         ts = (t0 - self._epoch) * 1e6
         with self._lock:
             self._note_thread(tid)
-            self._events.append(
+            self._append(
                 ("B", name, ts, tid, handle.span_id, handle.parent_id,
                  dict(args) if args else None, None)
             )
@@ -262,10 +295,13 @@ class Tracer:
         if stack:
             stack.pop()
         ts = (t1 - self._epoch) * 1e6
+        args = dict(handle.exit_args) if handle.exit_args else None
+        if failed:
+            args = dict(args or {}, failed=True)
         with self._lock:
-            self._events.append(
+            self._append(
                 ("E", handle.name, ts, handle.tid, handle.span_id, None,
-                 {"failed": True} if failed else None, None)
+                 args, None)
             )
 
     def span(self, name: str, parent: Optional[SpanHandle] = None,
@@ -278,13 +314,20 @@ class Tracer:
                  args: Optional[Dict] = None) -> None:
         """Record an already-measured interval ending NOW as one ``X``
         event (for intervals whose start lived on another thread —
-        enqueue→dispatch waits)."""
+        enqueue→dispatch waits — or that someone else measured: the
+        ``jax.monitoring`` durations of ``obs/compile.py``).  Its parent
+        defaults to the calling thread's innermost open span.  Measured
+        after the fact, it cannot be mirrored into the profiler's trace:
+        ``X`` events are tracer-only, and a reader places them on the
+        host clock through :meth:`epoch_perf_counter`."""
         tid = threading.get_ident()
         end = time.perf_counter()
         ts = (end - seconds - self._epoch) * 1e6
+        if parent is None:
+            parent = self.current()
         with self._lock:
             self._note_thread(tid)
-            self._events.append(
+            self._append(
                 ("X", name, ts, tid, next(self._ids),
                  parent.span_id if parent is not None else None,
                  dict(args) if args else None, seconds * 1e6)
@@ -296,6 +339,16 @@ class Tracer:
     def events(self) -> List[Tuple]:
         with self._lock:
             return list(self._events)
+
+    def evicted(self) -> int:
+        """Events the ring has dropped since the tracer was made."""
+        with self._lock:
+            return self._evicted
+
+    def epoch_perf_counter(self) -> float:
+        """``time.perf_counter()`` at the tracer's epoch: an event's
+        ``ts`` (µs) is ``(perf_counter - epoch) * 1e6``."""
+        return self._epoch
 
     def summarize(self) -> Dict[str, Dict[str, float]]:
         """Per-span-name latency table (count/total/p50/p95) over the
@@ -310,6 +363,7 @@ class Tracer:
         with self._lock:
             events = list(self._events)
             threads = dict(self._thread_names)
+            evicted = self._evicted
         pid = os.getpid()
         trace_events: List[Dict[str, Any]] = [
             {
@@ -337,6 +391,8 @@ class Tracer:
             "otherData": {
                 "counters": _counters.snapshot(),
                 "span_summary": self.summarize(),
+                "epoch_perf_counter": self._epoch,
+                "evicted_events": evicted,
             },
         }
         # Fleet identity (run id, rank, host) so traces from many ranks
@@ -366,10 +422,18 @@ def _ensure() -> Optional[Tracer]:
             out = envflags.get_str("BCG_TPU_TRACE_OUT")
             enabled = envflags.get_bool("BCG_TPU_TRACE") or bool(out)
             if enabled:
-                _tracer = Tracer(envflags.get_int("BCG_TPU_TRACE_RING"))
+                import jax.profiler
+
+                _tracer = Tracer(envflags.get_int("BCG_TPU_TRACE_RING"),
+                                 annotation=jax.profiler.TraceAnnotation)
                 if out:
                     atexit.register(flush)
             _configured = True
+    if _tracer is not None:
+        # Outside the lock: the listener's import reads this module.
+        from bcg_tpu.obs import compile as _compile
+
+        _compile.install_monitoring_listener()
     return _tracer
 
 
@@ -396,10 +460,42 @@ def span(name: str, parent: Optional[SpanHandle] = None,
     return _NULL_SPAN
 
 
+def span_once(name: str, args: Optional[Dict] = None):
+    """:func:`span`, unless the calling thread already has a span of
+    this name open: a phase that a recorder opens around a function and
+    the function opens for callers without a recorder is one span."""
+    t = _tracer if _configured else _ensure()
+    if t is None or any(h.name == name for h in t._stack()):
+        return _NULL_SPAN
+    return t.span(name, args=args)
+
+
+def spanned_once(name: str):
+    """Decorator: each call of the function is a :func:`span_once`."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with span_once(name):
+                return fn(*args, **kwargs)
+        return spanned
+    return wrap
+
+
 def current() -> Optional[SpanHandle]:
     """Calling thread's innermost open span (None when disabled/none)."""
     t = _tracer if _configured else _ensure()
     return t.current() if t is not None else None
+
+
+def annotate(**args) -> None:
+    """Add ``args`` to the calling thread's innermost open span; they
+    ride its E event (what a body learns only at its end: a decode
+    loop's steps).  No-op when disabled or outside any span."""
+    handle = current()
+    if handle is not None:
+        if handle.exit_args is None:
+            handle.exit_args = {}
+        handle.exit_args.update(args)
 
 
 def complete(name: str, seconds: float,

@@ -52,11 +52,6 @@ def _register(name: str, kind: str, default, doc: str) -> None:
 # --------------------------------------------------------------- registry
 # BCG_TPU_* operational flags.
 _register(
-    "BCG_TPU_TIMING", "bool", False,
-    "Print per-call prefill/decode wall times and the boot-phase "
-    "breakdown to stderr.",
-)
-_register(
     "BCG_TPU_CHECKPOINT_DIR", "str", None,
     "Root directory searched for local safetensors checkpoints "
     "(models/loader.find_checkpoint_dir).",

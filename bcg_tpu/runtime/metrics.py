@@ -29,6 +29,8 @@ from dataclasses import asdict
 from datetime import datetime
 from typing import Dict, Optional
 
+from bcg_tpu.obs import tracer as obs_tracer
+
 # Phases of the most recent BootPhaseRecorder (including a partially
 # failed boot) — bench.py's error path reads this.
 LAST_BOOT_PHASES: Optional[Dict] = None
@@ -194,6 +196,10 @@ class BootPhaseRecorder:
     before/after bounds each phase's resident delta.  A phase that
     raises is still recorded (``failed: true``) before the exception
     propagates — the breakdown survives a mid-boot OOM.
+
+    Every phase is also a ``boot.<phase>`` span of the tracer
+    (:mod:`bcg_tpu.obs.tracer`): ``phase()`` opens it, ``note()``
+    completes it.
     """
 
     def __init__(self):
@@ -207,16 +213,18 @@ class BootPhaseRecorder:
     def phase(self, name: str):
         t0 = time.perf_counter()
         before, _ = _device_memory()
-        try:
-            yield
-        except BaseException:
-            self._record(name, t0, before, failed=True)
-            raise
-        self._record(name, t0, before)
+        with obs_tracer.span_once("boot." + name):
+            try:
+                yield
+            except BaseException:
+                self._record(name, t0, before, failed=True)
+                raise
+            self._record(name, t0, before)
 
     def note(self, name: str, seconds: float) -> None:
         """Record an externally timed phase (e.g. the first serving
         call's compile+execute, measured where it runs)."""
+        obs_tracer.complete("boot." + name, seconds)
         after, peak = _device_memory()
         self.phases[name] = {
             "seconds": round(seconds, 3),

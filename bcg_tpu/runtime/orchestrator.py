@@ -23,6 +23,7 @@ Differences from the reference (documented improvements):
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import warnings
@@ -41,6 +42,7 @@ from bcg_tpu.config import BCGConfig
 from bcg_tpu.engine.interface import InferenceEngine, create_engine
 from bcg_tpu.game import ByzantineConsensusGame
 from bcg_tpu.obs import compile as obs_compile
+from bcg_tpu.obs import counters as obs_counters
 from bcg_tpu.obs import fleet as obs_fleet
 from bcg_tpu.obs import game_events as obs_game_events
 from bcg_tpu.obs import hostsync as obs_hostsync
@@ -324,6 +326,20 @@ class BCGSimulation:
 
     # --------------------------------------------------------- batched phases
 
+    @staticmethod
+    def _retry_span(level: str, rows: int, attempt: int = 2):
+        """One call of a retry ladder (``level``: the full batch again,
+        or one agent on its own) as a ``round.retry`` span, counted in
+        ``game.retry.calls`` / ``game.retry.rows``; a ladder's first
+        attempt is no retry."""
+        if attempt == 1:
+            return contextlib.nullcontext()
+        obs_counters.inc("game.retry.calls")
+        obs_counters.inc("game.retry.rows", rows)
+        return obs_tracer.span(
+            "round.retry", args={"level": level, "rows": rows}
+        )
+
     def _run_batched_decisions(self, round_num: int, game_state: Dict) -> None:
         """All agents' decisions in one guided batch, with the retry ladder
         (reference main.py:256-374)."""
@@ -361,11 +377,12 @@ class BCGSimulation:
                     f"  [RETRY {attempt}/{MAX_RETRIES}] Harvesting {len(pending)} "
                     f"pending rows from full batch of {len(agent_prompts)}..."
                 )
-            results = self.engine.batch_generate_json(
-                [p for _, p in agent_prompts],
-                temperature=self.config.llm.temperature_decide,
-                max_tokens=self.config.llm.max_tokens_decide,
-            )
+            with self._retry_span("batch", len(pending), attempt):
+                results = self.engine.batch_generate_json(
+                    [p for _, p in agent_prompts],
+                    temperature=self.config.llm.temperature_decide,
+                    max_tokens=self.config.llm.max_tokens_decide,
+                )
             still_failed = []
             for aid, prompt_tuple in pending:
                 result = results[row_of[aid]]
@@ -384,7 +401,8 @@ class BCGSimulation:
                     succeeded = []
                     for aid, _ in pending:
                         agent = self.agents[aid]
-                        new_value = agent.decide_next_value(game_state)
+                        with self._retry_span("sequential", 1):
+                            new_value = agent.decide_next_value(game_state)
                         # None is success too when it's a legitimate abstain
                         # (Byzantine "abstain"), not a retry exhaustion.
                         if new_value is not None or not agent.last_decision_failed:
@@ -462,11 +480,12 @@ class BCGSimulation:
                     f"  [RETRY {attempt}/{MAX_RETRIES}] Harvesting {len(pending)} "
                     f"pending votes from full batch of {len(vote_prompts)}..."
                 )
-            results = self.engine.batch_generate_json(
-                [p for _, p in vote_prompts],
-                temperature=self.config.llm.temperature_vote,
-                max_tokens=self.config.llm.max_tokens_vote,
-            )
+            with self._retry_span("batch", len(pending), attempt):
+                results = self.engine.batch_generate_json(
+                    [p for _, p in vote_prompts],
+                    temperature=self.config.llm.temperature_vote,
+                    max_tokens=self.config.llm.max_tokens_vote,
+                )
             still_failed = []
             for aid, prompt_tuple in pending:
                 result = results[row_of[aid]]
@@ -483,7 +502,8 @@ class BCGSimulation:
                         f"  [SEQUENTIAL RETRY] {len(pending)} votes failed, retrying individually..."
                     )
                     for aid, _ in pending:
-                        vote = self.agents[aid].vote_to_terminate(game_state)
+                        with self._retry_span("sequential", 1):
+                            vote = self.agents[aid].vote_to_terminate(game_state)
                         agent_results[aid] = {"_sequential_success": True, "vote": vote}
                     pending = []
                     break

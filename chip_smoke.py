@@ -63,13 +63,18 @@ def say(msg: str) -> None:
     print(msg, flush=True)
 
 
+def compile_totals() -> dict:
+    """What the program's one ``jax.monitoring`` listener has counted
+    (``bcg_tpu/obs/compile.py``): seconds of tracing, lowering and
+    backend compile, persistent-cache hits and misses."""
+    from bcg_tpu.obs import compile as obs_compile
+
+    return obs_compile.monitored_totals()
+
+
 class Phase:
     """``with Phase("boot") as p:`` prints the phase's wall seconds and,
     from JAX's own monitoring events, the compile seconds inside it."""
-
-    compile_s = 0.0   # backend compile seconds, process-wide
-    cache_hits = 0
-    cache_misses = 0
 
     def __init__(self, name: str):
         self.name = name
@@ -77,12 +82,12 @@ class Phase:
     def __enter__(self):
         say(f"--- {self.name}")
         self.t0 = time.perf_counter()
-        self.c0 = Phase.compile_s
+        self.c0 = compile_totals()["compile_s"]
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.seconds = time.perf_counter() - self.t0
-        self.compiled = Phase.compile_s - self.c0
+        self.compiled = compile_totals()["compile_s"] - self.c0
         if exc_type is None:
             say(f"    {self.name}: {self.seconds:.1f} s wall, of which "
                 f"{self.compiled:.1f} s backend compile")
@@ -90,20 +95,10 @@ class Phase:
 
 
 def install_compile_listeners() -> None:
-    import jax.monitoring
+    """The program's listener, counting with the tracer on or off."""
+    from bcg_tpu.obs import compile as obs_compile
 
-    def on_duration(event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            Phase.compile_s += duration
-
-    def on_event(event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            Phase.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            Phase.cache_misses += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
+    obs_compile.install_monitoring_listener(always=True)
 
 
 def cache_entries(path: str) -> int:
@@ -539,9 +534,10 @@ def main() -> int:
         run_one_chip(devices)
     check(jax.config.jax_compilation_cache_dir == cache_dir,
           f"JAX caches in {jax.config.jax_compilation_cache_dir!r}, not {cache_dir!r}")
+    totals = compile_totals()
     say(f"compile cache: {cache_entries(cache_dir)} entries (was {before}); "
-        f"persistent-cache hits {Phase.cache_hits}, misses {Phase.cache_misses}; "
-        f"backend compile {Phase.compile_s:.1f} s of {time.perf_counter() - t0:.1f} s")
+        f"persistent-cache hits {totals['cache_hits']}, misses {totals['cache_misses']}; "
+        f"backend compile {totals['compile_s']:.1f} s of {time.perf_counter() - t0:.1f} s")
     say(json.dumps({"ok": True, "device": device}))
     return 0
 

@@ -1,7 +1,7 @@
 """BAD: raw environment reads of registered flag names."""
 import os
 
-TIMING = os.environ.get("BCG_TPU_TIMING", "") not in ("", "0")  # BCG-ENV-RAW
+TRACE = os.environ.get("BCG_TPU_TRACE", "") not in ("", "0")  # BCG-ENV-RAW
 VERBOSE = os.getenv("VERBOSE") == "1"                           # BCG-ENV-RAW
 MODEL = os.environ["BENCH_MODEL"]                               # BCG-ENV-RAW
 

@@ -3,7 +3,7 @@ import os
 
 from bcg_tpu.runtime.envflags import get_bool, get_int, get_str, is_set
 
-TIMING = get_bool("BCG_TPU_TIMING")
+TRACE = get_bool("BCG_TPU_TRACE")
 ROUNDS = get_int("BENCH_ROUNDS")
 MODEL = get_str("BENCH_MODEL")
 XLA_FLAGS = os.environ.get("XLA_FLAGS", "")  # external env: allowed

@@ -2,5 +2,5 @@
 from bcg_tpu.config import env_flag
 from bcg_tpu.runtime import envflags
 
-A = envflags.get_bool("BCG_TPU_TIMING")
+A = envflags.get_bool("BCG_TPU_TRACE")
 B = env_flag("BCG_TPU_FINE_SUFFIX")

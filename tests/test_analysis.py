@@ -249,7 +249,7 @@ class TestRepoClean:
         scripts_dir = tmp_path / "scripts"
         scripts_dir.mkdir()
         (scripts_dir / "probe.py").write_text(
-            "import os\nMODE = os.environ.get('BCG_TPU_TIMING')\n"
+            "import os\nMODE = os.environ.get('BCG_TPU_TRACE')\n"
         )
         findings = analyze_paths(paths=[str(scripts_dir)], baseline=None).findings
         assert any(f.rule == "BCG-ENV-RAW" for f in findings)
@@ -360,7 +360,7 @@ class TestWholeProgram:
             with open(probe, "w", encoding="utf-8") as f:
                 f.write(
                     "import os\n"
-                    "MODE = os.environ.get('BCG_TPU_TIMING')\n"
+                    "MODE = os.environ.get('BCG_TPU_TRACE')\n"
                 )
             proc = subprocess.run(
                 [sys.executable, os.path.join("scripts", "lint.py"),
@@ -496,10 +496,10 @@ class TestEnvFlags:
             assert envflags.parse_bool(truthy, False) is True
 
     def test_read_at_call_time(self, monkeypatch):
-        monkeypatch.delenv("BCG_TPU_TIMING", raising=False)
-        assert envflags.get_bool("BCG_TPU_TIMING") is False
-        monkeypatch.setenv("BCG_TPU_TIMING", "1")
-        assert envflags.get_bool("BCG_TPU_TIMING") is True
+        monkeypatch.delenv("BCG_TPU_TRACE", raising=False)
+        assert envflags.get_bool("BCG_TPU_TRACE") is False
+        monkeypatch.setenv("BCG_TPU_TRACE", "1")
+        assert envflags.get_bool("BCG_TPU_TRACE") is True
 
     def test_get_int_fallback_on_garbage(self, monkeypatch):
         monkeypatch.setenv("BENCH_ROUNDS", "not-a-number")
@@ -521,7 +521,7 @@ class TestEnvFlags:
 
     def test_kind_mismatch_raises(self):
         with pytest.raises(TypeError):
-            envflags.get_int("BCG_TPU_TIMING")
+            envflags.get_int("BCG_TPU_TRACE")
         with pytest.raises(TypeError):
             envflags.get_bool("BENCH_MODEL")
 

@@ -115,6 +115,10 @@ class TestTracer:
                 pass
         assert len(tracer.events()) <= 32
         assert tracer.summarize()["tick"]["count"] == 100
+        # ... and the tracer says how much it dropped: a reader that
+        # needs every event of an interval checks this count.
+        assert tracer.evicted() == 200 - 32
+        assert Tracer().evicted() == 0
 
     def test_summarize_percentiles(self):
         tracer = Tracer()
@@ -143,6 +147,50 @@ class TestTracer:
         assert any(ev["ph"] == "M" for ev in data["traceEvents"])
         # Counters ride along so one file is the full observability state.
         assert "counters" in data["otherData"]
+        # The epoch's host-clock value places every ts (and the X events
+        # no profiler sees) on time.perf_counter(), without _epoch.
+        epoch = data["otherData"]["epoch_perf_counter"]
+        assert epoch == traced.epoch_perf_counter()
+        alpha = next(e for e in data["traceEvents"] if e["name"] == "alpha")
+        assert epoch + alpha["ts"] * 1e-6 <= time.perf_counter()
+        assert data["otherData"]["evicted_events"] == traced.evicted() == 0
+
+    def test_annotate_rides_the_exit_event(self, traced):
+        """What a body learns at its end (a decode loop's steps) lands
+        on the innermost open span's E event, beside ``failed``."""
+        with obs_tracer.span("outer"):
+            with obs_tracer.span("inner", args={"rows": 2}):
+                obs_tracer.annotate(steps=7)
+                obs_tracer.annotate(tokens=9)
+        with pytest.raises(ValueError):
+            with obs_tracer.span("boom"):
+                obs_tracer.annotate(steps=1)
+                raise ValueError("x")
+        ends = {e[1]: e[6] for e in traced.events() if e[0] == "E"}
+        assert ends == {"inner": {"steps": 7, "tokens": 9}, "outer": None,
+                        "boom": {"steps": 1, "failed": True}}
+        obs_tracer.annotate(steps=3)  # outside any span: no-op
+
+    def test_complete_lands_under_the_open_span(self, traced):
+        with obs_tracer.span("outer") as outer:
+            obs_tracer.complete("measured", 0.002)
+        obs_tracer.complete("loose", 0.001)
+        parents = {e[1]: e[5] for e in traced.events() if e[0] == "X"}
+        assert parents == {"measured": outer.span_id, "loose": None}
+
+    def test_span_once_opens_a_name_once_per_thread(self, traced):
+        """A phase opened by a recorder around a function, and by the
+        function for callers without a recorder, is one span."""
+        @obs_tracer.spanned_once("boot.stack")
+        def stack():
+            return obs_tracer.current().name
+
+        with obs_tracer.span_once("boot.stack"):
+            with obs_tracer.span("other"):
+                assert stack() == "other"
+        assert stack() == "boot.stack"
+        names = [e[1] for e in traced.events() if e[0] == "B"]
+        assert names == ["boot.stack", "other", "boot.stack"]
 
     def test_disabled_span_is_shared_noop(self, untraced):
         assert obs_tracer.get_tracer() is None
@@ -153,6 +201,8 @@ class TestTracer:
             assert handle is None
         assert obs_tracer.current() is None
         obs_tracer.complete("c", 0.1)  # must not raise
+        obs_tracer.annotate(steps=1)   # nor this
+        assert obs_tracer.span_once("a") is cm1
 
     def test_trace_out_implies_enabled_and_flush_writes(
         self, monkeypatch, tmp_path
@@ -457,6 +507,10 @@ class TestProfilerDelegation:
         assert prof.summary()["phase_counts"]["vote"] == 1
 
 
+_PROGRESS = ("engine.prefill.positions_", "engine.decode.tokens",
+             "engine.decode.row_steps")
+
+
 class TestRetraceCounters:
     """Compile/retrace accounting: exactly +1 per NEW shape signature,
     zero in steady state (the single most expensive silent regression
@@ -489,8 +543,9 @@ class TestRetraceCounters:
             # engine.prefill.positions_* are per-call PROGRESS counters
             # (real/padded prefill work) — they legitimately move every
             # call; this test pins the compile/retrace/spec families,
-            # where any steady-state movement is a regression.
-            and not k.startswith("engine.prefill.positions_")
+            # where any steady-state movement is a regression.  So are
+            # engine.decode.tokens / .row_steps (the loop's yield).
+            and not k.startswith(_PROGRESS)
         }
         assert steady == {}, f"steady-state decode retraced: {steady}"
         # A new token budget is a new decode-loop signature: exactly +1
@@ -506,7 +561,7 @@ class TestRetraceCounters:
         repeat = {
             k: v for k, v in obs_counters.delta(before_repeat).items()
             if k.startswith("engine.")
-            and not k.startswith("engine.prefill.positions_")  # per-call progress
+            and not k.startswith(_PROGRESS)  # per-call progress
         }
         assert repeat == {}, repeat
         engine.shutdown()
@@ -555,6 +610,258 @@ class TestRetraceCounters:
         deltas = {b - a for a, b in zip(accepts, accepts[1:])}
         assert len(deltas) > 1, deltas
         engine.shutdown()
+
+
+def _xplane_spans(trace_dir):
+    """``[(name, start_ns, end_ns)]`` of the ``bcg.*`` events on the
+    host planes of the capture under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    rows = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name.startswith("bcg.")
+    ]
+    # By start, the longer first: a parent before its first child.
+    return sorted(rows, key=lambda row: (row[1], -row[2]))
+
+
+class TestProfilerMirror:
+    """One clock: with the tracer on, every span is also a
+    ``bcg.<name>`` TraceAnnotation, so a jax.profiler capture carries
+    the program's spans beside the device rows."""
+
+    @staticmethod
+    def _game():
+        return run_simulation(n_agents=3, byzantine_count=0, max_rounds=2,
+                              backend="fake", seed=0)
+
+    def test_spans_land_in_the_profilers_trace(self, untraced, monkeypatch,
+                                               tmp_path):
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            # Tracer off: the shared no-op, and nothing for the profiler.
+            assert obs_tracer.span("round") is obs_tracer._NULL_SPAN
+            self._game()
+            monkeypatch.setenv("BCG_TPU_TRACE", "1")
+            obs_tracer.reset()
+            self._game()
+            events = obs_tracer.get_tracer().events()
+        finally:
+            jax.profiler.stop_trace()
+            obs_tracer.reset()
+        mirrored = _xplane_spans(tmp_path)
+        # Every B/E pair of the traced game, and none of the untraced
+        # game's, under the same name ...
+        pairs = [e[1] for e in events if e[0] == "B"]
+        assert pairs.count("round") == 2
+        assert sorted(n for n, _, _ in mirrored) == sorted(
+            "bcg." + n for n in pairs)
+        # ... nested the same way: walking the profiler's rows by start
+        # gives the tracer's B order, and each row lies inside the row
+        # of its tracer parent.
+        assert [n for n, _, _ in mirrored] == ["bcg." + n for n in pairs]
+        by_id = {e[4]: i for i, e in enumerate(
+            e for e in events if e[0] == "B")}
+        for i, begin in enumerate(e for e in events if e[0] == "B"):
+            if begin[5] is not None:
+                _, s, t = mirrored[i]
+                _, ps, pt = mirrored[by_id[begin[5]]]
+                assert ps <= s and t <= pt, begin[1]
+
+
+_VOTE = {
+    "type": "object",
+    "properties": {
+        "decision": {"type": "string", "enum": ["stop", "continue"]}
+    },
+    "required": ["decision"],
+    "additionalProperties": False,
+}
+_ROWS = [("sys", "vote please", _VOTE),
+         ("sys", "round 5 was mixed; vote once more please", _VOTE),
+         ("sys", "vote", _VOTE)]
+
+
+def _tiny_engine(**overrides):
+    import dataclasses
+
+    from bcg_tpu.config import EngineConfig
+    from bcg_tpu.engine.jax_engine import JaxEngine
+
+    return JaxEngine(dataclasses.replace(
+        EngineConfig(backend="jax", model_name="bcg-tpu/tiny-test",
+                     max_model_len=512),
+        **overrides,
+    ))
+
+
+class TestEngineSpans:
+    """The engine call's span table (DESIGN.md "Observability") and the
+    two always-on counters of the decode loop's yield."""
+
+    @pytest.mark.parametrize("overrides", [
+        {"prefix_caching": False},
+        {"prefix_caching": True},
+        {"paged_kv": True},
+    ], ids=["full_prompt", "prefixed", "paged"])
+    def test_engine_call_span_table(self, traced, overrides):
+        engine = _tiny_engine(**overrides)
+        try:
+            # First call: token DFAs, compiles, prefix entries.
+            engine.batch_generate_json(_ROWS, temperature=0.0, max_tokens=48)
+            first = len(traced.events())
+            engine.batch_generate_json(_ROWS, temperature=0.0, max_tokens=48)
+            steps = engine.last_decode_steps
+        finally:
+            engine.shutdown()
+        events = traced.events()[first:]
+        begins = [e for e in events if e[0] == "B"]
+        assert [e[1] for e in begins] == [
+            "engine.call", "engine.guides", "engine.prefill",
+            "engine.tokenize", "engine.decode", "engine.detokenize",
+        ]
+        ids = {e[1]: e[4] for e in begins}
+        parents = {e[1]: e[5] for e in begins}
+        assert parents["engine.tokenize"] == ids["engine.prefill"]
+        for child in ("engine.guides", "engine.prefill", "engine.decode",
+                      "engine.detokenize"):
+            assert parents[child] == ids["engine.call"], child
+        assert begins[0][6] == {"rows": 3, "max_tokens": 48}
+        ends = {e[1]: e[6] for e in events if e[0] == "E"}
+        # Nothing built on a second call; the loop's iterations ride
+        # engine.decode's exit, the shapes engine.prefill's.
+        assert ends["engine.guides"] == {"schemas": 1, "built": 0}
+        assert steps > 0 and ends["engine.decode"] == {"steps": steps}
+        assert ends["engine.prefill"]["chunks"] == 1
+        assert ends["engine.prefill"]["prompt_window"] > 0
+        assert ends["engine.prefill"]["cache_len"] > 0
+
+    @pytest.mark.parametrize("fast_forward", [False, True],
+                             ids=["plain_loop", "fast_forward"])
+    def test_decode_yield_counters(self, untraced, monkeypatch, fast_forward):
+        """``engine.decode.tokens`` over ``engine.decode.row_steps``: at
+        most 1 on the plain loop (rows sit finished while the longest
+        decodes), above 1 under fast-forward on a schema with a forced
+        chain — from what the call reads back anyway: the syncs of a
+        call stay three."""
+        from bcg_tpu.obs import hostsync as obs_hostsync
+
+        monkeypatch.setenv("BCG_TPU_HOSTSYNC", "1")
+        obs_hostsync.reset()
+        engine = _tiny_engine(prefix_caching=False, guided_compact_json=True,
+                              decode_fast_forward=fast_forward)
+        try:
+            before = obs_counters.snapshot()
+            out = engine.batch_generate_json(
+                _ROWS, temperature=0.0, max_tokens=48)
+            moved = obs_counters.delta(before)
+            steps = engine.last_decode_steps
+        finally:
+            engine.shutdown()
+            obs_hostsync.reset()
+        assert all(row.get("decision") in ("stop", "continue") for row in out)
+        assert moved["engine.hostsync.total"] == 3
+        assert moved["engine.decode.row_steps"] == steps * len(_ROWS)
+        # Byte tokens, compact JSON: {"decision":"stop"} is 19 of them,
+        # {"decision":"continue"} 23.
+        assert 19 * len(_ROWS) <= moved["engine.decode.tokens"] \
+            <= 23 * len(_ROWS)
+        ratio = moved["engine.decode.tokens"] / moved["engine.decode.row_steps"]
+        assert (ratio > 1.0) if fast_forward else (0.0 < ratio <= 1.0)
+
+    def test_compile_listener_records_under_the_open_span(self, traced):
+        """The program's one jax.monitoring listener: a forced retrace
+        is one jax.trace, one jax.lower and one jax.compile interval
+        under the span that was open, and adds to engine.jax.*_ms."""
+        import jax
+        import jax.numpy as jnp
+
+        fn = jax.jit(lambda x: jax.lax.mul(x, x))
+        small, large = jnp.ones(3), jnp.ones(4)
+        fn(small).block_until_ready()
+        before = obs_counters.snapshot()
+        first = len(traced.events())
+        with obs_tracer.span("retrace") as handle:
+            fn(large).block_until_ready()
+        fn(large).block_until_ready()   # cached: nothing more
+        intervals = [e for e in traced.events()[first:] if e[0] == "X"]
+        assert sorted(e[1] for e in intervals) == [
+            "jax.compile", "jax.lower", "jax.trace"]
+        for e in intervals:
+            assert e[5] == handle.span_id and e[7] > 0
+        moved = obs_counters.delta(before)
+        assert {"engine.jax.trace_ms", "engine.jax.lower_ms",
+                "engine.jax.compile_ms"} <= set(moved)
+
+
+class TestRetrySpans:
+    @pytest.mark.parametrize("failing, level, rows", [
+        (4, "batch", 4),        # the whole first batch: the batch again
+        (1, "sequential", 1),   # one row of four: that agent on its own
+    ], ids=["batch", "sequential"])
+    def test_retry_ladder_calls_are_spans_and_counted(self, traced, failing,
+                                                      level, rows):
+        before = obs_counters.snapshot()
+        run_simulation(n_agents=4, byzantine_count=0, max_rounds=1,
+                       backend="fake", seed=0,
+                       engine=FakeEngine(seed=0, fail_first_n_calls=failing))
+        moved = obs_counters.delta(before)
+        retries = [e for e in traced.events()
+                   if e[0] == "B" and e[1] == "round.retry"]
+        assert [e[6] for e in retries] == [{"level": level, "rows": rows}]
+        decide = next(e for e in traced.events()
+                      if e[0] == "B" and e[1] == "decide")
+        assert retries[0][5] == decide[4]
+        assert moved["game.retry.calls"] == 1
+        assert moved["game.retry.rows"] == rows
+
+    def test_a_clean_round_retries_nothing(self, traced):
+        before = obs_counters.snapshot()
+        run_simulation(n_agents=3, byzantine_count=0, max_rounds=1,
+                       backend="fake", seed=0)
+        assert not any(e[1] == "round.retry" for e in traced.events())
+        assert "game.retry.calls" not in obs_counters.delta(before)
+
+
+class TestBootSpans:
+    def test_boot_phases_are_spans(self, traced):
+        """One seam: phase() opens ``boot.<phase>`` (once, where the
+        phase's own function opens it too), note() completes it."""
+        from bcg_tpu.runtime.metrics import BootPhaseRecorder
+
+        @obs_tracer.spanned_once("boot.stack")
+        def stack():
+            pass
+
+        boot = BootPhaseRecorder()
+        with boot.phase("stack"):
+            stack()
+        stack()
+        with pytest.raises(MemoryError):
+            with boot.phase("quantize"):
+                raise MemoryError("RESOURCE_EXHAUSTED")
+        boot.note("first_compile", 0.25)
+        events = traced.events()
+        assert [(e[0], e[1]) for e in events] == [
+            ("B", "boot.stack"), ("E", "boot.stack"),
+            ("B", "boot.stack"), ("E", "boot.stack"),
+            ("B", "boot.quantize"), ("E", "boot.quantize"),
+            ("X", "boot.first_compile"),
+        ]
+        assert events[5][6] == {"failed": True}
+        assert events[6][7] == pytest.approx(0.25e6)
+        assert set(boot.phases) == {"stack", "quantize", "first_compile"}
+        assert boot.phases["quantize"]["failed"] is True
 
 
 class _DelayedCalls(InferenceEngine):
@@ -626,11 +933,15 @@ class TestDisabledOverhead:
 
     def test_disabled_overhead_bound(self, untraced, monkeypatch):
         # Unit cost of the disabled fast path.
+        # Since the spans mirror into the profiler's trace, the path is
+        # also: no TraceAnnotation made, no jax import asked for, and
+        # an exit annotation that finds no span.
+        assert obs_tracer.span("probe") is obs_tracer._NULL_SPAN
         probes = 20_000
         t0 = time.perf_counter()
         for _ in range(probes):
             with obs_tracer.span("probe"):
-                pass
+                obs_tracer.annotate(steps=1)
         per_span = (time.perf_counter() - t0) / probes
 
         # Scenario wall-clock with the tracer disabled (the shipped

@@ -11,7 +11,7 @@ import pytest
 
 from bcg_tpu.api import run_simulation
 from bcg_tpu.engine.fake import FakeEngine
-from bcg_tpu.obs import tracer as obs_tracer
+from bcg_tpu.obs import counters as obs_counters, tracer as obs_tracer
 from bcg_tpu.serve.engine import ServingEngine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -28,6 +28,7 @@ def traced(monkeypatch):
 
 
 def test_report_renders_traced_game(traced, tmp_path):
+    before = obs_counters.snapshot()
     serving = ServingEngine(FakeEngine(seed=0, policy="stubborn"),
                             linger_ms=1)
     out = run_simulation(n_agents=3, byzantine_count=0, max_rounds=2,
@@ -35,7 +36,13 @@ def test_report_renders_traced_game(traced, tmp_path):
     serving.shutdown()
     assert out["metrics"]["total_rounds"] == 2
     trace_path = tmp_path / "game_trace.json"
-    traced.export(str(trace_path))
+    # The registry is the process's: in a worker that ran other files
+    # first, their counters fill the report's ranked top-N list and
+    # push this game's out of it.  The report is of this game: give it
+    # what this game counted.
+    data = traced.export()
+    data["otherData"]["counters"] = obs_counters.delta(before)
+    trace_path.write_text(json.dumps(data))
 
     proc = subprocess.run(
         [sys.executable, SCRIPT, str(trace_path)],
