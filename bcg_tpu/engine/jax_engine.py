@@ -1776,6 +1776,7 @@ class JaxEngine(InferenceEngine):
             # accounting rationale as the dense entry builds.
             self.prefill_tokens += Lr_pad
             obs_counters.inc("engine.prefill.positions_padded", Lr_pad)
+            obs_counters.inc("engine.prefill.positions_run", Lr_pad)
             obs_counters.inc("engine.prefill.positions_real", Lr)
         return {
             "blocks": blocks,
@@ -2499,6 +2500,19 @@ class JaxEngine(InferenceEngine):
             self._cache_init_jits[key] = mk
         return mk()
 
+    def _prefill_chunk_starts(self, valid, L: int) -> range:
+        """Window offsets of the programs a dense prefill of the
+        LEFT-padded ``valid`` [B, L] sends: offset 0 alone for a
+        single pass, else from the first chunk in which ANY row holds a
+        token (chunks dead in every row leave; the last always runs, so
+        a batch with no token at all still yields its logits)."""
+        C = self.prefill_chunk
+        if not C or L <= C:
+            return range(1)
+        live = np.flatnonzero(valid.any(axis=0))
+        first = int(live[0]) if live.size else L - 1
+        return range(first // C * C, L, C)
+
     def _prefill_possibly_chunked(self, tokens, valid, L: int, cache,
                                   prefix_valid=None, prefix_lens=None):
         """Prefill ``tokens`` (optionally against an existing cached
@@ -2508,18 +2522,34 @@ class JaxEngine(InferenceEngine):
         Chunked prefill caps activation memory at O(B * chunk) instead of
         O(B * L): a [10, 4096]-token batch through an 8B model needs
         several 640 MB f32 rope/attention temps, which is exactly what a
-        weights+cache-full 16 GB chip does not have.  Chunk k attends the
-        cached KV of everything before it plus itself — the same
-        computation ``prefill_with_prefix`` already implements for prefix
-        caching, so each slice reuses that jit (one compile per distinct
-        chunk offset, persistent-cached).  Left-padding composes: early
-        all-pad slices write masked-off KV that later chunks never see.
+        weights+cache-full 16 GB chip does not have.  Chunk k writes
+        slots ``[P + kC, P + kC + C)`` and attends the cached KV of
+        everything before it plus itself, through
+        ``transformer.prefill_chunk_at``: a FIXED ``[B, P + L - C]``
+        history mask and a traced write slot, so every full-width chunk
+        is ONE compiled program whatever its offset (a ragged tail adds
+        one more shape).
+
+        The window is LEFT-padded, so its leading chunks may hold no
+        token in any row; the loop starts at the first chunk that does
+        (:meth:`_prefill_chunk_starts`).  The slots it passes over keep
+        what the cache was made with (zeros, unit scales) and stay
+        masked for every later chunk and for decode, exactly as the KV
+        of pad tokens was, so the live chunks' logits and KV are those
+        of a loop over all chunks.
         Applies on BOTH prompt paths — full-prompt and prefix-cached
         suffix (the suffix region's chunks extend the prefix).
         """
         C = self.prefill_chunk
         has_prefix = prefix_valid is not None
         P = prefix_valid.shape[1] if has_prefix else 0
+        B = tokens.shape[0]
+        starts = self._prefill_chunk_starts(valid, L)
+        # Counted here, not beside positions_padded in the callers: only
+        # this loop knows what it passed over.
+        obs_counters.inc(
+            "engine.prefill.positions_run", B * (L - starts.start)
+        )
         if not C or L <= C:
             if has_prefix:
                 from bcg_tpu.models.transformer import _cache_len
@@ -2573,21 +2603,13 @@ class JaxEngine(InferenceEngine):
             )
         # Chunked prefill under sp is ring-capable (the chunk jit carries
         # ring=): no bypass to note here.
-        # Single-shape chunk stepping (transformer.prefill_chunk_at): the
-        # history window is a FIXED [B, P + L - Ct] mask and the write
-        # slot a traced scalar, so every full-width chunk shares ONE
-        # compiled program regardless of offset (the previous
-        # growing-prefix form compiled L/C distinct programs per 8B
-        # boot).  A ragged tail chunk adds one
-        # more shape.
-        B = tokens.shape[0]
         base_lens = (
             np.asarray(prefix_lens, dtype=np.int64)
             if has_prefix
             else np.zeros(B, np.int64)
         )
         first_logits = None
-        for start in range(0, L, C):
+        for start in starts:
             Ct = min(C, L - start)
             H = P + L - Ct
             hist = np.zeros((B, H), dtype=bool)
@@ -2906,10 +2928,15 @@ class JaxEngine(InferenceEngine):
             # measurable without pad noise; entry builds count in their
             # creators).  `prefill_tokens` keeps its documented
             # padded-positions semantics for bench compatibility.
+            # `positions_run` is what went through the model: the dense
+            # prefill counts its own (less the all-pad chunks it passed
+            # over), the paged one runs its whole window.
             obs_counters.inc(
                 "engine.prefill.positions_padded",
                 B * (L if (prepped is None and not paged) else Ls),
             )
+            if paged:
+                obs_counters.inc("engine.prefill.positions_run", B * Ls)
             obs_counters.inc(
                 "engine.prefill.positions_real", int(valid.sum())
             )
@@ -2921,9 +2948,14 @@ class JaxEngine(InferenceEngine):
             cached = prepped is not None or (paged and P)
             window = Ls if (prepped is not None or paged) else L
             chunk = self.prefill_chunk
+            chunks = -(-window // chunk) if chunk and window > chunk else 1
+            sent = (
+                chunks if paged
+                else len(self._prefill_chunk_starts(valid, window))
+            )
             obs_tracer.annotate(
                 prompt_window=window, cache_len=S,
-                chunks=-(-window // chunk) if chunk and window > chunk else 1,
+                chunks=sent, chunks_skipped=chunks - sent,
                 prompt_max=int(prompt_lens.max()),
                 prefix="hit" if cached else "miss",
                 prefix_fallbacks=self.prefix_fallbacks,

@@ -475,15 +475,25 @@ def prefill_positions(counters: Dict[str, float]) -> str:
     """One-line real-vs-padded prefill position summary
     (engine.prefill.positions_*); '' when the export carries neither.
     Real positions are actual prompt-token work — prefix-cache savings
-    show up here without pad noise; the padded total is what the FLOP
-    bill sees."""
+    show up here without pad noise; the padded total is the windows'
+    size.  Where the export carries ``positions_run`` (what went through
+    the model: the padded total less the all-pad chunks a chunked
+    prefill passed over) it is what the FLOP bill sees, and the share is
+    of it."""
     padded = counters.get("engine.prefill.positions_padded")
     if not padded:
         return ""
     real = counters.get("engine.prefill.positions_real", 0)
+    run = counters.get("engine.prefill.positions_run")
+    if not run:
+        return (
+            f"== prefill positions: {int(real)} real / {int(padded)} padded "
+            f"({100.0 * real / padded:.1f}% real work) =="
+        )
     return (
-        f"== prefill positions: {int(real)} real / {int(padded)} padded "
-        f"({100.0 * real / padded:.1f}% real work) =="
+        f"== prefill positions: {int(real)} real / {int(run)} run / "
+        f"{int(padded)} padded ({100.0 * real / run:.1f}% real work, "
+        f"{100.0 * (1 - run / padded):.1f}% of the window skipped) =="
     )
 
 

@@ -400,6 +400,191 @@ class TestChunkedPrefill:
             ))
 
 
+class TestChunkedPrefillSkipsDeadChunks:
+    """The chunked prefill's host loop starts at the first chunk in which
+    any row of the LEFT-padded window holds a token: fewer calls of the
+    same compiled program, the same served tokens."""
+
+    VOTE_SCHEMA = TestChunkedPrefill.VOTE_SCHEMA
+    # Byte tokens: the chat template adds some 80 to a prompt's own.
+    LONG_ROW = ("sys " * 40, "user prompt " * 12, VOTE_SCHEMA)
+    SHORT_ROW = ("other sys " * 5, "short", VOTE_SCHEMA)
+
+    @pytest.fixture
+    def traced(self, monkeypatch):
+        from bcg_tpu.obs import tracer as obs_tracer
+
+        monkeypatch.setenv("BCG_TPU_TRACE", "1")
+        monkeypatch.delenv("BCG_TPU_TRACE_OUT", raising=False)
+        monkeypatch.delenv("BCG_TPU_TRACE_RING", raising=False)
+        obs_tracer.reset()
+        yield obs_tracer.get_tracer()
+        obs_tracer.reset()
+
+    @staticmethod
+    def _engine(**overrides):
+        return JaxEngine(dataclasses.replace(
+            EngineConfig(backend="jax", model_name="bcg-tpu/tiny-test",
+                         max_model_len=2048, prefix_caching=False,
+                         prefill_chunk=64),
+            **overrides,
+        ))
+
+    @staticmethod
+    def _spy(engine):
+        """Record every dense prefill's (valid, L) and every chunk
+        program the engine sends (its ``write_pos``)."""
+        windows, sent = [], []
+        prefill, chunk_at = engine._prefill_possibly_chunked, engine._prefill_chunk_at
+
+        def spy_prefill(tokens, valid, L, cache, **kw):
+            windows.append((valid.copy(), L))
+            return prefill(tokens, valid, L, cache, **kw)
+
+        def spy_chunk_at(*args, **kw):
+            sent.append(int(kw["write_pos"]))
+            return chunk_at(*args, **kw)
+
+        engine._prefill_possibly_chunked = spy_prefill
+        engine._prefill_chunk_at = spy_chunk_at
+        return windows, sent, chunk_at
+
+    @staticmethod
+    def _live_chunks(valid, L, C):
+        """ceil((L - first_valid) / C) counted on the chunk grid: the
+        chunks from the one holding the first valid column to the end."""
+        first = int(valid.any(axis=0).argmax())
+        return -(-L // C) - first // C
+
+    @pytest.mark.parametrize("rows, skips", [
+        ([LONG_ROW], True),
+        ([("sys " * 40, "user prompt " * 20, VOTE_SCHEMA)], False),
+    ], ids=["pad_in_front", "token_in_first_chunk"])
+    def test_sends_only_live_chunks(self, traced, rows, skips):
+        from bcg_tpu.obs import counters as obs_counters
+
+        engine = self._engine()
+        windows, sent, chunk_at = self._spy(engine)
+        before = obs_counters.snapshot()
+        try:
+            out = engine.batch_generate_json(rows, temperature=0.0, max_tokens=24)
+        finally:
+            engine.shutdown()
+        moved = obs_counters.delta(before)
+        assert "error" not in out[0]
+        (valid, L), = windows
+        C, B = 64, len(rows)
+        total, live = L // C, self._live_chunks(valid, L, C)
+        assert L % C == 0 and L > C
+        if skips:
+            # At least two whole chunks of pad in front of the window.
+            assert total - live >= 2
+        else:
+            assert valid[:, :C].any() and live == total
+        assert sent == list(range(L - live * C, L, C))
+        assert moved["engine.prefill.positions_run"] == B * live * C
+        assert moved["engine.prefill.positions_padded"] == B * L
+        assert moved["engine.prefill.positions_real"] == int(valid.sum())
+        end = next(e[6] for e in traced.events()
+                   if e[0] == "E" and e[1] == "engine.prefill")
+        assert (end["chunks"], end["chunks_skipped"]) == (live, total - live)
+        assert end["prompt_window"] == L
+        # Fewer calls of the SAME program: nothing new compiles.
+        assert chunk_at._cache_size() <= 2
+
+    @pytest.mark.parametrize("rows, overrides", [
+        ([LONG_ROW], {}),
+        # The suffix window's leading chunk goes (and the entry build's).
+        ([("sys " * 40, "user prompt " * 9, VOTE_SCHEMA)],
+         {"prefix_caching": True}),
+        ([LONG_ROW], {"prefill_chunk": 100}),
+        # The short row's pad covers chunks the long row's tokens reach:
+        # only chunks dead in EVERY row go.
+        ([LONG_ROW, SHORT_ROW], {}),
+    ], ids=["full_prompt", "prefix_cached", "non_divisor_chunk", "mixed_rows"])
+    def test_greedy_output_matches_single_pass(self, rows, overrides):
+        from bcg_tpu.obs import counters as obs_counters
+
+        one = self._engine(**{**overrides, "prefill_chunk": 0})
+        chunked = self._engine(**overrides)
+        C = chunked.prefill_chunk
+        windows, sent, _ = self._spy(chunked)
+        try:
+            r_one = one.batch_generate_json(rows, temperature=0.0, max_tokens=24)
+            before = obs_counters.snapshot()
+            r_chunked = chunked.batch_generate_json(
+                rows, temperature=0.0, max_tokens=24
+            )
+            moved = obs_counters.delta(before)
+        finally:
+            one.shutdown()
+            chunked.shutdown()
+        assert r_chunked == r_one
+        assert all("error" not in r for r in r_one)
+        # The batch's own window (the last dense prefill; entry builds
+        # come before it) left chunks out, and by the longest row alone.
+        valid, L = windows[-1]
+        live = self._live_chunks(valid, L, C)
+        assert 0 < live < -(-L // C)
+        assert len(sent) >= live
+        assert (moved["engine.prefill.positions_run"]
+                < moved["engine.prefill.positions_padded"])
+
+    def test_live_slots_bit_equal_to_all_chunks(self):
+        """First logits and every token's cache slot are, bit for bit,
+        those of a loop over ALL chunks; the slots passed over keep the
+        cache's zeros."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from bcg_tpu.models.transformer import init_kv_cache
+
+        engine = self._engine()
+        C = engine.prefill_chunk
+        prompts = ["sys " * 40 + "user prompt " * 12, "short " * 9]
+        tokens, valid, L = engine._prepare_batch(prompts, [24, 24])
+        B, S = len(prompts), L + 40
+        start0 = engine._prefill_chunk_starts(valid, L).start
+        assert start0 >= 2 * C and valid[:, start0:start0 + C].any()
+
+        def fresh():
+            return init_kv_cache(engine.spec, B, S, stacked=engine.scan_layers)
+
+        # The reference loop runs the engine's own jit of prefill_chunk_at:
+        # bit equality holds within one compiled program, not across an
+        # eager and a fused one.
+        try:
+            logits, cache = engine._prefill_possibly_chunked(
+                tokens, valid, L, fresh()
+            )
+            ref_cache, ref_logits = fresh(), None
+            for start in range(0, L, C):
+                hist = np.zeros((B, L - C), dtype=bool)
+                hist[:, :start] = valid[:, :start]
+                ref_logits, ref_cache = engine._prefill_chunk_at(
+                    engine.params,
+                    tokens=jnp.asarray(tokens[:, start:start + C]),
+                    valid=jnp.asarray(valid[:, start:start + C]),
+                    cache=ref_cache, hist_valid=jnp.asarray(hist),
+                    pos_offset=jnp.asarray(
+                        valid[:, :start].sum(axis=1), jnp.int32),
+                    write_pos=jnp.int32(start),
+                )
+        finally:
+            engine.shutdown()
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+        # Bf16 leaves are [B, S, Hkv, Dh].  A pad position's KV (a query
+        # with nothing to attend averages whatever the cache holds) is
+        # masked and never read: what must agree is every token's.
+        for got, ref in zip(jax.tree_util.tree_leaves(cache),
+                            jax.tree_util.tree_leaves(ref_cache)):
+            got, ref = np.asarray(got), np.asarray(ref)
+            assert got.shape[:2] == (B, S)
+            np.testing.assert_array_equal(got[:, :L][valid], ref[:, :L][valid])
+            assert not got[:, :start0].any() and ref[:, :start0].any()
+
+
 def test_fine_suffix_ladder_config(monkeypatch):
     """EngineConfig.fine_suffix_buckets selects the 1536/3072-rung
     ladder PER ENGINE (opt-in: decode streams allocated suffix slots

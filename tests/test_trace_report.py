@@ -347,6 +347,34 @@ def test_report_renders_compile_cost_tables(tmp_path):
     assert "retraces by cause" not in proc2.stdout
 
 
+@pytest.mark.parametrize("run, line", [
+    (None, "== prefill positions: 21900 real / 40960 padded "
+           "(53.5% real work) =="),
+    (25600, "== prefill positions: 21900 real / 25600 run / 40960 padded "
+            "(85.5% real work, 37.5% of the window skipped) =="),
+], ids=["older_export", "positions_run"])
+def test_report_renders_prefill_positions(tmp_path, run, line):
+    """Positions that went through the model stand beside real and
+    padded when the export carries ``engine.prefill.positions_run``; an
+    older export keeps its line."""
+    counters = {
+        "engine.prefill.positions_padded": 40960,
+        "engine.prefill.positions_real": 21900,
+    }
+    if run is not None:
+        counters["engine.prefill.positions_run"] = run
+    path = tmp_path / "prefill_trace.json"
+    path.write_text(json.dumps(
+        {"traceEvents": [], "otherData": {"counters": counters}}
+    ))
+    proc = subprocess.run(
+        [sys.executable, SCRIPT, str(path)],
+        capture_output=True, text=True, timeout=60,
+    )
+    assert proc.returncode == 0, proc.stderr
+    assert line in proc.stdout
+
+
 def test_report_renders_alerts_line(tmp_path):
     """alert.* transition counters in an export render as the one-line
     alert-plane summary with firing rule names, AND stay out of the
