@@ -4,6 +4,7 @@ one after the other, through ``BCGSimulation.run_round``."""
 from __future__ import annotations
 
 from lib import spans
+from lib.system import NotKept
 
 
 class Driver:
@@ -15,6 +16,7 @@ class Driver:
         agents = system.traffic["num_honest"] + system.traffic["num_byzantine"]
         # decide and vote rows, each counted once, retries not again
         self.decisions_per_game_round = 2 * agents
+        self.stopped = None      # why the last round played was stopped early, if it was
 
     def draw(self) -> dict:
         """The next round of the seed's stream: its games and the
@@ -22,36 +24,53 @@ class Driver:
         return {"games": [self.system.next_fitting() for _ in range(self.n)],
                 "key": self.system.sampling_state()}
 
-    def play(self, recipe: dict) -> list:
-        """One round from a recipe; returns the engine calls it made."""
+    def play(self, recipe: dict, screening: bool = False) -> list:
+        """One round from a recipe; returns the engine calls it made.
+        With ``screening`` the round stops at a call that shows set-up
+        will not keep it (a retry's further call, vote prompts off the
+        band); ``self.stopped`` then says why."""
         system = self.system
         sims = [system.game(k) for k in recipe["games"]]
         system.restore_sampling_state(recipe["key"])
         first = len(system.calls)
-        with spans.span("bench.round"):
-            for sim in sims:
-                sim.run_round()
+        self.stopped, system.screening = None, set() if screening else None
+        try:
+            with spans.span("bench.round"):
+                for sim in sims:
+                    sim.run_round()
+        except NotKept as e:
+            self.stopped = e.why
+            system.log(f"round of games {recipe['games']} stopped at {e}")
+        finally:
+            system.screening = None
         return system.calls[first:]
 
-    def _declared_kinds(self, calls: list) -> bool:
+    def _declared_work(self, calls: list) -> bool:
+        """Every declared kind once per game, each for a declared count
+        of decode steps.  More calls are a retry, and a decide call that
+        stops short of its budget (every row closed its answer early:
+        one round in some thirty at 8B) is a fifth less work.  The
+        declared calls at another shape than declared, or off their
+        rung, are a traffic file that does not describe its games: an
+        error."""
         decl = self.system.traffic["calls"]
-        return sorted(c.kind for c in calls) == sorted(list(decl) * self.n)
+        if sorted(c.kind for c in calls) != sorted(list(decl) * self.n) or \
+                not all(self.system.steps_declared(c) for c in calls):
+            return False
+        self.system.check_declared(calls, band=False)
+        return True
 
-    def stopped_short(self, calls: list) -> bool:
-        """The declared calls and no other, but not for the declared
-        count of decode steps."""
-        return self._declared_kinds(calls) and \
-            not all(self.system.steps_declared(c) for c in calls)
+    def off_band(self, calls: list) -> bool:
+        """The declared work on the declared rungs, but a call's longest
+        prompt off its kind's band: another count of prefill chunk
+        programs than the cell's windows send.  The seed's model and the
+        game decide that, not the file: passed over, and counted apart
+        from retries."""
+        return self._declared_work(calls) and \
+            not all(self.system.on_band(c) for c in calls)
 
     def clean(self, calls: list) -> bool:
-        """Did the round run exactly the declared work: every declared
-        kind once per game, each for a declared count of decode steps?
-        More calls are a retry, and a decide call that stops short of its
-        budget (every row closed its answer early: one round in some
-        thirty at 8B) is a fifth less work: not clean, passed over.  The
-        declared calls at another shape than declared are no retry but a
-        traffic file that does not describe its games: an error."""
-        if not self._declared_kinds(calls) or self.stopped_short(calls):
-            return False
-        self.system.check_declared(calls)
-        return True
+        """Did the round run exactly the declared work, every call on
+        its band?  If not it is passed over."""
+        return self._declared_work(calls) and \
+            all(self.system.on_band(c) for c in calls)

@@ -33,14 +33,26 @@ row of the window valid against its schema, no failed row.
 
 from __future__ import annotations
 
+import importlib
+
 import numpy as np
 
-from . import reference
 from .grammar import EOS, Grammar
 
 COLS = 257         # bytes and EOS; ids above carry no bytes and are never legal
 _RARE = 5.0        # bytes expected fewer times than this share one bin
 _BLOCK = 10        # rows a reference pass holds at once
+
+
+def reference_for(config: dict):
+    """The plain reference the configuration's file names
+    (``"reference": "<name>"`` is ``benchmark/references/<name>.py``):
+    one function ``logits(cfg, seed, tokens, lengths, cols, weights)``
+    that imports nothing of the program, makes its weights from ``seed``
+    at the stated precision (or at the file's ``control``), runs in
+    float32 at ``highest`` layer by layer, and returns float32
+    ``[rows, positions, cols]``."""
+    return importlib.import_module("references." + config["reference"])
 
 
 def distinct_rows(calls, kinds) -> list:
@@ -80,6 +92,7 @@ def positions(config: dict, traffic: dict, seed: int, sample: list,
     at a lower precision (the control's)."""
     cmp_cfg = traffic["compare"]
     T, skip_last = cmp_cfg["positions"], cmp_cfg["skip_last_tokens"]
+    reference = reference_for(config)
     scores, token, temp, row = [], [], [], []
     off_grammar = 0
     for first in range(0, len(sample), _BLOCK):

@@ -1,132 +1,25 @@
-"""Operations and bytes the algorithm needs, from shapes alone.
+"""Where a configuration's cost model is found, and the roofline.
 
-``cfg`` is a configuration file's dict (the published ``config.json``
-keys).  Counts are of what the mathematics requires at the TRUE lengths:
-pad positions, recomputation and allocated-but-unused cache slots are not
-work.  A multiply-add is two operations.
+A configuration's file names its cost model (``"costs": "<name>"``):
+``benchmark/costs/<name>.py`` holds the operations and bytes its model
+needs, from shapes alone.  Every such module offers ``prefill_flops(cfg,
+call)`` and ``decode_flops(cfg, call)`` (the whole step's operations,
+which ``round_mfu_pct`` reads) and whatever its kernels' metric files
+name under ``need`` or ``cost``, all with the one calling convention:
+the configuration's dict and one recorded call as
+``readers/work.py::recorded`` gives it (``prompt_lens``, ``passes``,
+``steps``).  A ``need`` returns a number, a ``cost`` a dict of ``flops``
+and ``bytes``.
 """
 
 from __future__ import annotations
 
-
-def dims(cfg: dict) -> dict:
-    H, Hkv, Dh = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    return {
-        "D": cfg["hidden_size"], "F": cfg["intermediate_size"],
-        "L": cfg["num_hidden_layers"], "V": cfg["vocab_size"],
-        "H": H, "Hkv": Hkv, "Dh": Dh, "q": H * Dh, "kv": Hkv * Dh,
-    }
+import importlib
 
 
-def matmul_params_per_layer(cfg: dict) -> int:
-    """Weights of the seven dense matmuls of one block."""
-    d = dims(cfg)
-    return d["D"] * (d["q"] + 2 * d["kv"]) + d["q"] * d["D"] + 3 * d["D"] * d["F"]
-
-
-def block_matmul_params(cfg: dict) -> int:
-    return cfg["num_hidden_layers"] * matmul_params_per_layer(cfg)
-
-
-def head_params(cfg: dict) -> int:
-    return cfg["hidden_size"] * cfg["vocab_size"]
-
-
-def prefill_attention_flops(cfg: dict, prompt_lens) -> int:
-    """Causal attention over each row's true prompt: QK^T and PV, each
-    2*Dh operations per (query, key) pair, n(n+1)/2 pairs per head."""
-    d = dims(cfg)
-    pairs = sum(n * (n + 1) // 2 for n in prompt_lens)
-    return 4 * d["Dh"] * d["H"] * d["L"] * pairs
-
-
-def prefill_flops(cfg: dict, prompt_lens) -> int:
-    """One prefill call: every prompt token through the blocks, the head
-    once per row (only the last position is sampled from)."""
-    tokens = sum(prompt_lens)
-    return (
-        2 * block_matmul_params(cfg) * tokens
-        + 2 * head_params(cfg) * len(prompt_lens)
-        + prefill_attention_flops(cfg, prompt_lens)
-    )
-
-
-def decode_attention_flops(cfg: dict, prompt_lens, new_tokens) -> int:
-    """Token j of a row attends its n prompt tokens and the j+1 tokens
-    decoded so far (itself included)."""
-    d = dims(cfg)
-    pairs = sum(m * n + m * (m + 1) // 2 for n, m in zip(prompt_lens, new_tokens))
-    return 4 * d["Dh"] * d["H"] * d["L"] * pairs
-
-
-def decode_flops(cfg: dict, prompt_lens, new_tokens) -> int:
-    """One decode loop: ``new_tokens[i]`` forward passes of row i (the
-    first token of a row is sampled from the prefill's logits, so a row
-    that emitted m tokens made m - 1 passes; callers pass passes)."""
-    tokens = sum(new_tokens)
-    return (
-        2 * (block_matmul_params(cfg) + head_params(cfg)) * tokens
-        + decode_attention_flops(cfg, prompt_lens, new_tokens)
-    )
-
-
-def weight_bytes(cfg: dict, weight_dtype: str) -> int:
-    """Bytes of the weights one decode step streams: seven matmuls per
-    block and the head at the served width, plus int8's f32 scale per
-    output channel.  The embedding is a gather of B rows: not counted."""
-    d = dims(cfg)
-    params = block_matmul_params(cfg) + head_params(cfg)
-    if weight_dtype == "bfloat16":
-        return 2 * params
-    if weight_dtype == "int8":
-        channels = d["L"] * (d["q"] + 2 * d["kv"] + d["D"] + 2 * d["F"] + d["D"]) + d["V"]
-        return params + 4 * channels
-    raise ValueError(f"no byte count for weight dtype {weight_dtype!r}")
-
-
-def kv_bytes_per_token(cfg: dict, kv_dtype: str) -> int:
-    """K and V of one token in every layer; int8 adds one f32 scale per
-    token per kv head for each of K and V."""
-    d = dims(cfg)
-    if kv_dtype == "bfloat16":
-        return 2 * 2 * d["kv"] * d["L"]
-    if kv_dtype == "int8":
-        return 2 * (d["kv"] + 4 * d["Hkv"]) * d["L"]
-    raise ValueError(f"no byte count for kv dtype {kv_dtype!r}")
-
-
-def decode_bytes(cfg: dict, weight_dtype: str, kv_dtype: str, steps: int,
-                 prompt_lens, new_tokens) -> int:
-    """One decode loop of ``steps`` iterations: every iteration streams
-    the weights once; pass j of a row reads the K/V of its n + j + 1
-    tokens."""
-    per_tok = kv_bytes_per_token(cfg, kv_dtype)
-    ctx = sum(m * n + m * (m + 1) // 2 for n, m in zip(prompt_lens, new_tokens))
-    return steps * weight_bytes(cfg, weight_dtype) + per_tok * ctx
-
-
-def flash_prefill_kernel(cfg: dict, prompt_lens) -> dict:
-    """The prefill attention kernel over one call, all layers: operations
-    as :func:`prefill_attention_flops`; bytes are q, k, v read and the
-    output written once, bf16."""
-    d = dims(cfg)
-    tokens = sum(prompt_lens)
-    return {
-        "flops": prefill_attention_flops(cfg, prompt_lens),
-        "bytes": 2 * tokens * (2 * d["q"] + 2 * d["kv"]) * d["L"],
-    }
-
-
-def decode_attention_kernel(cfg: dict, kv_dtype: str, prompt_lens, new_tokens) -> dict:
-    """The decode attention kernel over one loop, all layers: it reads
-    each row's K/V cache up to the current token once per pass."""
-    d = dims(cfg)
-    ctx = sum(m * n + m * (m + 1) // 2 for n, m in zip(prompt_lens, new_tokens))
-    return {
-        "flops": decode_attention_flops(cfg, prompt_lens, new_tokens),
-        "bytes": kv_bytes_per_token(cfg, kv_dtype) * ctx
-        + 2 * 2 * d["q"] * d["L"] * sum(new_tokens),
-    }
+def module_for(config: dict):
+    """The cost model the configuration's file names (``costs/<name>.py``)."""
+    return importlib.import_module("costs." + config["costs"])
 
 
 def roofline_seconds(flops: int, nbytes: int, peak_flops: float, peak_bw: float) -> tuple:

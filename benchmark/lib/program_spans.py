@@ -43,7 +43,7 @@ class Record:
     events: Optional[list]     # tracer intervals [name, t0_s, t1_s, args], host clock
     evicted: int               # events the tracer's ring dropped
     counters: dict             # the program's counters over the window
-    window_t0: Optional[float]  # host clock at the window's first round
+    setup_end: Optional[float]  # host clock at the end of the first round played
 
     # ---------------------------------------------------- the profiler's clock
 
@@ -71,19 +71,20 @@ class Record:
 
     # ------------------------------------------------------------ host clock
 
-    def before_window(self, names) -> Optional[list]:
+    def in_setup(self, names) -> Optional[list]:
         """Merged ``(t0, t1)`` seconds of the tracer's intervals of these
-        names that ended before the window opened: set-up's.  A union,
-        because one interval may lie inside another (a traced function's
-        inner jitted calls fire ``jax.trace`` events of their own)."""
-        if self.events is None or self.window_t0 is None:
+        names that ended by the end of the first round the process
+        played, where ``setup_s`` ends: set-up's.  A union, because one
+        interval may lie inside another (a traced function's inner
+        jitted calls fire ``jax.trace`` events of their own)."""
+        if self.events is None or self.setup_end is None:
             return None
         if self.evicted:
             raise RuntimeError(
                 f"the tracer's ring dropped {self.evicted} event(s): set-up's spans "
                 "are no longer whole (raise BCG_TPU_TRACE_RING in the metric's env)")
         return trace.union([(t0, t1) for n, t0, t1, _ in self.events
-                            if n in names and t1 <= self.window_t0])
+                            if n in names and t1 <= self.setup_end])
 
 
 def newest_xplane(trace_dir: str) -> Optional[str]:
@@ -154,10 +155,8 @@ def from_run(ctx: dict) -> Record:
     host, device = _trace_rows(TRACE_DIR, xplane, os.path.getmtime(xplane)) \
         if xplane else ([], [])
     events, evicted = tracer_intervals()
-    rounds = ctx["spans"].take(trace.ROUND_SPAN)
     return Record(host=host, device=device, events=events, evicted=evicted,
-                  counters=ctx["counters"],
-                  window_t0=min(t0 for t0, _ in rounds) if rounds else None)
+                  counters=ctx["counters"], setup_end=ctx["boot"].get("setup_end"))
 
 
 def from_file(path: str) -> Record:

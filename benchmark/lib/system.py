@@ -39,6 +39,16 @@ class UndeclaredWork(RuntimeError):
     compiled: the run is not a measurement."""
 
 
+class NotKept(Exception):
+    """Raised in place of an engine call that shows, before it runs,
+    that set-up will not keep the round it belongs to (``why`` is
+    ``"retried"`` or ``"off_band"``): the round stops there."""
+
+    def __init__(self, why: str, what: str):
+        super().__init__(what)
+        self.why = why
+
+
 @dataclasses.dataclass
 class Call:
     """One public engine call as it was asked and served."""
@@ -85,9 +95,27 @@ class Compiles:
 
 
 def check_spec(config: dict, spec) -> None:
-    for key, attr in _SPEC_KEYS.items():
+    """The program runs the sizes the configuration's file states: the
+    keys of ``_SPEC_KEYS`` that the file states (one it leaves out or
+    gives as ``null`` states nothing), and every pair of the file's own
+    ``spec_keys`` (file key -> ``ModelSpec`` attribute; a list is
+    compared as a list).  A key the program's spec has no attribute for
+    is an error: the file states what the program cannot be held to."""
+    pairs = {k: a for k, a in _SPEC_KEYS.items() if config.get(k) is not None}
+    pairs.update(config.get("spec_keys", {}))
+    for key, attr in pairs.items():
+        if not hasattr(spec, attr):
+            raise RuntimeError(
+                f"configuration {config['name']!r} states {key} but the program's "
+                f"{spec.name!r} has no {attr}")
         want, got = config[key], getattr(spec, attr)
-        if float(want) != float(got):
+        if isinstance(want, (list, tuple)):
+            same = list(want) == list(got)
+        elif isinstance(want, str):
+            same = want == got
+        else:
+            same = float(want) == float(got)
+        if not same:
             raise RuntimeError(
                 f"configuration {config['name']!r} states {key}={want} but the "
                 f"program's {spec.name!r} has {attr}={got}"
@@ -170,6 +198,9 @@ class System:
         if len(self._kind_by_budget) != len(traffic["calls"]):
             raise ValueError("call kinds are told apart by their budgets: they must differ")
         self._serving: Optional[list] = None     # texts of the call in flight
+        # set-up, after its first round: the call kinds of the round in
+        # play, to stop it at a call that shows it will not be kept
+        self.screening: Optional[set] = None
         self._alter: Optional[Callable] = None   # tests plant faults here
 
         cfg = game_config(config, traffic, seed, 0)
@@ -215,11 +246,19 @@ class System:
                 greedy = set(decl.get("greedy_rows", []))
                 temps = [0.0 if i in greedy else t for i, t in enumerate(temps)]
             ids = [self.prompt_ids(s, u) for s, u, _ in prompts]
+            longest = max(map(len, ids))
             room = engine.max_model_len - max(budgets) - 1
-            if max(map(len, ids)) > room:
+            if longest > room:
                 raise UndeclaredWork(
-                    f"a {kind} prompt of {max(map(len, ids))} tokens is longer than "
+                    f"a {kind} prompt of {longest} tokens is longer than "
                     f"the engine keeps ({room}): the reference would see another prompt")
+            if self.screening is not None:
+                if n != decl.get("rows") or kind in self.screening:
+                    raise NotKept("retried", f"a further {kind} call, of {n} rows")
+                self.screening.add(kind)
+                if not self._on_band(kind, longest):
+                    raise NotKept("off_band",
+                                  f"a {kind} call whose longest prompt is {longest} tokens")
             before = (engine.prefill_seconds, engine.decode_seconds,
                       engine.total_decode_steps)
             self._serving = served = []
@@ -316,10 +355,21 @@ class System:
         lo, hi = self.traffic["calls"][call.kind]["decode_steps"]
         return lo <= call.steps <= hi
 
-    def check_declared(self, calls=None) -> None:
+    def _on_band(self, kind: str, longest: int) -> bool:
+        band = self.traffic["calls"].get(kind, {}).get("prompt_band")
+        return band is None or band[0] < longest <= band[1]
+
+    def on_band(self, call) -> bool:
+        """Is the call's longest prompt on its kind's ``prompt_band``
+        (``(low, high]``, a part of the rung on which every call sends
+        the same count of prefill chunk programs)?  A kind with no band
+        is on it anywhere on its rung."""
+        return self._on_band(call.kind, max(call.prompt_lens))
+
+    def check_declared(self, calls=None, band: bool = True) -> None:
         """Each call (so far, or of ``calls``) was a declared one: its
-        kind's rows, its longest prompt on the kind's rung, its decode
-        steps in the kind's band."""
+        kind's rows, its longest prompt on the kind's rung and, with
+        ``band``, on its band, its decode steps in the kind's band."""
         decl = self.traffic["calls"]
         for call in self.calls if calls is None else calls:
             want = decl.get(call.kind)
@@ -327,12 +377,14 @@ class System:
                 raise UndeclaredWork(f"call kind {call.kind!r} is not declared")
             longest = max(call.prompt_lens)
             if call.rows != want["rows"] or not self.steps_declared(call) or not (
-                    want["prompt_rung_below"] < longest <= want["prompt_rung"]):
+                    want["prompt_rung_below"] < longest <= want["prompt_rung"]) or (
+                    band and not self.on_band(call)):
                 raise UndeclaredWork(
                     f"{call.kind} call ran rows={call.rows}, longest prompt {longest}, "
                     f"{call.steps} decode steps; the traffic file declares "
                     f"rows={want['rows']}, prompts in ({want['prompt_rung_below']}, "
-                    f"{want['prompt_rung']}] and steps in {want['decode_steps']}"
+                    f"{want['prompt_rung']}], band {want.get('prompt_band')} "
+                    f"and steps in {want['decode_steps']}"
                 )
 
     def memory_peak_bytes(self) -> int:

@@ -11,9 +11,12 @@ game's retry ladder send more calls, single rows among them, at shapes
 no traffic file can know beforehand.  So set-up draws rounds from the
 seed's stream and plays each once, which is also the warm-up: a round
 that ran exactly the declared calls, each for a declared count of decode
-steps, is *proved*; one that retried is passed over, like a game whose
-prompts fall off the declared rungs ("choose traffic on which no
-operation fails").  The window then plays the proved rounds
+steps and on its band of prompt lengths, is *proved*; one that retried
+is passed over, like a game whose prompts fall off the declared rungs
+("choose traffic on which no operation fails"), and so is one whose
+vote prompts fell off the band (another count of prefill chunk
+programs), and set-up goes on with the next model of the seed's stream.
+The window then plays the proved rounds
 (``distinct_rounds`` of them, of successive games of the stream) again,
 in turn, each from the same games and the same
 sampling key: the same prompts through the same programs give the same
@@ -21,6 +24,14 @@ tokens, so no row can come back invalid and every window of a cell is
 the same number of calls of the same shapes.  It opens and closes on
 whole rounds: as many as end within ``--seconds``, and the first in any
 case.
+
+Set-up, as ``setup_s`` counts it, ends with the first round the process
+plays to its end, kept or not: by then every declared shape is compiled
+or loaded and the first decisions are made, which is what a user's fresh
+process pays.  The rounds after it prove and screen for the window:
+they are the harness's own, differ in number from seed to seed, and are
+counted (``rounds_passed_over``, ``rounds_off_band``), not timed as
+set-up.
 """
 
 from __future__ import annotations
@@ -38,56 +49,75 @@ def driver_for(system: System):
 
 
 _REDRAW = 7919      # the next model of a seed's stream: weights from seed + _REDRAW
+_MOST_ROUNDS = 40   # set-up gives up on a seed after playing this many
 
 
 def warm_up(games) -> dict:
     """Set-up's rounds: draw and play until ``distinct_rounds`` rounds
-    are proved.  The first play compiles (or loads) the declared shapes.
-    A round that retried is passed over (one in 8 to 37 at 8B).  Two
-    things are properties of the seed's random model and not of a round,
-    and send set-up to the next model of the seed's stream, as a game
-    off the declared rungs sends it to the next game: a round that
-    stopped short of the declared decode steps (every row closes its
-    answer before the budget: one seed in some sixty, a fifth less work
-    a round), and a second round in a row that retried (a greedy row,
-    the same text every round, that the game's validity check refuses:
-    seed 1800104729 retried in 14 rounds of 14).  Rounds proved on the
-    model before are dropped.  What set-up played is dropped from the
-    record."""
+    are proved on one model.  The first play compiles (or loads) the
+    declared shapes, and ``setup_s`` ends with it (``setup_end``, with
+    ``compile_s`` the backend's compile seconds up to there); should a
+    round that is kept still compile, set-up runs to that round's end.
+
+    A round is not kept if it retried, stopped short of the declared
+    decode steps, or sent a call's prompts off the band.  Each of the
+    three is a property of the seed's random model far more than of the
+    game: a model whose rows run their first string to the budget sends
+    the shortest vote prompts in every round (seed 2202000011: 9 rounds
+    of 9 under the band), one whose greedy row the game's validity check
+    refuses retries in every round (seed 1800104729: 14 of 14), one that
+    closes every answer early stops short in every round.  So a round
+    that is not kept sends set-up to the next model of the seed's stream
+    (weights from ``weights_seed + _REDRAW``; the reference follows), as
+    a game off the declared rungs sends it to the next game; rounds
+    proved on the model before are dropped.  After the first round,
+    which has to run whole, a round stops at the call that shows it will
+    not be kept (``games.play(..., screening=True)``): screening is the
+    harness's own cost and no user's.  What set-up played is dropped
+    from the record."""
     system = games.system
     want = system.traffic["distinct_rounds"]
-    failed0 = system.engine.failed_rows
-    games.proved, passed_over, redrawn, in_a_row = [], 0, 0, 0
-    for _ in range(want + 12):
+    games.proved, passed_over, off_band, redrawn = [], 0, 0, 0
+    setup_end = compile_s = None
+    for _ in range(_MOST_ROUNDS):
         if len(games.proved) == want:
             break
         recipe = games.draw()
-        calls = games.play(recipe)
-        if games.clean(calls) and system.engine.failed_rows == failed0:
+        failed0, compiled = system.engine.failed_rows, system.compiles.snapshot()
+        calls = games.play(recipe, screening=setup_end is not None)
+        failed = system.engine.failed_rows - failed0
+        kept = games.stopped is None and games.clean(calls) and not failed
+        if setup_end is None or (kept and system.compiles.snapshot() != compiled):
+            if setup_end is not None:
+                system.log("a round that is kept compiled: set-up runs to its end")
+            setup_end, compile_s = time.perf_counter(), system.compiles.backend_s
+        ran = [(c.kind, c.rows, max(c.prompt_lens), c.steps) for c in calls]
+        if kept:
+            system.log(f"round of games {recipe['games']} ran {ran}: proved")
             recipe["texts"] = [list(c.texts) for c in calls]
             recipe["calls"] = calls
             games.proved.append(recipe)
-            in_a_row = 0
             continue
-        passed_over += 1
-        in_a_row += 1
-        system.log(f"round of games {recipe['games']} ran "
-                   f"{[(c.kind, c.rows, c.steps) for c in calls]}, "
-                   f"{system.engine.failed_rows - failed0} failed rows: passed over")
-        failed0 = system.engine.failed_rows
-        if games.stopped_short(calls) or in_a_row == 2:
-            redrawn += 1
-            passed_over += len(games.proved)
-            games.proved, in_a_row = [], 0
-            system.remake_weights(system.weights_seed + _REDRAW)
-            system.log(f"the seed's model stops short or retries round after round: "
-                       f"weights redrawn from {system.weights_seed}")
+        if games.stopped == "off_band" or (
+                games.stopped is None and not failed and games.off_band(calls)):
+            off_band += 1
+            why = "off the band"
+        else:
+            passed_over += 1
+            why = f"retried or stopped short, {failed} failed rows"
+        passed_over += len(games.proved)
+        games.proved = []
+        redrawn += 1
+        system.log(f"round of games {recipe['games']} ran {ran}: {why}, passed over; "
+                   f"weights redrawn from {system.weights_seed + _REDRAW}")
+        system.remake_weights(system.weights_seed + _REDRAW)
     if len(games.proved) < want:
         raise RuntimeError("the seed's stream gave no round of the declared work")
     system.calls.clear()
     spans.RECORDED.clear()
     return {"rounds_proved": len(games.proved), "rounds_passed_over": passed_over,
-            "models_redrawn": redrawn}
+            "rounds_off_band": off_band, "models_redrawn": redrawn,
+            "setup_end": setup_end, "compile_s": compile_s}
 
 
 def measure(games, seconds: float) -> dict:

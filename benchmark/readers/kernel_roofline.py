@@ -3,7 +3,7 @@ for what its calls needed (the larger of operations over peak and bytes
 over peak) over the device time of the kernel's events in the trace.
 The events are found by the pattern the configuration's file keeps under
 ``trace_names[names]``; what the calls needed comes from the function of
-``readers/work.py`` that ``cost`` names."""
+the configuration's cost model that ``cost`` names."""
 
 from lib import costs, peaks, trace
 from readers import work
@@ -14,7 +14,7 @@ def read(ctx, names, cost):
     seconds = pattern and trace.seconds_matching(ctx["trace"]["ops_s"], pattern)
     if not seconds:
         return None
-    need = getattr(work, cost)(ctx)
+    need = work.total(ctx, cost)
     table = peaks.peaks_for(ctx["device"]["kind"])
     chips = ctx["cell"]["chips"]
     least, _bound = costs.roofline_seconds(

@@ -1,7 +1,12 @@
-"""What the window's calls needed, from the recorded calls and the cost
-functions: shared by the share-of-peak readers."""
+"""What the window's calls needed: each recorded call, handed to the
+function of the configuration's cost model (``costs/<name>.py``) that a
+metric's file names, summed over the calls.  Nothing here is a model's."""
+
+import collections
 
 from lib import costs
+
+Recorded = collections.namedtuple("Recorded", "prompt_lens passes steps")
 
 
 def passes(call) -> list:
@@ -11,33 +16,16 @@ def passes(call) -> list:
     return [max(0, min(len(t) + 1, b) - 1) for t, b in zip(call.texts, call.budgets)]
 
 
-def prefill_flops(ctx) -> int:
-    return sum(costs.prefill_flops(ctx["config"], c.prompt_lens) for c in ctx["calls"])
+def recorded(call) -> Recorded:
+    """One engine call as every cost function takes it."""
+    return Recorded(list(call.prompt_lens), passes(call), call.steps)
 
 
-def decode_flops(ctx) -> int:
-    return sum(costs.decode_flops(ctx["config"], c.prompt_lens, passes(c))
-               for c in ctx["calls"])
-
-
-def decode_bytes(ctx) -> int:
-    cfg = ctx["config"]
-    return sum(costs.decode_bytes(cfg, cfg["weight_dtype"], cfg["kv_dtype"], c.steps,
-                                  c.prompt_lens, passes(c)) for c in ctx["calls"])
-
-
-def _kernel_total(needs) -> dict:
-    needs = list(needs)
-    return {"flops": sum(n["flops"] for n in needs), "bytes": sum(n["bytes"] for n in needs)}
-
-
-def flash_prefill_kernel(ctx) -> dict:
-    return _kernel_total(costs.flash_prefill_kernel(ctx["config"], c.prompt_lens)
-                         for c in ctx["calls"])
-
-
-def decode_attention_kernel(ctx) -> dict:
-    cfg = ctx["config"]
-    return _kernel_total(
-        costs.decode_attention_kernel(cfg, cfg["kv_dtype"], c.prompt_lens, passes(c))
-        for c in ctx["calls"])
+def total(ctx, name):
+    """The cost model's function ``name`` over the window's calls: a
+    number, or a kernel's ``flops`` and ``bytes``, summed."""
+    fn = getattr(costs.module_for(ctx["config"]), name)
+    needs = [fn(ctx["config"], recorded(c)) for c in ctx["calls"]]
+    if needs and isinstance(needs[0], dict):
+        return {k: sum(n[k] for n in needs) for k in needs[0]}
+    return sum(needs)

@@ -4,16 +4,18 @@
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Boots the cell's configuration through the program's normal entry
-points with weights, games and sampling drawn from ``--seed``, proves
-rounds of the work the cell's traffic file declares (set-up, which is
-also the warm-up), plays them again as whole rounds for ``--seconds``
-(the window), frees the program, compares what the window served with
-the plain reference, and prints one JSON
+points with weights, games and sampling drawn from ``--seed``, plays
+rounds until enough are proved to be the work the cell's traffic file
+declares (the first is set-up's, and the warm-up), plays the proved ones
+again as whole rounds for ``--seconds`` (the window), frees the program,
+compares what the window served with the plain reference the
+configuration's file names, and prints one JSON
 object as the last line of standard output.  No accelerator, or fewer
 chips than the cell asks for, is a non-zero exit and no result line.
-The harness is data: a cell, a configuration, a traffic mix (and the
-driver of its ``mode``) and a per-layer metric (and its reader) are
-files found by the names in ``BENCHMARK.json``.
+The harness is data: a cell, a configuration (with the reference and
+the cost model its file names), a traffic mix (and the driver of its
+``mode``) and a per-layer metric (and its reader) are files found by
+the names in ``BENCHMARK.json``.
 """
 
 from __future__ import annotations
@@ -97,8 +99,11 @@ def run_cell(bench: dict, cell: dict, config: dict, traffic: dict, seed: int,
     log(f"booted in {sysm.boot_s:.1f}s (weights {sysm.weights_s:.1f}s)")
     games = window.driver_for(sysm)
     proved = window.warm_up(games)
-    setup_s = time.perf_counter() - T_START
-    log(f"set-up done: {setup_s:.1f}s, {proved['rounds_passed_over']} round(s) passed over, "
+    setup_s = proved["setup_end"] - T_START     # to the end of the first round played
+    log(f"set-up done: {setup_s:.1f}s to the first round's end, "
+        f"{time.perf_counter() - proved['setup_end']:.1f}s of proving after it; "
+        f"{proved['rounds_passed_over']} round(s) passed over and "
+        f"{proved['rounds_off_band']} off the band, "
         f"backend compile {sysm.compiles.backend_s:.1f}s, "
         f"cache hits {sysm.compiles.cache_hits} misses {sysm.compiles.cache_misses}")
 
@@ -115,8 +120,7 @@ def run_cell(bench: dict, cell: dict, config: dict, traffic: dict, seed: int,
     counters1 = system.program_counters()
     device = dict(device, memory_peak_bytes=sysm.memory_peak_bytes())
     calls = list(sysm.calls)
-    boot = {"boot_s": sysm.boot_s, "weights_s": sysm.weights_s,
-            "compile_s": sysm.compiles.backend_s, **proved}
+    boot = {"boot_s": sysm.boot_s, "weights_s": sysm.weights_s, **proved}
     invalid = correct.invalid_rows(calls)
     sample = correct.distinct_rows(calls, traffic["compare"]["kinds"])
     weights_seed = sysm.weights_seed

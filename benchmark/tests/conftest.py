@@ -10,3 +10,5 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 sys.path.insert(0, os.path.dirname(BENCH))   # the program
 sys.path.insert(0, BENCH)                    # lib, readers, run
+# what tests/configs/tiny-named.json names: references.recording, costs.recording
+sys.path.insert(0, os.path.join(HERE, "stubs"))

@@ -40,7 +40,8 @@ def table(monkeypatch):
         noise = np.random.default_rng(9).normal(0.0, shift, cols)
         return np.broadcast_to(base + noise, tokens.shape + (cols,)).astype(np.float32)
 
-    monkeypatch.setattr(correct.reference, "logits", logits)
+    monkeypatch.setattr(correct, "reference_for",
+                        lambda config: types.SimpleNamespace(logits=logits))
     return base
 
 

@@ -64,7 +64,7 @@ def _by_hand(**changes):
         ],
         evicted=0,
         counters={"engine.decode.tokens": 3190, "engine.decode.row_steps": 3200},
-        window_t0=59.9,
+        setup_end=30.0,
     )
     rec.update(changes)
     return program_spans.Record(**rec)
@@ -118,12 +118,13 @@ def test_one_clock():
     assert program_spans.program_time_inside(rec, "no_such_program", "round") is None
 
 
-def test_set_up_is_a_union_before_the_window(by_hand):
+def test_set_up_is_a_union_up_to_the_first_rounds_end(by_hand):
     assert setup_spans.read({}, ["boot.init_params", "boot.quantize", "boot.stack"]) \
         == pytest.approx(2.5)
     # [1.2,1.4] + [5,9] holding [6,7] + [9,10]; the window's own is left out
     assert setup_spans.read({}, ["jax.trace", "jax.lower"]) == pytest.approx(5.2)
-    assert setup_spans.read({}, ["round"]) == pytest.approx(26.0 + 24.5)
+    # the first round ends with set-up; the second, proving, is not set-up's
+    assert setup_spans.read({}, ["round"]) == pytest.approx(26.0)
     assert setup_spans.read({}, ["no.such.span"]) is None
 
 
@@ -139,7 +140,7 @@ def test_a_program_without_the_mirror_reads_nothing(by_hand):
     """A parent commit: ``bench.*`` spans and device rows, no ``bcg.*``
     span, a tracer without a public epoch, none of the counters."""
     by_hand(host=[["bench.round", 90.0, 920.0]], device=[], events=None,
-            counters={"engine.hostsync.total": 12}, window_t0=59.9)
+            counters={"engine.hostsync.total": 12}, setup_end=30.0)
     assert span_self_mean.read({}, "round", "engine.call") is None
     assert span_sum_per.read({}, ["engine.guides"], "engine.call") is None
     assert counter_ratio.read({}, "engine.decode.tokens", "engine.decode.row_steps") is None
@@ -176,22 +177,25 @@ def test_from_run_reads_the_live_tracer(monkeypatch, tmp_path):
         t0 = time.perf_counter()
         with obs_tracer.span("round"):
             obs_tracer.complete("jax.trace", 0.001)
-        spans = types.SimpleNamespace(take=lambda name: [(time.perf_counter(), 0.0)])
-        rec = program_spans.from_run({"spans": spans, "counters": {"a": 1}})
+        rec = program_spans.from_run({"boot": {"setup_end": time.perf_counter()},
+                                      "counters": {"a": 1}})
     finally:
         obs_tracer.reset()
     assert rec.host == [] and rec.device == [] and rec.evicted == 0
     assert rec.counters == {"a": 1}
     assert [e[0] for e in rec.events] == ["jax.trace", "round"]
-    assert t0 <= rec.events[1][1] <= rec.events[1][2] <= rec.window_t0
-    assert rec.before_window(["round"]) == [(rec.events[1][1], rec.events[1][2])]
+    assert t0 <= rec.events[1][1] <= rec.events[1][2] <= rec.setup_end
+    assert rec.in_setup(["round"]) == [(rec.events[1][1], rec.events[1][2])]
 
 
 # ------------------------------------------------- the record from the chip
 
+RECORD = os.path.join(HERE, "data", "program_record.json")
+
+
 @pytest.fixture(scope="module")
 def recorded():
-    return program_spans.from_file(os.path.join(HERE, "data", "program_record.json"))
+    return program_spans.from_file(RECORD)
 
 
 def test_recorded_clocks_are_one(recorded):
@@ -207,16 +211,15 @@ def test_recorded_clocks_are_one(recorded):
         recorded, names["decode_program"], "engine.decode") >= 0.99
 
 
-def test_recorded_run_reads_every_new_metric(recorded):
-    """Through the metric files, as a run reads them (the autouse
-    fixture of ``benchmark/conftest.py`` hands the readers this
-    record)."""
+def test_recorded_run_reads_every_new_metric(recorded, monkeypatch):
+    """Through the metric files, as a run reads them, from the record."""
     import run
 
+    monkeypatch.setattr(program_spans, "SOURCE", lambda ctx: recorded)
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     new = ["round_host_s", "engine_host_s_per_call", "decode_tokens_per_row_step",
            "idle_unattributed_pct", "setup_weights_s", "setup_trace_lower_s",
-           "setup_rounds_s"]
+           "setup_rounds_s", "prefill_positions_real_share"]
     only = dict(bench, per_layer=[m for m in bench["per_layer"] if m["name"] in new])
     out = run.read_per_layer(only, "qwen3-8b-int8.lockstep", {})
     assert list(out) == new
@@ -225,11 +228,18 @@ def test_recorded_run_reads_every_new_metric(recorded):
     assert 0.9 < out["decode_tokens_per_row_step"]["value"] < 1.01
     assert 0.0 <= out["idle_unattributed_pct"]["value"] <= 100.0
     assert out["setup_trace_lower_s"]["value"] < out["setup_rounds_s"]["value"]
+    assert 0.5 < out["prefill_positions_real_share"]["value"] <= 1.0
+    # set-up's rounds are the one round that ends it
+    first = min(e[2] for e in recorded.events if e[0] == "round")
+    assert recorded.setup_end == first
+    assert out["setup_rounds_s"]["value"] == pytest.approx(
+        sum(e[2] - e[1] for e in recorded.events if e[0] == "round" and e[2] <= first))
     assert len(recorded.spans("round")) == 2 and len(recorded.spans("engine.call")) == 4
     for m in bench["per_layer"]:
         if m["name"] in new:
             spec = run.metric_file(bench, m["name"])
-            assert spec["env"]["BCG_TPU_TRACE"] == "1"
+            if m["source"] == "program_span":
+                assert spec["env"]["BCG_TPU_TRACE"] == "1"
             assert m["workloads"] == ["qwen3-8b-int8.lockstep"]
             assert (m["unit"], m["source"], m["layer"], m["moves"]) == \
                 (spec["unit"], spec["source"], spec["layer"], spec["moves"])

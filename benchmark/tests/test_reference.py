@@ -1,7 +1,7 @@
-"""The plain reference against the program at a size a test run can
-hold: same weights from the same seed, logits that agree to bfloat16's
-rounding, and a control (the precision below the stated one) that does
-not."""
+"""The plain reference that the tiny configuration's file names, against
+the program at a size a test run can hold: same weights from the same
+seed, logits that agree to bfloat16's rounding, and a control (the
+precision below the stated one) that does not."""
 
 import json
 import os
@@ -9,10 +9,11 @@ import os
 import numpy as np
 import pytest
 
-from lib import reference
+from lib import correct
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TINY = json.load(open(os.path.join(HERE, "configs", "tiny.json")))
+reference = correct.reference_for(TINY)
 SEED = 2 ** 31 + 77
 
 
@@ -81,7 +82,7 @@ def test_control_precision_is_told_apart():
     bfloat16 arithmetic does: the control cannot pass for the model."""
     tokens, lengths = _rows()
     ref = reference.logits(TINY, SEED, tokens, lengths, 259)
-    low = reference.logits(TINY, SEED, tokens, lengths, 259, "int4")
+    low = reference.logits(TINY, SEED, tokens, lengths, 259, TINY["control"]["weights"])
     i, n = 0, lengths[0]
     rel = np.abs(low[i, :n] - ref[i, :n]).max() / np.abs(ref[i, :n]).max()
     assert rel > 0.1

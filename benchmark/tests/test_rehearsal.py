@@ -81,6 +81,81 @@ def test_undeclared_shape_fails_the_run():
         run.run_cell(bench, cell, config, traffic, 2147404729, 6.0, False, DEVICE)
 
 
+# ------------------------------------------------------------ the band
+
+def _short(traffic: dict) -> dict:
+    """The tiny mix with a 100-token decide budget and one proved round:
+    rounds of a third of the time, for cases that play many."""
+    traffic = json.loads(json.dumps(traffic))
+    traffic["calls"]["decide"].update(max_tokens=100, decode_steps=[1, 99])
+    traffic["distinct_rounds"] = 1
+    return traffic
+
+
+BAND_SEED = 77
+
+
+@pytest.fixture(scope="module")
+def vote_lengths():
+    """The longest vote prompt of each round that set-up would play if
+    it kept none: game k on the k-th model of the seed's stream."""
+    from lib import system, window
+
+    _, _, config, traffic = _files()
+    sysm = system.System(config, _short(traffic), BAND_SEED)
+    try:
+        games = window.driver_for(sysm)
+        lengths = []
+        for _ in range(4):
+            lengths += [max(c.prompt_lens) for c in games.play(games.draw())
+                        if c.kind == "vote"]
+            sysm.remake_weights(sysm.weights_seed + window._REDRAW)
+        return lengths
+    finally:
+        sysm.close()
+
+
+@pytest.mark.parametrize("first_on_band", [True, False])
+def test_set_up_passes_over_off_band_rounds_and_ends_with_the_first(
+        vote_lengths, first_on_band, monkeypatch):
+    """Set-up passes over a round whose vote prompts are off the band,
+    goes on with the seed's next model and still proves a round;
+    ``setup_s`` ends with the first round played, on the band or off it,
+    and what is played after it is not in it."""
+    import time
+
+    import run
+
+    # the band holds one round's length alone: the first, or the first later
+    # one that differs from all before it
+    k = 0 if first_on_band else next(
+        (i for i, n in enumerate(vote_lengths) if i and n not in vote_lengths[:i]), None)
+    if k is None:
+        pytest.skip(f"no later round stands apart in {vote_lengths}")
+    bench, cell, config, traffic = _files()
+    traffic = _short(traffic)
+    traffic["calls"]["vote"]["prompt_band"] = [vote_lengths[k] - 1, vote_lengths[k]]
+    logged = []
+    monkeypatch.setattr(run, "log", lambda m: logged.append((time.perf_counter(), m)))
+    out = run.run_cell(bench, cell, config, traffic, BAND_SEED, 3.0, False, DEVICE)
+    # (``correct`` is not this case's: the limits were read at the 300-token budget)
+    assert out["failed"] == 0 and out["attempted"] >= 8
+    passed = [m for _t, m in logged if "off the band, passed over" in m]
+    assert len(passed) == k
+    stopped = [m for _t, m in logged if "stopped at a vote call" in m]
+    assert len(stopped) == max(0, k - 1)            # the first round runs whole
+    at, done = next((t, m) for t, m in logged if m.startswith("set-up done"))
+    assert f"0 round(s) passed over and {k} off the band" in done
+    setup_s = out["metrics"]["setup_s"]["value"]
+    if k:
+        # logged right after the clock was read at the first round's end
+        first_end = next(t for t, m in logged if "passed over" in m)
+        assert first_end - run.T_START == pytest.approx(setup_s, abs=1.0)
+        assert at - run.T_START - setup_s > 1.0 * k
+    else:
+        assert at - run.T_START == pytest.approx(setup_s, abs=1.0)
+
+
 def test_redraw_onto_declared_rungs():
     from lib import system
 

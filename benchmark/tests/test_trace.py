@@ -1,6 +1,6 @@
 """The trace reduction, on rows made by hand and on a small trace
 recorded on the chip (``data/trace_events.json``: the first events of
-each device line and the harness's spans of one traced 8B round)."""
+each device line and the harness's spans of one traced 8B window)."""
 
 import json
 import os
@@ -89,12 +89,13 @@ def _sweep_union_length(intervals, lo, hi):
 
 def test_recorded_trace():
     """The first 3,000 device operations, the programs and the harness's
-    spans of one traced 8B round on the v5e (my chip run, PR 25)."""
+    spans of one traced 8B window on the v5e (my chip run, PR 28;
+    ``tools/program_record.py --rows``)."""
     rows = json.load(open(os.path.join(HERE, "data", "trace_events.json")))
     r = trace.reduce_events(rows)
     ops = [(s, s + d) for p, l, n, s, d in rows if l == trace.OPS_LINE]
     rounds = [(s, s + d) for p, l, n, s, d in rows if n == trace.ROUND_SPAN]
-    lo, hi = rounds[0]
+    lo, hi = min(s for s, _ in rounds), max(e for _, e in rounds)
     assert r["devices"] == 1
     assert r["window_s"] == pytest.approx((hi - lo) * 1e-9)
     assert r["busy_s"] == pytest.approx(_sweep_union_length(ops, lo, hi) * 1e-9, rel=1e-9)

@@ -81,6 +81,7 @@ def play_seeds(config: dict, traffic: dict, seeds: list, fault_seeds: int) -> di
             "sound": correct.distinct_rows(calls, kinds),
             "invalid": correct.invalid_rows(calls),
             "calls": len(calls), "retried": proved["rounds_passed_over"],
+            "off_band": proved["rounds_off_band"],
             "weights_seed": sysm.weights_seed,
         }
         if n < fault_seeds:
@@ -91,6 +92,7 @@ def play_seeds(config: dict, traffic: dict, seeds: list, fault_seeds: int) -> di
             sysm.calls.clear()
         say(f"seed {seed}: {len(calls)} calls, {len(out[seed]['sound'])} rows to compare, "
             f"invalid {out[seed]['invalid']}, rounds passed over {out[seed]['retried']}, "
+            f"off the band {out[seed]['off_band']}, "
             f"{time.perf_counter() - t0:.1f}s")
     sysm.close()
     return out

@@ -6,7 +6,7 @@ run for this directory's tests.
 
     BCG_TPU_TRACE_OUT=chiprun_out/tracer.json python3 benchmark/run.py ... --trace 1
     python3 benchmark/tools/program_record.py chiprun_out/tracer.json \
-        [--dump chiprun_out/program_record.json]
+        [--dump chiprun_out/program_record.json] [--rows chiprun_out/trace_events.json]
 
 The run leaves its ``.xplane.pb`` under ``benchmark/.trace``; the
 tracer's own events (set-up's spans, ``jax.trace`` / ``jax.lower`` /
@@ -14,7 +14,9 @@ tracer's own events (set-up's spans, ``jax.trace`` / ``jax.lower`` /
 makes the program write when it exits.  ``--dump`` keeps the record
 small: of the device's operations only the merged busy intervals, of the
 tracer's intervals only those of a millisecond and more (a shorter one
-inside a longer one adds nothing to a union).
+inside a longer one adds nothing to a union).  ``--rows`` keeps a small
+trace for ``tests/data/trace_events.json``: the harness's spans, the
+programs, and the window's first ``ROWS_KEPT`` device operations.
 """
 
 from __future__ import annotations
@@ -46,14 +48,12 @@ def from_export(path: str) -> program_spans.Record:
     host = program_spans.host_rows(program_spans.newest_xplane(program_spans.TRACE_DIR))
     device = [r for r in trace.load_events(program_spans.TRACE_DIR)
               if trace.DEVICE_PLANE.match(r[0])]
-    # The window's rounds are the tracer's last ``round`` spans, as many
-    # as the profiler's trace holds.
-    in_window = sum(1 for n, _s, _d in host if n == program_spans.PREFIX + "round")
-    rounds = sorted(t0 for n, t0, _t1, _a in intervals if n == "round")
+    # Set-up ends with the first round the process played.
+    rounds = sorted(t1 for n, _t0, t1, _a in intervals if n == "round")
     return program_spans.Record(
         host=host, device=device, events=intervals,
         evicted=other["evicted_events"], counters=other["counters"],
-        window_t0=rounds[-in_window] if in_window else None)
+        setup_end=rounds[0] if rounds else None)
 
 
 def thin(rec: program_spans.Record) -> dict:
@@ -62,14 +62,26 @@ def thin(rec: program_spans.Record) -> dict:
                        if p == first and line == trace.OPS_LINE])
     device = [r for r in rec.device if r[0] == first and r[1] == trace.MODULES_LINE]
     device += [[first, trace.OPS_LINE, "busy", s, e - s] for s, e in ops]
-    keep = ("engine.decode.", "engine.hostsync.total", "game.retry.")
+    keep = ("engine.decode.", "engine.prefill.", "engine.hostsync.total", "game.retry.")
     return {
         "host": rec.host, "device": sorted(device, key=lambda r: r[3]),
         "events": [e for e in rec.events if e[2] - e[1] >= 1e-3],
         "evicted": rec.evicted,
         "counters": {k: v for k, v in rec.counters.items() if k.startswith(keep)},
-        "window_t0": rec.window_t0,
+        "setup_end": rec.setup_end,
     }
+
+
+ROWS_KEPT = 3000
+
+
+def small_trace(rows: list) -> list:
+    """Rows as ``lib.trace.load_events`` gives them, cut to a size a
+    test can hold."""
+    t0 = min(s for _p, _l, n, s, _d in rows if n == trace.ROUND_SPAN)
+    ops = sorted((r for r in rows if r[1] == trace.OPS_LINE and r[3] >= t0),
+                 key=lambda r: r[3])[:ROWS_KEPT]
+    return [r for r in rows if r[1] != trace.OPS_LINE] + ops
 
 
 def mean_s(spans: list) -> float:
@@ -80,6 +92,7 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("export")
     ap.add_argument("--dump")
+    ap.add_argument("--rows")
     ap.add_argument("--config", default="benchmark/configs/qwen3-8b-int8.json")
     args = ap.parse_args()
     rec = from_export(args.export)
@@ -104,7 +117,7 @@ def main() -> int:
             out["program_s"][name] = out["program_s"].get(name, 0.0) + d * program_spans.NS
     by_name: dict = {}
     for n, t0, t1, _a in rec.events:
-        if t1 <= rec.window_t0:
+        if t1 <= rec.setup_end:
             by_name.setdefault(n, []).append((t0, t1))
     out["setup_s_by_name"] = {n: [len(v), trace.total(trace.union(v))]
                               for n, v in sorted(by_name.items())}
@@ -112,6 +125,10 @@ def main() -> int:
     if args.dump:
         with open(args.dump, "w") as f:
             json.dump(thin(rec), f, separators=(",", ":"))
+    if args.rows:
+        with open(args.rows, "w") as f:
+            json.dump(small_trace(trace.load_events(program_spans.TRACE_DIR)), f,
+                      separators=(",", ":"))
     return 0
 
 

@@ -12,7 +12,7 @@ for set in 1 2; do
     line=$(python3 benchmark/run.py --workload "$w" --seed "$seed" --seconds "$secs" --trace 0 2>chiprun_out/last_stderr.txt | tail -1)
     rc=$?
     echo "{\"set\": $set, \"seed\": $seed, \"rc\": $rc, \"wall_s\": $(( $(date +%s) - s )), \"line\": ${line:-null}}" | tee -a "$out" | cut -c1-420
-    grep "set-up done\|window:\|passed over\|redrawn\|other tokens" chiprun_out/last_stderr.txt
+    grep "set-up done\|window:\|proved\|passed over\|redrawn\|other tokens" chiprun_out/last_stderr.txt
   done
 done
 for i in $(seq 1 "$traced"); do
