@@ -57,12 +57,15 @@ from bcg_tpu.obs import (
     tracer as obs_tracer,
 )
 from bcg_tpu.models.configs import (
+    FULL_ATTENTION,
     LARGE_MODEL_PARAMS,
+    LINEAR_ATTENTION,
     ModelSpec,
     spec_for_model,
 )
 from bcg_tpu.runtime import resilience
 from bcg_tpu.models.transformer import (
+    cache_bytes,
     decode_chunk,
     decode_step,
     init_kv_cache,
@@ -70,6 +73,7 @@ from bcg_tpu.models.transformer import (
     prefill,
     prefill_chunk_at,
     prefill_with_prefix,
+    probe_weight,
     stack_layer_params,
 )
 
@@ -235,6 +239,39 @@ def _kernel_fallback_warn(family: str, knob: str, detail: str,
 
 
 class JaxEngine(InferenceEngine):
+    def _refuse_unbuilt_for_hybrid(self, config, mesh) -> None:
+        """A spec with ``layer_types`` keeps two kinds of per-row state
+        (K/V in its full-attention layers, recurrent state and conv tail
+        in its delta-rule layers).  What is built for that is the plain
+        path: full or chunked prefill into a dense cache and the
+        one-token decode loop on one device.  Every option that would
+        need a state snapshot, a state rollback, a paged or a sharded
+        form of the second kind raises here, by name; nothing falls
+        back."""
+        from bcg_tpu.runtime.envflags import get_str
+
+        kv = (get_str("BCG_TPU_KV_DTYPE") or str(config.kv_cache_dtype)).strip().lower()
+        unbuilt = [why for on, why in (
+            (getattr(config, "prefix_caching", True),
+             "prefix_caching (a cached prefix's boundary would need a "
+             "snapshot of the recurrent state)"),
+            (getattr(config, "decode_fast_forward", False),
+             "decode_fast_forward (a decode chunk has no recurrent form)"),
+            (getattr(config, "spec_decode", False) or env_flag("BCG_TPU_SPEC"),
+             "spec_decode (a rejected draft would need the state rolled back)"),
+            (getattr(config, "paged_kv", False) or env_flag("BCG_TPU_PAGED_KV"),
+             "paged_kv (the block pool holds K/V only)"),
+            (kv == "int4", "kv_cache_dtype='int4'"),
+            (config.quantization == "int4", "quantization='int4'"),
+            (mesh is not None and mesh.size > 1,
+             "a multi-device mesh (tensor, sequence or data parallel: the "
+             "recurrent state has no sharded form)"),
+        ) if on]
+        if unbuilt:
+            raise ValueError(
+                f"{self.spec.name} is a hybrid (layer_types) spec; not built "
+                "for it: " + "; ".join(unbuilt))
+
     def __init__(self, config, mesh=None, params=None, spec: Optional[ModelSpec] = None):
         _enable_compilation_cache()
         self.config = config
@@ -260,6 +297,8 @@ class JaxEngine(InferenceEngine):
             )
         self.tokenizer: Tokenizer = tokenizer_for_model(config.model_name)
         self.mesh = mesh
+        if self.spec.hybrid:
+            self._refuse_unbuilt_for_hybrid(config, mesh)
         # Kernel eligibility, decided ONCE here from what the engine can
         # observe — backend, head dim, mesh — and never again at trace
         # time: the ops run the kernel they are handed or raise.  Under a
@@ -452,6 +491,9 @@ class JaxEngine(InferenceEngine):
             self._kv_slot_bytes = self.spec.num_kv_heads * (2 * self.spec.head_dim + 8)
         else:
             self._kv_slot_bytes = self.spec.num_kv_heads * self.spec.head_dim * 4
+        # Layers that hold K/V: all of them, or a hybrid's full-attention
+        # ones (its other layers' state is counted by cache_bytes).
+        self._kv_layers = self.spec.layers_of(FULL_ATTENTION)
         self.max_model_len = config.max_model_len
         # Forced-chain fast-forward (guided/processor.py FF_CHUNK): each
         # decode step carries the sampled token plus its DFA-forced
@@ -651,11 +693,10 @@ class JaxEngine(InferenceEngine):
             # like an owned one, without consuming the donor's copy.)
             from bcg_tpu.models.quantize import is_int4, is_quantized
 
-            wq = (self.params["layers"]["wq"] if layers_stacked(self.params)
-                  else self.params["layers"][0]["wq"])
+            probe = probe_weight(self.params)
             tree_mode = (
-                ("int4" if is_int4(wq) else "int8")
-                if is_quantized(wq) else None
+                ("int4" if is_int4(probe) else "int8")
+                if is_quantized(probe) else None
             )
             mismatch = tree_mode != quant_mode and not (
                 tree_mode is None and not layers_stacked(self.params)
@@ -682,7 +723,7 @@ class JaxEngine(InferenceEngine):
             # twice, and only consume (free-as-we-go) a tree this engine
             # created itself.
             with self._boot.phase("quantize"):
-                if not is_quantized(self.params["layers"][0]["wq"]):
+                if not is_quantized(probe_weight(self.params)):
                     self.params = quantize_params(
                         self.params, self.spec, consume=owns_params,
                         mode=quant_mode, mesh=mesh,
@@ -803,6 +844,17 @@ class JaxEngine(InferenceEngine):
 
         # jit entry points (shape-polymorphic via jax.jit's trace cache).
         self._prefill_impl = self._kernel_impl(self.attention_impl)
+        if self.spec.hybrid:
+            from bcg_tpu.ops import HybridImpl, gated_delta
+
+            # One switch for the Pallas kernels: where flash prefill
+            # runs, the chunkwise delta-rule kernel runs; elsewhere its
+            # XLA twin (the same chunk mathematics as a scan).
+            self._prefill_impl = HybridImpl(
+                self._prefill_impl,
+                gated_delta.PALLAS if self.attention_impl == "pallas"
+                else gated_delta.XLA,
+            )
         # Named after their obs_hlo census entries: every program of
         # the prefill family carries "prefill" in the profiler's trace.
         self._prefill = jax.jit(
@@ -944,6 +996,7 @@ class JaxEngine(InferenceEngine):
         self._kv_budget_warned = False
         self._mesh_devices = mesh.size if mesh is not None else 1
         self._kv_bytes_memo: Dict[Tuple[int, int], int] = {}
+        self._cache_bytes_memo: Dict[Tuple[int, int], Dict[str, int]] = {}
         self._param_bytes = sum(
             getattr(p, "nbytes", 0) for p in jax.tree.leaves(self.params)
         )
@@ -2485,6 +2538,14 @@ class JaxEngine(InferenceEngine):
         FULL unsharded cache on one device first — a transient dp× spike
         on exactly the large-batch configs dp exists to fit."""
         kw = dict(quantized=self.kv_quantized, stacked=self.scan_layers)
+        by_kind = self._cache_bytes(B, S)
+        obs_counters.inc("engine.cache.kv_bytes", by_kind["kv"])
+        if by_kind["linear_state"]:
+            obs_counters.inc(
+                "engine.cache.linear_state_bytes", by_kind["linear_state"])
+            obs_counters.inc(
+                "engine.linear.state_rows",
+                B * self.spec.layers_of(LINEAR_ATTENTION))
         if self.mesh is None or self._mesh_devices <= 1:
             return init_kv_cache(self.spec, B, S, **kw)
         key = (B, S)
@@ -2550,6 +2611,13 @@ class JaxEngine(InferenceEngine):
         obs_counters.inc(
             "engine.prefill.positions_run", B * (L - starts.start)
         )
+        if self.spec.hybrid:
+            # rows x positions x linear layers through the delta-rule
+            # prefill (the chunkwise kernel, or its XLA twin)
+            obs_counters.inc(
+                "engine.linear.prefill_positions",
+                B * (L - starts.start) * self.spec.layers_of(LINEAR_ATTENTION),
+            )
         if not C or L <= C:
             if has_prefix:
                 from bcg_tpu.models.transformer import _cache_len
@@ -3159,7 +3227,7 @@ class JaxEngine(InferenceEngine):
         self.prefill_tokens += B * (L if (prepped is None and not paged) else Ls)
         self.prefill_seconds += t1 - t0
         self.decode_seconds += t2 - t1
-        self.decode_kv_bytes += steps * B * S * slot_bytes * spec.num_layers
+        self.decode_kv_bytes += steps * B * S * slot_bytes * self._kv_layers
         self.decode_weight_passes += steps
         texts = []
         served = 0
@@ -3180,12 +3248,26 @@ class JaxEngine(InferenceEngine):
         obs_counters.inc("engine.decode.row_steps", steps * real_B)
         return texts
 
+    def _cache_bytes(self, B: int, S: int) -> Dict[str, int]:
+        """Bytes of a [B, S] decode cache by kind of state, read off the
+        allocation's own shapes (``transformer.cache_bytes``): what the
+        allocation counters and, for a hybrid, the row cap count."""
+        got = self._cache_bytes_memo.get((B, S))
+        if got is None:
+            got = self._cache_bytes_memo[(B, S)] = cache_bytes(
+                self.spec, B, S,
+                quantized=self.kv_quantized, stacked=self.scan_layers,
+            )
+        return got
+
     def _kv_bytes_per_device(self, B: int, S: int) -> int:
         """Per-device decode-cache bytes for a [B, S] cache under the
         layout ``kv_cache_tree_sharding`` ACTUALLY places — an axis that
         fails its divisibility guard (Hkv % tp, S % sp, B % dp)
         replicates and does NOT divide.  Memoized per (B, S): eval_shape
         is cheap but this sits on every generation call's cap path."""
+        if self.spec.hybrid:
+            return sum(self._cache_bytes(B, S).values())
         if self.mesh is None or self._mesh_devices <= 1:
             return B * S * self._kv_slot_bytes * self.spec.num_layers
         key = (B, S)
@@ -3262,7 +3344,7 @@ class JaxEngine(InferenceEngine):
         tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
         div = tp if tp > 1 and self.spec.num_kv_heads % tp == 0 else 1
         block_bytes = max(
-            1, block_size * self._kv_slot_bytes * self.spec.num_layers // div
+            1, block_size * self._kv_slot_bytes * self._kv_layers // div
         )
         if self._mem_limit:
             budget = (
@@ -3436,7 +3518,7 @@ class JaxEngine(InferenceEngine):
         # window (max_model_len - min - 1) plus the batch-wide decode
         # reservation.
         S = self.max_model_len - min(budgets) - 1 + decode_res
-        kv_total = B * S * self._kv_slot_bytes * spec.num_layers
+        kv_total = B * S * self._kv_slot_bytes * self._kv_layers
         per_device = (
             self._kv_bytes_per_device(B, S) + self._param_bytes_per_device
         )
@@ -3597,6 +3679,11 @@ class JaxEngine(InferenceEngine):
             build_plan,
         )
 
+        if self.spec.hybrid:
+            raise MegaroundUnsupported(
+                f"{self.spec.name} is a hybrid (layer_types) spec: the "
+                "fused round's in-trace caches hold K/V only"
+            )
         if self._paged is not None:
             raise MegaroundUnsupported(
                 "paged-KV engine (the fused round allocates dense "

@@ -12,7 +12,6 @@ on local disk (HF cache layout or a flat directory of ``*.safetensors``).
 
 from __future__ import annotations
 
-import math
 import os
 from typing import Dict, Optional
 
@@ -94,6 +93,11 @@ def load_checkpoint_params(
     ``ckpt_dir``: a pre-resolved checkpoint directory (skips the
     candidate walk a caller already did via :func:`find_checkpoint_dir`).
     """
+    if spec.hybrid:
+        raise ValueError(
+            f"{spec.name}: no checkpoint loader is built for a spec with "
+            "layer_types (the name map below is the dense family's); serve "
+            "its shapes with random weights through a bcg-tpu/* preset")
     if ckpt_dir is None:
         ckpt_dir = find_checkpoint_dir(model_name)
     if ckpt_dir is None:
@@ -235,7 +239,9 @@ def init_random_params_sharded(
     differ bit-wise from ``transformer.init_params``'s legacy-RNG
     output; no golden-value contract exists for random weights.
     """
-    from bcg_tpu.models.transformer import assemble_param_tree, param_plan
+    from bcg_tpu.models.transformer import (
+        RANDOM_KINDS, assemble_param_tree, init_leaf, param_plan, plan_keys,
+    )
 
     sharding_for = None
     if mesh is not None:
@@ -244,8 +250,8 @@ def init_random_params_sharded(
         sharding_for = lambda logical: param_sharding(logical, spec, mesh)  # noqa: E731
 
     plan = param_plan(spec)
-    keys = jax.random.split(key, 4 + spec.num_layers * 7)
-    spare_key = keys[-1]  # never consumed by the plan; feeds ones/zeros jits
+    keys = plan_keys(spec, key, plan)
+    spare_key = keys[-1]  # feeds the constant leaves' jits, which ignore it
     ki = 0
     fns: Dict = {}
     items = []
@@ -254,7 +260,7 @@ def init_random_params_sharded(
     try:
         for logical, kind, shape in plan:
             leaf = logical.split(".")[-1]
-            if kind == "dense":
+            if kind in RANDOM_KINDS:
                 k = keys[ki]
                 ki += 1
             else:
@@ -265,20 +271,13 @@ def init_random_params_sharded(
             if fn is None:
 
                 def _init(k, _kind=kind, _shape=shape, _logical=logical):
-                    if _kind == "dense":
-                        w = (
-                            jax.random.normal(k, _shape, jnp.float32)
-                            / math.sqrt(_shape[0])
-                        ).astype(dtype)
-                        # Dense leaves only, like init_params and
-                        # boot_peak_report — the three param_plan
-                        # consumers must agree on what transforms.
-                        if leaf_transform is not None:
-                            w = leaf_transform(_logical, w)
-                        return w
-                    if _kind == "ones":
-                        return jnp.ones(_shape, dtype)
-                    return jnp.zeros(_shape, dtype)
+                    w = init_leaf(_kind, _shape, k, dtype)
+                    # Dense leaves only, like init_params and
+                    # boot_peak_report — the three param_plan
+                    # consumers must agree on what transforms.
+                    if _kind == "dense" and leaf_transform is not None:
+                        w = leaf_transform(_logical, w)
+                    return w
 
                 out_shardings = None
                 if sharding_for is not None:
@@ -338,7 +337,7 @@ def boot_peak_report(
     ``tests/test_born_sharded.py`` and ``scripts/boot_smoke.py`` against
     the components reported here.
     """
-    from bcg_tpu.models.transformer import param_plan
+    from bcg_tpu.models.transformer import RANDOM_KINDS, param_plan
 
     transform = None
     if quantization is not None:
@@ -358,7 +357,8 @@ def boot_peak_report(
     max_transient_leaf = None
     group_bytes: Dict[str, int] = {}
     for logical, kind, shape in param_plan(spec):
-        src_dtype = jnp.float32 if kind == "dense" else dtype
+        # every random leaf is drawn in float32, then cast
+        src_dtype = jnp.float32 if kind in RANDOM_KINDS else dtype
 
         def _make(w, _logical=logical, _kind=kind):
             w = w.astype(dtype)
@@ -384,7 +384,7 @@ def boot_peak_report(
         # (out_shardings propagate back through the elementwise chain).
         transient = (
             _shard_bytes(src, sharding_for(logical) if sharding_for else None)
-            if kind == "dense"
+            if kind in RANDOM_KINDS
             else 0
         )
         init_peak = max(init_peak, done + transient + out_b)

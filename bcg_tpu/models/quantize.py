@@ -55,7 +55,12 @@ from bcg_tpu.obs import tracer as obs_tracer
 QuantizedDense = Dict[str, jax.Array]
 DenseWeight = Union[jax.Array, QuantizedDense]
 
-_QUANT_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# The block matmuls that quantize: the dense family's seven, and the five
+# projections of a hybrid's delta-rule layer (its two per-head gate
+# projections, 30 columns wide, its conv taps and its vectors stay as
+# they are).
+_QUANT_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                 "lin_wq", "lin_wk", "lin_wv", "lin_wg", "lin_wo")
 
 INT4_GROUP = 128
 

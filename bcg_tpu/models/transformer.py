@@ -15,6 +15,14 @@ TPU-first design choices:
   XLA path (einsum softmax einsum — XLA fuses it well on MXU) or the
   Pallas flash kernel in :mod:`bcg_tpu.ops.attention`.
 
+Two block families share this file (``models/configs.py``): the dense
+one above (``_block``; every layer the same) and hybrids whose spec
+states ``layer_types`` — a block per layer type from ``_HYBRID_BLOCKS``
+(full attention reusing ``_attend``; the gated delta rule of
+``ops/gated_delta.py``), two kinds of per-row state in the cache (K/V
+for the full layers, recurrent state and conv tail for the linear
+ones), and a layer scan over the PERIODS of the pattern.
+
 Replaces the CUDA side of the reference's engine (vLLM internals behind
 ``vllm_agent.py:100-157``); no reference code exists at this layer.
 """
@@ -22,26 +30,59 @@ Replaces the CUDA side of the reference's engine (vLLM internals behind
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from functools import partial
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from bcg_tpu.models.configs import ModelSpec
+from bcg_tpu.models.configs import FULL_ATTENTION, LINEAR_ATTENTION, ModelSpec
 from bcg_tpu.models.quantize import dense
 from bcg_tpu.obs import tracer as obs_tracer
-from bcg_tpu.ops import impl_mesh, is_pallas
+from bcg_tpu.ops import HybridImpl, impl_mesh, is_pallas
 
 TransformerParams = Dict  # pytree: see init_params for the layout
 
 
 # ----------------------------------------------------------------- building
 
+# Leaf kinds that draw from a key (see init_leaf); the rest are constants.
+RANDOM_KINDS = ("dense", "gate", "conv", "a_log", "dt_bias")
+
+# A post-norm stack adds a unit-RMS vector to the residual stream per
+# sublayer and feeds it to the next layer un-normalised: at 32 layers its
+# RMS reaches 8.  The delta-rule layer's two per-head gate projections
+# are drawn that much smaller, so that their pre-activations stay of
+# order 1 at every depth (see init_leaf).
+_GATE_DAMP = 8.0
+
+# Norm vectors of a hybrid that are not ones.  A random delta-rule layer
+# reads its state through the current token's query and answers the
+# last few tokens; softmax attention over a 2,000-token prompt answers
+# their mean; a post-norm stack rescales both answers to one size.  With
+# every norm vector at one the prompt's mean carries a fifth of the
+# logits' variance over a string's positions (the dense family: nine
+# tenths): the next-byte distribution is drawn afresh at every step and
+# every seed's model closes its guided strings alike.  The vector on a
+# full-attention mixer's output at 4 makes that share four fifths, and
+# the final one at 2 sharpens the logits, so that whether a string
+# closes is the seed's model's, as in the dense family (PERF.md
+# section 6, PR 29).
+_FULL_MIXER_NORM = 4.0
+_FINAL_NORM = 2.0
+_CONSTANT_KINDS = {"ones": 1.0, "zeros": 0.0, "full_mixer_norm": _FULL_MIXER_NORM,
+                   "hybrid_final_norm": _FINAL_NORM}
+
+
 def param_plan(spec: ModelSpec):
     """Ordered ``(logical_name, init_kind, shape)`` triples for every
     leaf :func:`init_params` creates — ``init_kind`` is ``"dense"``
-    (random, scaled by 1/sqrt(fan_in)), ``"ones"`` (norm vectors) or
-    ``"zeros"`` (projection biases).
+    (random, scaled by 1/sqrt(fan_in)), a constant of
+    ``_CONSTANT_KINDS`` (``"ones"``: norm vectors; ``"zeros"``:
+    projection biases; a hybrid's two norm vectors that are not ones)
+    or, in a hybrid's delta-rule layers, ``"gate"``, ``"conv"``,
+    ``"a_log"`` and ``"dt_bias"``
+    (:func:`init_leaf`).
 
     This is the single source of truth for the parameter layout: the
     eager initializer (:func:`init_params`), the born-sharded
@@ -50,9 +91,18 @@ def param_plan(spec: ModelSpec):
     all iterate it, so creation order, key consumption and shapes
     cannot drift between the materializing and the abstract paths.
 
-    Key-consumption contract: dense leaves consume one key each, in
-    plan order, from ``jax.random.split(key, 4 + num_layers * 7)``.
+    Two key-consumption contracts (:func:`plan_keys`), each restated by
+    the benchmark's plain reference of its family:
+
+    * the dense family (no ``layer_types``): dense leaves consume one
+      key each, in plan order, from ``jax.random.split(key, 4 +
+      num_layers * 7)`` (``benchmark/references/dense_gqa.py``);
+    * a hybrid: every random leaf (``RANDOM_KINDS``) consumes one key,
+      in plan order, from ``jax.random.split(key, n)`` with ``n`` the
+      count of such leaves (``benchmark/references/olmo_hybrid.py``).
     """
+    if spec.hybrid:
+        return _hybrid_param_plan(spec)
     plan = [
         ("embed", "dense", (spec.vocab_size, spec.hidden_size)),
         ("final_norm", "ones", (spec.hidden_size,)),
@@ -84,6 +134,101 @@ def param_plan(spec: ModelSpec):
     if not spec.tie_embeddings:
         plan.append(("lm_head", "dense", (spec.hidden_size, spec.vocab_size)))
     return plan
+
+
+def _hybrid_param_plan(spec: ModelSpec):
+    """The plan of a spec with ``layer_types``: each layer's leaves by
+    its type.  Both kinds carry the two sublayer norms and the SwiGLU
+    half; a linear layer's mixer is the gated delta rule's (five
+    projections, the two per-head gates, the depthwise conv's taps,
+    ``A_log``, ``dt_bias`` and the per-head output norm)."""
+    D = spec.hidden_size
+    plan = [
+        ("embed", "dense", (spec.vocab_size, D)),
+        ("final_norm", "hybrid_final_norm", (D,)),
+    ]
+    H = spec.linear_num_value_heads
+    for li, kind in enumerate(spec.layer_types):
+        pre = f"layers.{li}."
+        shapes = spec.matmul_shapes(kind)
+        dense_leaf = lambda name: (pre + name, "dense", shapes[name])  # noqa: E731
+        if kind == LINEAR_ATTENTION:
+            plan += [
+                dense_leaf("lin_wq"), dense_leaf("lin_wk"), dense_leaf("lin_wv"),
+                (pre + "lin_wa", "gate", shapes["lin_wa"]),
+                (pre + "lin_wb", "gate", shapes["lin_wb"]),
+                (pre + "lin_conv", "conv",
+                 (spec.linear_conv_kernel_dim, spec.linear_conv_size)),
+                (pre + "lin_a_log", "a_log", (H,)),
+                (pre + "lin_dt_bias", "dt_bias", (H,)),
+                dense_leaf("lin_wg"),
+                (pre + "lin_out_norm", "ones", (spec.linear_value_head_dim,)),
+                dense_leaf("lin_wo"),
+            ]
+        else:
+            plan += [dense_leaf(n) for n in ("wq", "wk", "wv", "wo")]
+            if spec.qk_norm:
+                full = spec.qk_norm == "full"
+                plan += [
+                    (pre + "q_norm", "ones", (spec.q_size if full else spec.head_dim,)),
+                    (pre + "k_norm", "ones", (spec.kv_size if full else spec.head_dim,)),
+                ]
+        plan += [
+            (pre + "attn_norm",
+             "ones" if kind == LINEAR_ATTENTION else "full_mixer_norm", (D,)),
+            (pre + "mlp_norm", "ones", (D,)),
+            dense_leaf("w_gate"), dense_leaf("w_up"), dense_leaf("w_down"),
+        ]
+    if not spec.tie_embeddings:
+        plan.append(("lm_head", "dense", (D, spec.vocab_size)))
+    return plan
+
+
+def plan_keys(spec: ModelSpec, key: jax.Array, plan=None) -> jax.Array:
+    """The keys :func:`param_plan`'s random leaves consume, in plan
+    order, under the family's contract (see :func:`param_plan`)."""
+    if not spec.hybrid:
+        return jax.random.split(key, 4 + spec.num_layers * 7)
+    plan = param_plan(spec) if plan is None else plan
+    return jax.random.split(key, sum(kind in RANDOM_KINDS for _, kind, _ in plan))
+
+
+def init_leaf(kind: str, shape, key: jax.Array, dtype) -> jax.Array:
+    """One leaf of the plan from its key (constants ignore it).
+
+    ``dense``: normal / sqrt(fan_in).  The delta-rule layer's own:
+    ``gate`` (the two per-head gate projections) normal / (8
+    sqrt(fan_in)); ``conv`` taps normal / 2; ``a_log`` = log U(1, 16) and
+    ``dt_bias`` the inverse softplus of U(0.001, 0.1).  With the gates'
+    pre-activations of order 1, ``softplus(. + dt_bias)`` stays near
+    U(0.001, 0.1) times e^(+-1) and a head's decay ``exp(-exp(a_log)
+    softplus(.))`` covers long memory (0.9996) and short (0.02), and the
+    write strength ``2 sigmoid(.)`` passes 1 about half the time.  Gates
+    at the matrices' own scale saturate instead: on a residual stream of
+    RMS 8 nearly every decay is 0, and a rounding error of 0.02 in the
+    pre-activation moves ``g`` by 16 times that (measured: the W8A8
+    model's greedy tokens then lie up to 2.3 below the float32
+    reference's best, against 4.4 for int4 weights)."""
+    if kind == "dense":
+        return (
+            jax.random.normal(key, shape, jnp.float32) / math.sqrt(shape[0])
+        ).astype(dtype)
+    if kind == "gate":
+        return (
+            jax.random.normal(key, shape, jnp.float32)
+            / (_GATE_DAMP * math.sqrt(shape[0]))
+        ).astype(dtype)
+    if kind == "conv":
+        return (jax.random.normal(key, shape, jnp.float32) / 2.0).astype(dtype)
+    if kind == "a_log":
+        return jnp.log(
+            jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
+    if kind == "dt_bias":
+        dt = jax.random.uniform(key, shape, jnp.float32, 0.001, 0.1)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    if kind in _CONSTANT_KINDS:
+        return jnp.full(shape, _CONSTANT_KINDS[kind], dtype)
+    raise ValueError(f"unknown init kind {kind!r}")
 
 
 def assemble_param_tree(items) -> TransformerParams:
@@ -119,6 +264,7 @@ def init_params(
       layers.l.w_gate/w_up [D, F]   layers.l.w_down [F, D]
       final_norm       [D]
       lm_head          [D, V]       (absent when tie_embeddings)
+    (a hybrid's layers: :func:`_hybrid_param_plan`.)
 
     ``leaf_transform(logical_name, tensor)`` (same hook as the streamed
     checkpoint loader) is applied to each dense weight AS IT IS CREATED,
@@ -136,20 +282,18 @@ def init_params(
     partitionable RNG for mesh-shape invariance); random weights carry
     no golden-value contract.
     """
-    keys = iter(jax.random.split(key, 4 + spec.num_layers * 7))
+    plan = param_plan(spec)
+    keys = iter(plan_keys(spec, key, plan))
 
     def build(logical, kind, shape):
-        if kind == "dense":
-            w = (
-                jax.random.normal(next(keys), shape, jnp.float32)
-                / math.sqrt(shape[0])
-            ).astype(dtype)
-            return leaf_transform(logical, w) if leaf_transform else w
-        return (jnp.ones if kind == "ones" else jnp.zeros)(shape, dtype)
+        w = init_leaf(
+            kind, shape, next(keys) if kind in RANDOM_KINDS else None, dtype)
+        if kind == "dense" and leaf_transform:
+            return leaf_transform(logical, w)
+        return w
 
     return assemble_param_tree(
-        (logical, build(logical, kind, shape))
-        for logical, kind, shape in param_plan(spec)
+        (logical, build(logical, kind, shape)) for logical, kind, shape in plan
     )
 
 
@@ -239,28 +383,58 @@ def stack_layer_params(
                 )
                 return fn(leaves)
 
+    def stack(layers) -> Dict:
+        stacked: Dict = {}
+        for name in list(layers[0].keys()):
+            if consume:
+                leaves = [l.pop(name) for l in layers]
+            else:
+                leaves = [l[name] for l in layers]
+            if stack_group is not None:
+                stacked[name] = stack_group(name, leaves)
+            elif isinstance(leaves[0], dict):  # quantized {"q", "scale"}
+                stacked[name] = {
+                    k: jnp.stack([lv[k] for lv in leaves]) for k in leaves[0]
+                }
+            else:
+                stacked[name] = jnp.stack(leaves)
+            del leaves
+        return stacked
+
     out = dict(params)
-    stacked: Dict = {}
-    for name in list(layers[0].keys()):
-        if consume:
-            leaves = [l.pop(name) for l in layers]
-        else:
-            leaves = [l[name] for l in layers]
-        if stack_group is not None:
-            stacked[name] = stack_group(name, leaves)
-        elif isinstance(leaves[0], dict):  # quantized {"q", "scale"}
-            stacked[name] = {
-                k: jnp.stack([lv[k] for lv in leaves]) for k in leaves[0]
-            }
-        else:
-            stacked[name] = jnp.stack(leaves)
-        del leaves
-    out["layers"] = stacked
+    kinds = [layer_kind(l) for l in layers]
+    if LINEAR_ATTENTION in kinds:
+        # A hybrid stacks BY TYPE, each type's layers in model order:
+        # the scan walks periods and indexes each type's stack.
+        if mesh is not None:
+            raise ValueError(
+                "stack_layer_params: a hybrid tree has no sharded stacking")
+        out["layers"] = {
+            kind: stack([l for l, k in zip(layers, kinds) if k == kind])
+            for kind in dict.fromkeys(kinds)
+        }
+    else:
+        out["layers"] = stack(layers)
     return out
+
+
+def layer_kind(layer: Dict) -> str:
+    """A layer's type, told from its leaves."""
+    return LINEAR_ATTENTION if "lin_wq" in layer else FULL_ATTENTION
 
 
 def layers_stacked(params: TransformerParams) -> bool:
     return isinstance(params["layers"], dict)
+
+
+def probe_weight(params: TransformerParams):
+    """One block matmul weight of the tree (``w_down``: every layer of
+    every family has it), list form or stacked: what tells a tree's
+    quantization format."""
+    layers = params["layers"]
+    if not isinstance(layers, dict):
+        return layers[0]["w_down"]
+    return (layers if "w_down" in layers else next(iter(layers.values())))["w_down"]
 
 
 # ------------------------------------------------------------------ kernels
@@ -300,6 +474,15 @@ def rope_table(
         inv_freq = scaled
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def _rope_for(spec: ModelSpec, positions: jax.Array):
+    """cos/sin for a spec's rotary embedding, or ``(None, None)`` where
+    it has none (``rope_theta=None``): the blocks then skip
+    :func:`apply_rope`; nothing is fed a stand-in theta."""
+    if spec.rope_theta is None:
+        return None, None
+    return rope_table(positions, spec.head_dim, spec.rope_theta, spec.rope_scaling)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -554,9 +737,44 @@ def _block(
     if spec.qk_norm:
         q = rms_norm(q, layer["q_norm"], spec.rms_eps)
         k = rms_norm(k, layer["k_norm"], spec.rms_eps)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cos is not None:
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
+    attn_out, new_entry = _attend(
+        spec, q, k, v, kv_write_pos, cache_entry, attn_mask, impl,
+        hist_len=hist_len, ring=ring, kv_valid=kv_valid,
+    )
+    x = x + dense(attn_out.reshape(B, T, spec.q_size), layer["wo"])
+
+    x = x + _swiglu(layer, rms_norm(x, layer["mlp_norm"], spec.rms_eps))
+    return x, new_entry
+
+
+def _swiglu(layer: Dict, h: jax.Array) -> jax.Array:
+    """The SwiGLU MLP on its (normed) input: every block's second half."""
+    gate = jax.nn.silu(dense(h, layer["w_gate"]))
+    return dense(gate * dense(h, layer["w_up"]), layer["w_down"])
+
+
+def _attend(
+    spec: ModelSpec,
+    q: jax.Array,              # [B, T, H, Dh], positions applied
+    k: jax.Array,              # [B, T, Hkv, Dh]
+    v: jax.Array,
+    kv_write_pos: jax.Array,
+    cache_entry: Dict,
+    attn_mask: jax.Array,
+    impl: str,
+    hist_len: int = 0,
+    ring=None,
+    kv_valid=None,
+) -> Tuple[jax.Array, Dict]:
+    """Write the fresh K/V into the layer's cache entry and attend: the
+    attention of :func:`_block` (arguments as there), shared with the
+    hybrid family's full-attention block.  Returns the attention output
+    [B, T, H, Dh] and the updated entry."""
+    T = q.shape[1]
     new_entry = _write_cache(cache_entry, k, v, kv_write_pos)
 
     scale = 1.0 / math.sqrt(spec.head_dim)
@@ -616,12 +834,177 @@ def _block(
         )[:, None]
     else:
         attn_out = _cache_attention(q, new_entry, attn_mask, scale, impl)
-    x = x + dense(attn_out.reshape(B, T, spec.q_size), layer["wo"])
+    return attn_out, new_entry
 
-    h = rms_norm(x, layer["mlp_norm"], spec.rms_eps)
-    gate = jax.nn.silu(dense(h, layer["w_gate"]))
-    x = x + dense(gate * dense(h, layer["w_up"]), layer["w_down"])
-    return x, new_entry
+
+# ------------------------------------------------------- the hybrid family
+
+class _Ctx(NamedTuple):
+    """What a hybrid block needs of the call beside its own layer and
+    its own cache entry."""
+    cos: Optional[jax.Array]
+    sin: Optional[jax.Array]
+    write_pos: jax.Array           # cache slot of the chunk's column 0
+    attn_mask: jax.Array           # as :func:`_block` takes it
+    hist_len: int
+    valid: Optional[jax.Array]     # [B, T] bool, False on pads; None in a
+                                   # decode step (every row's token counts)
+    impl: HybridImpl               # what each kind of layer runs
+
+
+def _norm_in(spec: ModelSpec, x, weight):
+    """The sublayer's input under the spec's norm placement."""
+    return x if spec.norm_placement == "post" else rms_norm(x, weight, spec.rms_eps)
+
+
+def _norm_out(spec: ModelSpec, y, weight):
+    """The sublayer's output under the spec's norm placement."""
+    return rms_norm(y, weight, spec.rms_eps) if spec.norm_placement == "post" else y
+
+
+def _mlp_sublayer(layer: Dict, spec: ModelSpec, x: jax.Array) -> jax.Array:
+    y = _swiglu(layer, _norm_in(spec, x, layer["mlp_norm"]))
+    return x + _norm_out(spec, y, layer["mlp_norm"])
+
+
+def _block_full(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
+                ctx: _Ctx) -> Tuple[jax.Array, Dict]:
+    """A hybrid's full-attention layer: the dense family's attention
+    (:func:`_attend`: same cache entry, same kernels) under the spec's
+    norm placement, q/k norm form and rotary setting."""
+    B, T, _ = x.shape
+    h = _norm_in(spec, x, layer["attn_norm"])
+    q, k, v = dense(h, layer["wq"]), dense(h, layer["wk"]), dense(h, layer["wv"])
+    if spec.qk_norm == "full":   # over the whole projection, before the split
+        q = rms_norm(q, layer["q_norm"], spec.rms_eps)
+        k = rms_norm(k, layer["k_norm"], spec.rms_eps)
+    q = q.reshape(B, T, spec.num_heads, spec.head_dim)
+    k = k.reshape(B, T, spec.num_kv_heads, spec.head_dim)
+    v = v.reshape(B, T, spec.num_kv_heads, spec.head_dim)
+    if spec.qk_norm is True:     # per head
+        q = rms_norm(q, layer["q_norm"], spec.rms_eps)
+        k = rms_norm(k, layer["k_norm"], spec.rms_eps)
+    if ctx.cos is not None:
+        q = apply_rope(q, ctx.cos, ctx.sin)
+        k = apply_rope(k, ctx.cos, ctx.sin)
+    attn_out, new_entry = _attend(
+        spec, q, k, v, ctx.write_pos, entry, ctx.attn_mask, ctx.impl.attention,
+        hist_len=ctx.hist_len,
+    )
+    y = dense(attn_out.reshape(B, T, spec.q_size), layer["wo"])
+    x = x + _norm_out(spec, y, layer["attn_norm"])
+    return _mlp_sublayer(layer, spec, x), new_entry
+
+
+def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
+                       ctx: _Ctx) -> Tuple[jax.Array, Dict]:
+    """A hybrid's linear-attention layer: the gated delta rule
+    (``ops/gated_delta.py``).  Its cache entry is the recurrent state
+    ``S`` [B, H, dv, dk] float32 and the conv tail ``conv`` [B, K-1,
+    channels] (the last K-1 inputs of the depthwise causal conv).
+
+    A pad position (``ctx.valid`` False) leaves both as they were: its
+    conv input is zero (what the conv sees before a sequence starts,
+    and pads stand only before a row's tokens), its decay is 0 and its
+    write strength 0.  So a row's state is that of the row alone, and
+    a chunk of pads maps zeros to zeros."""
+    from bcg_tpu.ops.gated_delta import gated_delta_prefill, gated_delta_step
+
+    B, T, _ = x.shape
+    H = spec.linear_num_value_heads
+    dk, dv = spec.linear_key_head_dim, spec.linear_value_head_dim
+    K = spec.linear_conv_kernel_dim
+    f32 = jnp.float32
+    h = _norm_in(spec, x, layer["attn_norm"])
+
+    u = jnp.concatenate(
+        [dense(h, layer["lin_wq"]), dense(h, layer["lin_wk"]),
+         dense(h, layer["lin_wv"])], axis=-1)                     # [B, T, C]
+    if ctx.valid is not None:
+        u = jnp.where(ctx.valid[..., None], u, 0)
+    window = jnp.concatenate([entry["conv"], u.astype(entry["conv"].dtype)], axis=1)
+    taps = layer["lin_conv"].astype(f32)                           # [K, C]
+    y = sum(taps[i] * window[:, i:i + T].astype(f32) for i in range(K))
+    y = jax.nn.silu(y)
+    q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
+    q, k = q.reshape(B, T, H, dk), k.reshape(B, T, H, dk)
+    v = v.reshape(B, T, H, dv)
+    unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)  # noqa: E731
+    q, k = unit(q) * dk ** -0.5, unit(k)
+
+    gate = lambda w: jnp.dot(h, w, preferred_element_type=f32)    # noqa: E731  [B, T, H]
+    beta = jax.nn.sigmoid(gate(layer["lin_wb"]))
+    if spec.linear_allow_neg_eigval:
+        beta = 2.0 * beta
+    g = -jnp.exp(layer["lin_a_log"].astype(f32)) * jax.nn.softplus(
+        gate(layer["lin_wa"]) + layer["lin_dt_bias"].astype(f32))
+    if ctx.valid is None:       # one decoded token: the recurrence itself
+        o, S = gated_delta_step(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], entry["S"])
+        o = o[:, None]
+    else:
+        g = jnp.where(ctx.valid[..., None], g, 0.0)
+        beta = jnp.where(ctx.valid[..., None], beta, 0.0)
+        o, S = gated_delta_prefill(
+            q.astype(x.dtype), k.astype(x.dtype), v.astype(x.dtype), g, beta,
+            entry["S"], impl=ctx.impl.delta)
+    new_entry = {"S": S, "conv": window[:, T:]}
+
+    o = rms_norm(o.astype(x.dtype), layer["lin_out_norm"], spec.rms_eps)
+    o = o * jax.nn.silu(dense(h, layer["lin_wg"])).reshape(B, T, H, dv)
+    y = dense(o.reshape(B, T, H * dv), layer["lin_wo"])
+    x = x + _norm_out(spec, y, layer["attn_norm"])
+    return _mlp_sublayer(layer, spec, x), new_entry
+
+
+# Layer type -> block.  A further mechanism adds a function and an entry.
+_HYBRID_BLOCKS = {
+    FULL_ATTENTION: _block_full,
+    LINEAR_ATTENTION: _block_gated_delta,
+}
+
+
+def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
+                       x: jax.Array, cache, ctx: _Ctx):
+    """:func:`_run_layers` for a spec with ``layer_types``.  List form:
+    a Python loop over the layers, each with its own cache entry.
+    Stacked form (``stack_layer_params``: one stack per type): ONE
+    ``lax.scan`` over the PERIODS of the pattern, whose body applies the
+    period's layers in order, so the program is O(1) in depth; both
+    kinds of state ride the carry, indexed per type."""
+    layers = params["layers"]
+    if not isinstance(layers, dict):
+        new_cache = []
+        for layer, kind, entry in zip(layers, spec.layer_types, cache):
+            x, entry = _HYBRID_BLOCKS[kind](layer, spec, x, entry, ctx)
+            new_cache.append(entry)
+        return x, new_cache
+
+    period = spec.layer_period
+    per = {kind: period.count(kind) for kind in dict.fromkeys(period)}
+
+    def body(carry, p):
+        h, c = carry
+        seen = dict.fromkeys(per, 0)
+        for kind in period:
+            # the period's j-th layer of this kind is the type's stack's
+            # (p * per-period + j)-th: ONE layer's weights are sliced where
+            # they are used (a period's slab as the scan's xs is copied
+            # whole every iteration: 5 GB a decode step at 7B, measured)
+            li = p * per[kind] + seen[kind]
+            seen[kind] += 1
+            take = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)  # noqa: E731
+            h, entry = _HYBRID_BLOCKS[kind](
+                jax.tree.map(take, layers[kind]), spec, h,
+                jax.tree.map(take, c[kind]), ctx)
+            c = {**c, kind: jax.tree.map(
+                lambda a, e: jax.lax.dynamic_update_index_in_dim(a, e, li, 0),
+                c[kind], entry)}
+        return (h, c), None
+
+    (x, new_cache), _ = jax.lax.scan(
+        body, (x, cache), jnp.arange(spec.num_layers // len(period)))
+    return x, new_cache
 
 
 def _run_layers(
@@ -637,6 +1020,7 @@ def _run_layers(
     chunk: bool = False,
     ring=None,
     kv_valid=None,
+    valid=None,
 ):
     """Apply every decoder block: a Python loop for list-form params
     (each layer unrolled into the HLO — best when the program already
@@ -645,7 +1029,22 @@ def _run_layers(
     ``stack_layer_params``).  The scanned cache rides the scan CARRY
     (dynamic_index/dynamic_update per layer) — riding xs/ys would
     materialize a second full cache, which OOMs at 8B — and keeps the
-    same [Lyr, ...] layout."""
+    same [Lyr, ...] layout.
+
+    A spec with ``layer_types`` goes through :func:`_run_layers_hybrid`
+    (a block per layer type, two kinds of state); ``valid`` ([B, T],
+    False on pads) is what its recurrent layers need of a prefill, and
+    the call forms it is not built for raise here, at trace time."""
+    if spec.hybrid:
+        if chunk or ring is not None or (valid is None and x.shape[1] > 1):
+            raise NotImplementedError(
+                f"{spec.name}: a spec with layer_types runs prefill, "
+                "prefill_chunk_at and decode_step only (no decode chunk, "
+                "ring, cached-prefix or paged form carries recurrent state)")
+        if not isinstance(impl, HybridImpl):   # a plain name: the XLA twin
+            impl = HybridImpl(impl, "xla")
+        return _run_layers_hybrid(params, spec, x, cache, _Ctx(
+            cos, sin, write_pos, attn_mask, hist_len, valid, impl))
     layers = params["layers"]
     if isinstance(layers, dict):
         # The cache rides the scan CARRY, not xs/ys: ys would be a second
@@ -732,6 +1131,13 @@ def init_kv_cache(
     batch at a fixed HBM budget (see models/quantize.py's int4-KV
     contract).
 
+    A spec with ``layer_types`` gets K/V entries for its
+    full-attention layers only and, for each linear layer, ``{"S":
+    [B, H, dv, dk] float32, "conv": [B, K-1, channels]}`` (zeros: the
+    state before a sequence starts).  Stacked, its cache is ``{kind:
+    leaves [layers of that kind, ...]}``; :func:`cache_bytes` counts
+    either form by kind of state.
+
     The list form keeps separate pytree leaves so the
     ``dynamic_update_slice`` in each decode step is a pure per-buffer
     update XLA can alias in-place inside ``lax.while_loop``.  The stacked
@@ -760,9 +1166,44 @@ def init_kv_cache(
             "v": jnp.zeros(lead + shape, dtype),
         }
 
+    if spec.hybrid:
+        # Two kinds of state: K/V for the full-attention layers only,
+        # and for each delta-rule layer its recurrent state (float32)
+        # and the conv's last K-1 inputs.
+        def linear(lead=()):
+            return {
+                "S": jnp.zeros(
+                    lead + (batch, spec.linear_num_value_heads,
+                            spec.linear_value_head_dim, spec.linear_key_head_dim),
+                    jnp.float32),
+                "conv": jnp.zeros(
+                    lead + (batch, spec.linear_conv_kernel_dim - 1,
+                            spec.linear_conv_size), dtype),
+            }
+
+        make = {FULL_ATTENTION: entry, LINEAR_ATTENTION: linear}
+        if stacked:
+            return {kind: make[kind](lead=(spec.layers_of(kind),))
+                    for kind in dict.fromkeys(spec.layer_period)}
+        return [make[kind]() for kind in spec.layer_types]
     if stacked:
         return entry(lead=(spec.num_layers,))
     return [entry() for _ in range(spec.num_layers)]
+
+
+def cache_bytes(spec: ModelSpec, batch: int, max_len: int, **kw) -> Dict[str, int]:
+    """Bytes of what :func:`init_kv_cache` allocates for these
+    arguments, by kind of state: ``kv`` (keys, values, their scales) and
+    ``linear_state`` (recurrent state and conv tail; 0 for a spec with
+    no linear layer).  Read off the allocation's own shapes, so a count
+    made from it cannot drift from what is allocated."""
+    shapes = jax.eval_shape(partial(init_kv_cache, spec, batch, max_len, **kw))
+    out = {"kv": 0, "linear_state": 0}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(shapes):
+        name = path[-1].key
+        kind = "linear_state" if name in ("S", "conv") else "kv"
+        out[kind] += math.prod(leaf.shape) * leaf.dtype.itemsize
+    return out
 
 
 def prefill(
@@ -781,7 +1222,7 @@ def prefill(
     B, L = tokens.shape
     positions = jnp.cumsum(valid.astype(jnp.int32), axis=1) - 1
     positions = jnp.maximum(positions, 0)
-    cos, sin = rope_table(positions, spec.head_dim, spec.rope_theta, spec.rope_scaling)
+    cos, sin = _rope_for(spec, positions)
 
     causal = jnp.tril(jnp.ones((L, L), bool))
     # Prefill attends over the fresh [B, L] chunk only — nothing beyond L
@@ -790,7 +1231,8 @@ def prefill(
 
     x = params["embed"][tokens]
     x, new_cache = _run_layers(
-        params, spec, x, cos, sin, jnp.int32(0), cache, attn_mask, impl
+        params, spec, x, cos, sin, jnp.int32(0), cache, attn_mask, impl,
+        valid=valid,
     )
     logits = _logits(params, spec, x[:, -1:, :])[:, 0, :]  # [B, V]
     return logits, new_cache
@@ -828,8 +1270,7 @@ def prefill_sp(
         raise ValueError(f"prompt length {L} not divisible by sp={sp}")
     positions = jnp.cumsum(valid.astype(jnp.int32), axis=1) - 1
     positions = jnp.maximum(positions, 0)
-    cos, sin = rope_table(positions, spec.head_dim, spec.rope_theta,
-                          spec.rope_scaling)
+    cos, sin = _rope_for(spec, positions)
 
     x = params["embed"][tokens]
     x = jax.lax.with_sharding_constraint(
@@ -864,7 +1305,7 @@ def prefill_with_prefix(
     P = prefix_valid.shape[1]
     positions = prefix_lens[:, None] + jnp.cumsum(valid.astype(jnp.int32), axis=1) - 1
     positions = jnp.maximum(positions, 0)
-    cos, sin = rope_table(positions, spec.head_dim, spec.rope_theta, spec.rope_scaling)
+    cos, sin = _rope_for(spec, positions)
 
     causal = jnp.tril(jnp.ones((Ls, Ls), bool))
     chunk_mask = causal[None] & valid[:, None, :] & valid[:, :, None]   # [B, Ls, Ls]
@@ -907,7 +1348,7 @@ def prefill_paged(
     P = prefix_valid.shape[1]
     positions = prefix_lens[:, None] + jnp.cumsum(valid.astype(jnp.int32), axis=1) - 1
     positions = jnp.maximum(positions, 0)
-    cos, sin = rope_table(positions, spec.head_dim, spec.rope_theta, spec.rope_scaling)
+    cos, sin = _rope_for(spec, positions)
 
     causal = jnp.tril(jnp.ones((Ls, Ls), bool))
     chunk_mask = causal[None] & valid[:, None, :] & valid[:, :, None]   # [B, Ls, Ls]
@@ -960,7 +1401,7 @@ def prefill_paged_chunk_at(
     B, C = tokens.shape
     positions = pos_offset[:, None] + jnp.cumsum(valid.astype(jnp.int32), axis=1) - 1
     positions = jnp.maximum(positions, 0)
-    cos, sin = rope_table(positions, spec.head_dim, spec.rope_theta, spec.rope_scaling)
+    cos, sin = _rope_for(spec, positions)
 
     H = hist_valid.shape[1]
     causal = jnp.tril(jnp.ones((C, C), bool))
@@ -1014,7 +1455,7 @@ def prefill_chunk_at(
     B, C = tokens.shape
     positions = pos_offset[:, None] + jnp.cumsum(valid.astype(jnp.int32), axis=1) - 1
     positions = jnp.maximum(positions, 0)
-    cos, sin = rope_table(positions, spec.head_dim, spec.rope_theta, spec.rope_scaling)
+    cos, sin = _rope_for(spec, positions)
 
     H = hist_valid.shape[1]
     causal = jnp.tril(jnp.ones((C, C), bool))
@@ -1043,7 +1484,7 @@ def prefill_chunk_at(
         attn_mask = jnp.concatenate([hist_mask, chunk_mask], axis=2)
         x, new_cache = _run_layers(
             params, spec, x, cos, sin, write_pos, cache, attn_mask, impl,
-            hist_len=H,
+            hist_len=H, valid=valid,
         )
     logits = _logits(params, spec, x[:, -1:, :])[:, 0, :]
     return logits, new_cache
@@ -1063,7 +1504,7 @@ def decode_step(
 ) -> Tuple[jax.Array, Dict]:
     """One autoregressive step for the whole batch."""
     B = token.shape[0]
-    cos, sin = rope_table(seq_positions[:, None], spec.head_dim, spec.rope_theta, spec.rope_scaling)
+    cos, sin = _rope_for(spec, seq_positions[:, None])
     x = params["embed"][token][:, None, :]  # [B, 1, D]
 
     x, new_cache = _run_layers(
@@ -1097,7 +1538,7 @@ def decode_chunk(
     row's LAST VALID chunk position and the updated cache.
     """
     B, K = tokens.shape
-    cos, sin = rope_table(positions, spec.head_dim, spec.rope_theta, spec.rope_scaling)
+    cos, sin = _rope_for(spec, positions)
 
     # Mask: chunk queries attend to valid prior cache slots plus the
     # causally-visible valid part of the chunk itself.
@@ -1146,8 +1587,7 @@ def decode_chunk_spec(
     attention itself is mask-driven and shared.
     """
     B, K1 = tokens.shape
-    cos, sin = rope_table(positions, spec.head_dim, spec.rope_theta,
-                          spec.rope_scaling)
+    cos, sin = _rope_for(spec, positions)
 
     # Mask: chunk queries attend valid prior cache slots plus the
     # causally-visible valid chunk prefix, scattered at per-row columns.
@@ -1195,8 +1635,9 @@ def _block_chunk(
     if spec.qk_norm:
         q = rms_norm(q, layer["q_norm"], spec.rms_eps)
         k = rms_norm(k, layer["k_norm"], spec.rms_eps)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cos is not None:
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     new_entry = _write_cache(cache_entry, k, v, write_pos)
 
@@ -1266,9 +1707,7 @@ def _block_chunk(
         )
     x = x + dense(attn_out.reshape(B, K, spec.q_size), layer["wo"])
 
-    h = rms_norm(x, layer["mlp_norm"], spec.rms_eps)
-    gate = jax.nn.silu(dense(h, layer["w_gate"]))
-    x = x + dense(gate * dense(h, layer["w_up"]), layer["w_down"])
+    x = x + _swiglu(layer, rms_norm(x, layer["mlp_norm"], spec.rms_eps))
     return x, new_entry
 
 

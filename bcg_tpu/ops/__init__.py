@@ -21,6 +21,16 @@ class PallasTP(NamedTuple):
     mesh: Mesh
 
 
+class HybridImpl(NamedTuple):
+    """``impl`` marker of a model with two kinds of layer: what its
+    full-attention layers run (any marker above, or a plain name) and
+    what its gated delta-rule layers run (an ``ops.gated_delta``
+    marker).  Resolved by the engine at boot, like the rest."""
+
+    attention: object
+    delta: str
+
+
 def is_pallas(impl) -> bool:
     return impl == "pallas" or isinstance(impl, PallasTP)
 

@@ -47,10 +47,24 @@ BLOCK_S = 512
 ALIGN_S = 1024
 
 
-def _pick_block(S: int, requested) -> int:
+# The all-heads int8 kernel holds one K and one V block of EVERY kv head
+# at once, double-buffered: 4 * Hkv * block_s * Dh bytes.  Mosaic's
+# scoped VMEM is 16 MiB on a v5e; half of it for these leaves room for
+# scales, q, the mask and the accumulators.
+_KV_BLOCKS_BUDGET = 8 << 20
+
+
+def _pick_block(S: int, requested, kv_row_bytes: int = 0) -> int:
+    """``kv_row_bytes``: bytes of one cache position over all the kv
+    heads a program holds (Hkv * Dh for the int8 layout; 0 where a
+    program holds one head).  8 heads of 128 keep the 1024 block; 30
+    (an MHA model's) halve it, or the kernel does not fit VMEM."""
     if requested is not None:
         return requested
-    return ALIGN_S if S % ALIGN_S == 0 else BLOCK_S
+    block = ALIGN_S if S % ALIGN_S == 0 else BLOCK_S
+    while block > 128 and 4 * block * kv_row_bytes > _KV_BLOCKS_BUDGET:
+        block //= 2
+    return block
 
 
 def _decode_kernel(
@@ -250,7 +264,10 @@ def decode_attention(
     """
     B, H, Dh = q.shape
     quantized = k_scale is not None
-    block_s = _pick_block(k.shape[2] if quantized else k.shape[1], block_s)
+    block_s = (
+        _pick_block(k.shape[2], block_s, k.shape[1] * k.shape[3]) if quantized
+        else _pick_block(k.shape[1], block_s)
+    )
     if quantized:
         Hkv = k.shape[1]
         group = H // Hkv
@@ -342,7 +359,10 @@ def chunk_decode_attention(
     """
     B, K, H, Dh = q.shape
     quantized = k_scale is not None
-    block_s = _pick_block(k.shape[2] if quantized else k.shape[1], block_s)
+    block_s = (
+        _pick_block(k.shape[2], block_s, k.shape[1] * k.shape[3]) if quantized
+        else _pick_block(k.shape[1], block_s)
+    )
     if quantized:
         Hkv = k.shape[1]
         group = H // Hkv

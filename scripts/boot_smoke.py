@@ -198,6 +198,10 @@ def run_all(verbose: bool = True) -> list:
         else:
             quants = [None, "int8"]
         for mesh_name, mesh in meshes:
+            if spec.hybrid and mesh is not None:
+                # a spec with layer_types has no sharded form (recurrent
+                # state): the engine refuses a mesh at boot
+                continue
             for quant in quants:
                 got = check_preset(name, mesh, quant)
                 problems += got

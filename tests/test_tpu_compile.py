@@ -129,6 +129,39 @@ class TestOneChip:
             ((B, FF_CHUNK, S_CACHE), jnp.bool_),
         )
 
+    def test_int8_decode_30_kv_heads(self, one):
+        # An MHA model's full-attention layers (30 KV heads of 128,
+        # group 1): the all-heads kernel holds every head's K and V
+        # block at once, and at the 1024 block that is over a v5e's
+        # scoped VMEM; the block follows the heads held (_pick_block).
+        hkv, s_cache = 30, 5120
+        _compile(
+            _decode_int8, one,
+            ((B, hkv, DH), jnp.bfloat16),
+            ((B, hkv, s_cache, DH), jnp.int8), ((B, hkv, s_cache, DH), jnp.int8),
+            ((B, hkv, s_cache), jnp.float32), ((B, hkv, s_cache), jnp.float32),
+            ((B, s_cache), jnp.bool_),
+        )
+
+    def test_gated_delta_prefill_chunk(self, one):
+        # One 512-token prefill chunk of a delta-rule layer at the
+        # hybrid's published widths: 30 heads, key dim 96, value dim 192
+        # (neither a multiple of the 128 lanes), float32 state.
+        from bcg_tpu.ops import gated_delta
+
+        heads, dk, dv, T = 30, 96, 192, 512
+
+        def chunk(q, k, v, g, beta, S):
+            return gated_delta.gated_delta_prefill(
+                q, k, v, g, beta, S, impl=gated_delta.PALLAS)
+
+        _compile(
+            chunk, one,
+            ((B, T, heads, dk), jnp.bfloat16), ((B, T, heads, dk), jnp.bfloat16),
+            ((B, T, heads, dv), jnp.bfloat16), ((B, T, heads), jnp.float32),
+            ((B, T, heads), jnp.float32), ((B, heads, dv, dk), jnp.float32),
+        )
+
     @pytest.mark.parametrize("top_p", [1.0, 0.9])
     def test_fused_sampler_qwen_vocab(self, one, top_p):
         from bcg_tpu.ops import guided_sampler as gs
