@@ -527,6 +527,45 @@ def attention(q, k, v, mask, scale, impl="xla"):
 
 # ------------------------------------------------------------------ forward
 
+class _Layer(NamedTuple):
+    """One layer's K/V entry of a STACKED dense cache (the layer scan's
+    carry), addressed and never materialised: ``stack`` holds the leaves
+    [Lyr, ...], ``li`` says which layer.  :func:`_write_cache` writes
+    the fresh tokens into the stack at ``li`` in place, a reader takes
+    through :func:`_leaf` one ``dynamic_slice`` bounded to what it reads
+    (XLA fuses it into the consumer), and the int8 decode kernel reads
+    its layer through an index map (``ops/decode_attention.py``).
+    Slicing the entry out of the carry and writing it back copied the
+    whole layer four times a layer and decode step: 16% of a round at
+    8B and 27% of the hybrid's (PERF.md section 6, PR 30).  The list
+    (unrolled) cache has no stacked axis and its entries stay dicts; so
+    does a paged entry, whose pool is addressed by its block table."""
+    stack: Dict
+    li: jax.Array
+
+
+def _leaves(entry) -> Dict:
+    """The dict whose keys and dtypes say what an entry stores (int8 or
+    int4 scales, a block table), for either form of entry."""
+    return entry.stack if isinstance(entry, _Layer) else entry
+
+
+def _leaf(entry, name: str, upto: Optional[int] = None) -> jax.Array:
+    """Leaf ``name`` of a dense entry as the entry's own array
+    ([B, ...]), cut to cache slots [0, upto) when given."""
+    leaves = _leaves(entry)
+    a = leaves[name]
+    # slots: last axis of a scale, S of [.., Hkv, S, Dh] int8 storage or
+    # of [.., S, Hkv, Dh] bf16
+    axis = a.ndim - (1 if name.endswith("_scale") else 2 if "k_scale" in leaves else 3)
+    if not isinstance(entry, _Layer):
+        return a if upto is None else jax.lax.slice_in_dim(a, 0, upto, axis=axis)
+    sizes = [1, *a.shape[1:]]
+    if upto is not None:
+        sizes[axis] = upto
+    return jax.lax.dynamic_slice(a, [entry.li] + [0] * (a.ndim - 1), sizes)[0]
+
+
 def kv_is_int4(entry: Dict) -> bool:
     """True for a packed-int4 KV entry.  The marker is the SCALE dtype —
     int4 scales are bf16 where the int8 arm's are f32 (see
@@ -564,7 +603,7 @@ def _kv_dequantizer(entry: Dict):
     return dequantize_kv
 
 
-def _write_cache(entry: Dict, k, v, pos) -> Dict:
+def _write_cache(entry, k, v, pos):
     """Write fresh k/v into the cache entry (quantizing if it is int8
     or packed int4 — the entry's scale dtype selects, see
     :func:`_kv_quantizer`).
@@ -580,63 +619,103 @@ def _write_cache(entry: Dict, k, v, pos) -> Dict:
     slicing S x Dh is native — the bf16 layout's [.., S, Hkv, Dh] would
     hand Mosaic (1, 128)-row int8 blocks (measured ~70x slower decode).
 
+    A stacked cache's layer (:class:`_Layer`) takes the same update one
+    axis deeper, at ``(li, ...)`` of the stack: T slots of one layer
+    written in place in the scan's carry, nothing else moved.
+
     A PAGED entry (block pool + per-row block table, ``"tbl"`` present —
     :mod:`bcg_tpu.ops.paged_attention`) routes both position forms
     through the block-indexed scatter instead; the logical semantics
     are identical.
     """
-    if "tbl" in entry:
+    leaves = _leaves(entry)
+    if "tbl" in leaves:
         from bcg_tpu.ops.paged_attention import paged_write
 
         return paged_write(entry, k, v, pos)
     if getattr(pos, "ndim", 0) == 1:
         return _write_cache_rows(entry, k, v, pos)
-    new = dict(entry)
-    if "k_scale" in entry:
-        quantize_kv = _kv_quantizer(entry)
+    if "k_scale" in leaves:
+        quantize_kv = _kv_quantizer(leaves)
         kq, ksc = quantize_kv(k)   # kq: [B, T, Hkv, Dh(/2)]; ksc: [B, T, Hkv]
         vq, vsc = quantize_kv(v)
-        new["k"] = jax.lax.dynamic_update_slice(
-            entry["k"], kq.transpose(0, 2, 1, 3), (0, 0, pos, 0))
-        new["v"] = jax.lax.dynamic_update_slice(
-            entry["v"], vq.transpose(0, 2, 1, 3), (0, 0, pos, 0))
-        new["k_scale"] = jax.lax.dynamic_update_slice(
-            entry["k_scale"], ksc.transpose(0, 2, 1), (0, 0, pos))
-        new["v_scale"] = jax.lax.dynamic_update_slice(
-            entry["v_scale"], vsc.transpose(0, 2, 1), (0, 0, pos))
+        fresh = {
+            "k": (kq.transpose(0, 2, 1, 3), (0, 0, pos, 0)),
+            "v": (vq.transpose(0, 2, 1, 3), (0, 0, pos, 0)),
+            "k_scale": (ksc.transpose(0, 2, 1), (0, 0, pos)),
+            "v_scale": (vsc.transpose(0, 2, 1), (0, 0, pos)),
+        }
     else:
-        new["k"] = jax.lax.dynamic_update_slice(entry["k"], k.astype(entry["k"].dtype), (0, pos, 0, 0))
-        new["v"] = jax.lax.dynamic_update_slice(entry["v"], v.astype(entry["v"].dtype), (0, pos, 0, 0))
-    return new
+        fresh = {
+            "k": (k.astype(leaves["k"].dtype), (0, pos, 0, 0)),
+            "v": (v.astype(leaves["v"].dtype), (0, pos, 0, 0)),
+        }
+    stacked = isinstance(entry, _Layer)
+    new = dict(leaves)
+    for name, (update, at) in fresh.items():
+        if stacked:
+            update, at = update[None], (entry.li,) + at
+        new[name] = jax.lax.dynamic_update_slice(leaves[name], update, at)
+    return entry._replace(stack=new) if stacked else new
 
 
-def _write_cache_rows(entry: Dict, k, v, row_pos) -> Dict:
+def _write_cache_rows(entry, k, v, row_pos):
     """Per-row-position variant of :func:`_write_cache`: row ``b``'s
     [T]-token chunk lands at cache slots ``[row_pos[b], row_pos[b]+T)``
     (a scatter instead of ``dynamic_update_slice``; indices are in
     bounds by the caller's slot provisioning)."""
-    new = dict(entry)
+    leaves = _leaves(entry)
+    stacked = isinstance(entry, _Layer)
+    lead = (entry.li,) if stacked else ()
+    new = dict(leaves)
     B, T = k.shape[0], k.shape[1]
     bidx = jnp.arange(B)[:, None]                       # [B, 1]
     sidx = row_pos[:, None] + jnp.arange(T)[None, :]    # [B, T]
-    if "k_scale" in entry:
-        quantize_kv = _kv_quantizer(entry)
+    if "k_scale" in leaves:
+        quantize_kv = _kv_quantizer(leaves)
         kq, ksc = quantize_kv(k)   # kq: [B, T, Hkv, Dh(/2)]; ksc: [B, T, Hkv]
         vq, vsc = quantize_kv(v)
         # Storage [B, Hkv, S, Dh] / scales [B, Hkv, S]: advanced indices
-        # on axes (0, 2) move to the front, so the target region is
+        # on axes (0, 2) move to the front (with a stack's scalar layer
+        # index before them, which broadcasts), so the target region is
         # [B, T, Hkv, Dh] / [B, T, Hkv] — already the fresh-KV layout.
-        new["k"] = entry["k"].at[bidx, :, sidx].set(kq)
-        new["v"] = entry["v"].at[bidx, :, sidx].set(vq)
-        new["k_scale"] = entry["k_scale"].at[bidx, :, sidx].set(ksc)
-        new["v_scale"] = entry["v_scale"].at[bidx, :, sidx].set(vsc)
+        at = lead + (bidx, slice(None), sidx)
+        fresh = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
     else:
-        new["k"] = entry["k"].at[bidx, sidx].set(k.astype(entry["k"].dtype))
-        new["v"] = entry["v"].at[bidx, sidx].set(v.astype(entry["v"].dtype))
-    return new
+        at = lead + (bidx, sidx)
+        fresh = {"k": k.astype(leaves["k"].dtype), "v": v.astype(leaves["v"].dtype)}
+    for name, update in fresh.items():
+        new[name] = leaves[name].at[at].set(update)
+    return entry._replace(stack=new) if stacked else new
 
 
-def _cache_attention(q, entry: Dict, mask, scale, impl: str):
+def _sp_args(entry):
+    """``k, v`` and the scale keywords of the ``sp`` ring forms
+    (``ops/ring_attention.py``): one layer's whole leaves, of which each
+    device reads its own S/sp slice."""
+    leaves = _leaves(entry)
+    assert not kv_is_int4(leaves), (
+        "int4 KV does not compose with sp-sharded decode (the ring "
+        "kernels dequantize int8 scales) — the engine rejects the "
+        "pairing at boot"
+    )
+    scales = {n: _leaf(entry, n) for n in ("k_scale", "v_scale") if n in leaves}
+    return _leaf(entry, "k"), _leaf(entry, "v"), scales
+
+
+def _int8_kernel_args(entry):
+    """``k, v`` and the keyword arguments with which
+    ``ops/decode_attention``'s two forms read an int8 entry: a stacked
+    layer hands over the whole stack and its index (the kernel's index
+    map finds the layer), a dict entry its own leaves."""
+    leaves = _leaves(entry)
+    kw = {"k_scale": leaves["k_scale"], "v_scale": leaves["v_scale"]}
+    if isinstance(entry, _Layer):
+        kw["layer"] = entry.li
+    return leaves["k"], leaves["v"], kw
+
+
+def _cache_attention(q, entry, mask, scale, impl: str):
     """Decode-step attention over the (possibly int8) cache.
 
     q: [B, 1, H, Dh]; mask: [B, S] attendable slots.  A Pallas ``impl``
@@ -644,7 +723,8 @@ def _cache_attention(q, entry: Dict, mask, scale, impl: str):
     lane-aligned head dim) streams the cache once and dequantizes in
     VMEM; any other ``impl`` dequantizes and runs the stock einsum.
     """
-    if "tbl" in entry:
+    leaves = _leaves(entry)
+    if "tbl" in leaves:
         # Paged cache (ops/paged_attention.py): ``impl`` carries the
         # engine-resolved paged marker — "paged_pallas"(+"_it") runs
         # the fused page-gather kernel, anything else the block-table
@@ -653,26 +733,18 @@ def _cache_attention(q, entry: Dict, mask, scale, impl: str):
         from bcg_tpu.ops.paged_attention import paged_decode_attention
 
         return paged_decode_attention(q, entry, mask, scale, impl=impl)
-    quantized = "k_scale" in entry
     if is_pallas(impl):
         from bcg_tpu.ops.decode_attention import decode_attention
 
         # The dense kernel streams unpacked int8 only.
-        assert not kv_is_int4(entry), "no dense Pallas decode for int4 KV"
+        assert not kv_is_int4(leaves), "no dense Pallas decode for int4 KV"
+        if "k_scale" in leaves:
+            k, v, kw = _int8_kernel_args(entry)
+        else:   # the bf16 kernel (interpret-mode checks) takes one entry
+            k, v, kw = _leaf(entry, "k"), _leaf(entry, "v"), {}
         return decode_attention(
-            q[:, 0], entry["k"], entry["v"], mask, scale,
-            k_scale=entry.get("k_scale"), v_scale=entry.get("v_scale"),
-            mesh=impl_mesh(impl),
-        )[:, None]
-    k, v = entry["k"], entry["v"]
-    if quantized:
-        dequantize_kv = _kv_dequantizer(entry)
-
-        # Quantized cache layout is [B, Hkv, S, Dh(/2 packed)] with
-        # scales [B, Hkv, S]; the (slow-path) full dequant transposes
-        # back to the attention layout [B, S, Hkv, Dh].
-        k = dequantize_kv(k, entry["k_scale"]).transpose(0, 2, 1, 3).astype(q.dtype)
-        v = dequantize_kv(v, entry["v_scale"]).transpose(0, 2, 1, 3).astype(q.dtype)
+            q[:, 0], k, v, mask, scale, mesh=impl_mesh(impl), **kw)[:, None]
+    k, v = _layer_kv(entry, q.dtype)
     return _xla_attention(q, k, v, mask[:, None, :], scale)
 
 
@@ -683,13 +755,14 @@ def _cache_len(cache) -> int:
     return entry["k"].shape[-2 if "k_scale" in entry else -3]
 
 
-def _dequant_slice(entry: Dict, name: str, upto: int, dtype) -> jax.Array:
-    """Cache slots [0, upto) of k or v as [B, upto, Hkv, Dh], dequantized
-    (and transposed out of the [B, Hkv, S, Dh] storage) if stored int8.
-    Paged entries gather only the table's first ``upto / bs`` block
-    columns (the caller block-aligns the prefix region) to the same
-    dense view first."""
-    if "tbl" in entry:
+def _dequant_slice(entry, name: str, upto: Optional[int], dtype) -> jax.Array:
+    """Cache slots [0, upto) (all of them: None) of k or v as
+    [B, upto, Hkv, Dh], dequantized (and transposed out of the
+    [B, Hkv, S, Dh] storage) if stored int8.  Of a stacked layer only
+    that much of that layer is read.  Paged entries gather only the
+    table's first ``upto / bs`` block columns (the caller block-aligns
+    the prefix region) to the same dense view first."""
+    if "tbl" in _leaves(entry):
         from bcg_tpu.ops.paged_attention import block_size, paged_gather_entry
 
         bs = block_size(entry)
@@ -697,16 +770,27 @@ def _dequant_slice(entry: Dict, name: str, upto: int, dtype) -> jax.Array:
             f"paged history window {upto} not block-aligned (bs={bs})"
         )
         entry = paged_gather_entry(entry, upto_blocks=upto // bs)
+    leaves = _leaves(entry)
     scale_name = f"{name}_scale"
-    if scale_name not in entry:
-        return entry[name][:, :upto].astype(dtype)
-    dequantize_kv = _kv_dequantizer(entry)
+    if scale_name not in leaves:
+        return _leaf(entry, name, upto).astype(dtype)
+    dequantize_kv = _kv_dequantizer(leaves)
 
     # astype BEFORE the transpose: the transpose is the materialization
     # point, and a bf16 buffer halves its traffic vs transposing in f32.
     return dequantize_kv(
-        entry[name][:, :, :upto], entry[scale_name][:, :, :upto]
+        _leaf(entry, name, upto), _leaf(entry, scale_name, upto)
     ).astype(dtype).transpose(0, 2, 1, 3)
+
+
+def _layer_kv(entry, dtype):
+    """ONE layer's whole K and V in the attention layout
+    [B, S, Hkv, Dh], for the XLA fallbacks: a quantized entry's (slow
+    path) full dequant to ``dtype``, a bf16 one as stored."""
+    if "k_scale" in _leaves(entry):
+        return (_dequant_slice(entry, "k", None, dtype),
+                _dequant_slice(entry, "v", None, dtype))
+    return _leaf(entry, "k"), _leaf(entry, "v")
 
 
 def _block(
@@ -820,17 +904,11 @@ def _attend(
         # active.
         from bcg_tpu.ops.ring_attention import sp_decode_attention
 
-        assert not kv_is_int4(new_entry), (
-            "int4 KV does not compose with sp-sharded decode (the ring "
-            "kernels dequantize int8 scales) — the engine rejects the "
-            "pairing at boot"
-        )
         mesh, axis_name = ring
+        ck, cv, scales = _sp_args(new_entry)
         attn_out = sp_decode_attention(
-            q[:, 0], new_entry["k"], new_entry["v"], attn_mask, mesh,
-            axis_name=axis_name, scale=scale,
-            k_scale=new_entry.get("k_scale"),
-            v_scale=new_entry.get("v_scale"),
+            q[:, 0], ck, cv, attn_mask, mesh,
+            axis_name=axis_name, scale=scale, **scales,
         )[:, None]
     else:
         attn_out = _cache_attention(q, new_entry, attn_mask, scale, impl)
@@ -964,6 +1042,28 @@ _HYBRID_BLOCKS = {
 }
 
 
+def _carried_entry(stack: Dict, li):
+    """Layer ``li``'s entry of one stacked state in the layer scan's
+    carry.  Dense K/V is addressed in place (:class:`_Layer`): a step
+    adds T slots to it.  A paged pool's entry and a delta-rule layer's
+    ``S`` / ``conv`` are sliced out and written back whole: the first is
+    addressed by its block table, the second rewritten whole each step
+    by design (XLA updates it in place already)."""
+    if "k" in stack and "tbl" not in stack:
+        return _Layer(stack, li)
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False), stack)
+
+
+def _carry_with(stack: Dict, li, entry) -> Dict:
+    """The stacked state after layer ``li``'s block returned ``entry``."""
+    if isinstance(entry, _Layer):
+        return entry.stack
+    return jax.tree.map(
+        lambda a, e: jax.lax.dynamic_update_index_in_dim(a, e, li, 0),
+        stack, entry)
+
+
 def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
                        x: jax.Array, cache, ctx: _Ctx):
     """:func:`_run_layers` for a spec with ``layer_types``.  List form:
@@ -971,7 +1071,8 @@ def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
     Stacked form (``stack_layer_params``: one stack per type): ONE
     ``lax.scan`` over the PERIODS of the pattern, whose body applies the
     period's layers in order, so the program is O(1) in depth; both
-    kinds of state ride the carry, indexed per type."""
+    kinds of state ride the carry, indexed per type
+    (:func:`_carried_entry`: K/V in place, recurrent state whole)."""
     layers = params["layers"]
     if not isinstance(layers, dict):
         new_cache = []
@@ -996,10 +1097,8 @@ def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
             take = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)  # noqa: E731
             h, entry = _HYBRID_BLOCKS[kind](
                 jax.tree.map(take, layers[kind]), spec, h,
-                jax.tree.map(take, c[kind]), ctx)
-            c = {**c, kind: jax.tree.map(
-                lambda a, e: jax.lax.dynamic_update_index_in_dim(a, e, li, 0),
-                c[kind], entry)}
+                _carried_entry(c[kind], li), ctx)
+            c = {**c, kind: _carry_with(c[kind], li, entry)}
         return (h, c), None
 
     (x, new_cache), _ = jax.lax.scan(
@@ -1026,10 +1125,11 @@ def _run_layers(
     (each layer unrolled into the HLO — best when the program already
     compiles), or ONE ``lax.scan`` over stacked params + stacked cache
     (program size O(1) in depth — the 8B-unblocking path; see
-    ``stack_layer_params``).  The scanned cache rides the scan CARRY
-    (dynamic_index/dynamic_update per layer) — riding xs/ys would
-    materialize a second full cache, which OOMs at 8B — and keeps the
-    same [Lyr, ...] layout.
+    ``stack_layer_params``).  The scanned cache rides the scan CARRY —
+    riding xs/ys would materialize a second full cache, which OOMs at
+    8B — keeps the same [Lyr, ...] layout, and is updated in it in
+    place: a layer's dense K/V entry is addressed as (stack, layer
+    index) and never sliced out (:class:`_Layer`, :func:`_carried_entry`).
 
     A spec with ``layer_types`` goes through :func:`_run_layers_hybrid`
     (a block per layer type, two kinds of state); ``valid`` ([B, T],
@@ -1056,10 +1156,7 @@ def _run_layers(
         def body(carry, per_layer):
             h, c = carry
             li, lp = per_layer
-            ce = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False),
-                c,
-            )
+            ce = _carried_entry(c, li)
             if chunk:
                 h, entry = _block_chunk(
                     lp, spec, h, cos, sin, write_pos, ce, attn_mask, impl,
@@ -1070,11 +1167,7 @@ def _run_layers(
                     lp, spec, h, cos, sin, write_pos, ce, attn_mask, impl,
                     hist_len=hist_len, ring=ring, kv_valid=kv_valid,
                 )
-            c = jax.tree.map(
-                lambda a, e: jax.lax.dynamic_update_index_in_dim(a, e, li, 0),
-                c, entry,
-            )
-            return (h, c), None
+            return (h, _carry_with(c, li, entry)), None
 
         (x, new_cache), _ = jax.lax.scan(
             body, (x, cache), (jnp.arange(num_layers), layers)
@@ -1141,8 +1234,9 @@ def init_kv_cache(
     The list form keeps separate pytree leaves so the
     ``dynamic_update_slice`` in each decode step is a pure per-buffer
     update XLA can alias in-place inside ``lax.while_loop``.  The stacked
-    form trades some of that aliasing freedom (scan's ys re-stack the
-    entries) for an O(1)-in-depth program — the 8B compile unblocking."""
+    form has one buffer per leaf, updated in place at (layer, slots) in
+    the scan's carry, for an O(1)-in-depth program — the 8B compile
+    unblocking."""
     if quantized == "int4":
         from bcg_tpu.models.quantize import kv_int4_layout
 
@@ -1643,8 +1737,9 @@ def _block_chunk(
 
     # Attend over the full cache including the just-written chunk.
     scale = 1.0 / math.sqrt(spec.head_dim)
-    quantized = "k_scale" in new_entry
-    if "tbl" in new_entry:
+    leaves = _leaves(new_entry)
+    quantized = "k_scale" in leaves
+    if "tbl" in leaves:
         # Paged cache (chunk form — the fast-forward / speculative-
         # verify decode windows; paged chunked PREFILL attends via
         # ``_block``'s cached-prefix path instead): ``impl`` carries
@@ -1666,17 +1761,11 @@ def _block_chunk(
         # sharding.  An int8 cache dequantizes its local slice only.
         from bcg_tpu.ops.ring_attention import sp_chunk_decode_attention
 
-        assert not kv_is_int4(new_entry), (
-            "int4 KV does not compose with sp-sharded decode (the ring "
-            "kernels dequantize int8 scales) — the engine rejects the "
-            "pairing at boot"
-        )
         mesh, axis_name = ring
+        ck, cv, scales = _sp_args(new_entry)
         attn_out = sp_chunk_decode_attention(
-            q, new_entry["k"], new_entry["v"], attn_mask, mesh,
-            axis_name=axis_name, scale=scale,
-            k_scale=new_entry.get("k_scale"),
-            v_scale=new_entry.get("v_scale"),
+            q, ck, cv, attn_mask, mesh,
+            axis_name=axis_name, scale=scale, **scales,
         )
     elif quantized and is_pallas(impl):
         # int8 cache: stream once, dequantize in VMEM (K*group query rows
@@ -1685,23 +1774,12 @@ def _block_chunk(
         # only for a dense int8 cache (_resolved_loop_impl).
         from bcg_tpu.ops.decode_attention import chunk_decode_attention
 
-        assert not kv_is_int4(new_entry), "no dense Pallas decode for int4 KV"
+        assert not kv_is_int4(leaves), "no dense Pallas decode for int4 KV"
+        ck, cv, kw = _int8_kernel_args(new_entry)
         attn_out = chunk_decode_attention(
-            q, new_entry["k"], new_entry["v"], attn_mask, scale,
-            k_scale=new_entry["k_scale"], v_scale=new_entry["v_scale"],
-            mesh=impl_mesh(impl),
-        )
+            q, ck, cv, attn_mask, scale, mesh=impl_mesh(impl), **kw)
     else:
-        ck, cv = new_entry["k"], new_entry["v"]
-        if quantized:
-            dequantize_kv = _kv_dequantizer(new_entry)
-
-            # The XLA path: full dequant out of the
-            # [B, Hkv, S, Dh(/2 packed)] storage layout.
-            ck = dequantize_kv(
-                ck, new_entry["k_scale"]).transpose(0, 2, 1, 3).astype(q.dtype)
-            cv = dequantize_kv(
-                cv, new_entry["v_scale"]).transpose(0, 2, 1, 3).astype(q.dtype)
+        ck, cv = _layer_kv(new_entry, q.dtype)
         attn_out = attention(
             q, ck, cv, attn_mask, scale, "xla" if quantized else impl
         )

@@ -40,19 +40,27 @@ _NEG_INF = -1e30
 def shard_heads(kernel, mesh: Mesh, batch: int, head_ranks):
     """``kernel(*head_operands, mask)`` over per-device head shards.
     Head operands are ``[B, heads, ...]`` of the given ranks and shard
-    their head axis over ``tp``; the trailing mask is ``[B, rows, S]``
-    with no head axis; the output is ``[B, heads, rows, Dh]``.  Batch shards
-    over ``dp`` when it divides; other mesh axes replicate.  Callers
-    check head divisibility (the engine's boot rule)."""
+    their head axis over ``tp``; ``(rank, lead)`` is one with ``lead``
+    replicated axes in front (a stacked cache's ``[Lyr, B, heads,
+    ...]``), ``None`` one replicated whole (a prefetched scalar).  The
+    trailing mask is ``[B, rows, S]`` with no head axis; the output is
+    ``[B, heads, rows, Dh]``.  Batch shards over ``dp`` when it divides;
+    other mesh axes replicate.  Callers check head divisibility (the
+    engine's boot rule)."""
     dp = mesh.shape.get("dp", 1)
     dp_ax = "dp" if dp > 1 and batch % dp == 0 else None
 
-    def heads(rank):
-        return P(dp_ax, "tp", *([None] * (rank - 2)))
+    def heads(rank, lead=0):
+        return P(*([None] * lead), dp_ax, "tp", *([None] * (rank - lead - 2)))
+
+    def operand(r):
+        if r is None:
+            return P()
+        return heads(*r) if isinstance(r, tuple) else heads(r)
 
     return jax.shard_map(
         kernel, mesh=mesh,
-        in_specs=tuple(heads(r) for r in head_ranks)
+        in_specs=tuple(operand(r) for r in head_ranks)
         + (P(dp_ax, None, None),),
         out_specs=heads(4),
         # pallas_call results carry no varying-axes type.

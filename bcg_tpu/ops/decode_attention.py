@@ -20,6 +20,13 @@ bf16 axis order would hand it (1, 128)-row int8 blocks (measured ~70x
 slower); scales [B, Hkv, S] (S minor-most, lane-aligned, exactly what
 the cache stores — no per-step transpose); mask [B, S] bool (attendable
 slots).  Returns [B, H, Dh] in q's dtype.
+
+The int8 forms also take a STACKED cache (the scan-over-layers carry:
+k/v [Lyr, B, Hkv, S, Dh], scales [Lyr, B, Hkv, S]) with a ``layer``
+index: the index is scalar-prefetched and the K/V/scale index maps
+read that layer's blocks out of the stack, so the layer's entry is
+never sliced out into a buffer of its own (a whole-layer copy a layer
+and step).  A per-entry cache runs the same call as a stack of one.
 """
 
 from __future__ import annotations
@@ -176,15 +183,19 @@ def _decode_kernel_allheads(
             ).astype(o_ref.dtype)
 
 
-def _quantized_attention(qg, kp, vp, ksp, vsp, mp, scale, block_s, interpret,
-                         mesh=None):
+def _quantized_attention(qg, layer, kp, vp, ksp, vsp, mp, scale, block_s,
+                         interpret, mesh=None):
     """Shared pallas_call for the int8 single-step and chunk paths.
 
-    qg [B, Hkv, rows, Dh]; kp/vp [B, Hkv, Sp, Dh] int8; scales
-    [B, Hkv, Sp]; mp [B, M, Sp] with M == 1 (broadcast) or rows.
-    Returns [B, Hkv, rows, Dh].  ``mesh``: each ``tp`` device runs the
-    kernel on its own Hkv/tp heads (ops/attention.shard_heads) — every
-    operand but the mask is laid out kv-head-major for exactly this.
+    qg [B, Hkv, rows, Dh]; layer [1] int32; kp/vp [Lyr, B, Hkv, Sp, Dh]
+    int8; scales [Lyr, B, Hkv, Sp]; mp [B, M, Sp] with M == 1
+    (broadcast) or rows.  Returns [B, Hkv, rows, Dh].  ``layer`` is
+    scalar-prefetched: the K/V/scale index maps read that layer's
+    blocks, the stack's leading axis squeezed away, so the kernel body
+    sees one entry's blocks whatever the stack's depth.  ``mesh``: each
+    ``tp`` device runs the kernel on its own Hkv/tp heads
+    (ops/attention.shard_heads) — every operand but the mask is laid
+    out kv-head-major for exactly this.
     """
     if mesh is not None:
         from bcg_tpu.ops.attention import shard_heads
@@ -194,40 +205,48 @@ def _quantized_attention(qg, kp, vp, ksp, vsp, mp, scale, block_s, interpret,
                 _quantized_attention, scale=scale, block_s=block_s,
                 interpret=interpret,
             ),
-            mesh, qg.shape[0], (4, 4, 4, 3, 3),
-        )(qg, kp, vp, ksp, vsp, mp)
+            mesh, qg.shape[0], (4, None, (5, 1), (5, 1), (4, 1), (4, 1)),
+        )(qg, layer, kp, vp, ksp, vsp, mp)
     B, Hkv, rows, Dh = qg.shape
-    Sp = kp.shape[2]
+    Sp = kp.shape[3]
     M = mp.shape[1]
     nS = Sp // block_s
-    kv_spec = pl.BlockSpec((1, Hkv, block_s, Dh), lambda b, s: (b, 0, s, 0))
-    scale_spec = pl.BlockSpec((1, Hkv, block_s), lambda b, s: (b, 0, s))
-    kernel = functools.partial(
-        _decode_kernel_allheads, scale=scale, num_s_blocks=nS, hkv=Hkv,
-    )
+    kv_spec = pl.BlockSpec(
+        (None, 1, Hkv, block_s, Dh), lambda b, s, li: (li[0], b, 0, s, 0))
+    scale_spec = pl.BlockSpec(
+        (None, 1, Hkv, block_s), lambda b, s, li: (li[0], b, 0, s))
+    q_spec = pl.BlockSpec((1, Hkv, rows, Dh), lambda b, s, li: (b, 0, 0, 0))
+
+    def kernel(layer_ref, *refs):
+        del layer_ref   # the index maps read it
+        _decode_kernel_allheads(*refs, scale=scale, num_s_blocks=nS, hkv=Hkv)
+
     return pl.pallas_call(
         kernel,
-        grid=(B, nS),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, rows, Dh), lambda b, s: (b, 0, 0, 0)),
-            kv_spec,
-            kv_spec,
-            scale_spec,
-            scale_spec,
-            pl.BlockSpec((1, M, block_s), lambda b, s: (b, 0, s)),
-        ],
-        out_specs=pl.BlockSpec((1, Hkv, rows, Dh), lambda b, s: (b, 0, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, nS),
+            in_specs=[
+                q_spec,
+                kv_spec,
+                kv_spec,
+                scale_spec,
+                scale_spec,
+                pl.BlockSpec((1, M, block_s), lambda b, s, li: (b, 0, s)),
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+                pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+                pltpu.VMEM((Hkv, rows, Dh), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, Dh), qg.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, rows, 1), jnp.float32),
-            pltpu.VMEM((Hkv, rows, 1), jnp.float32),
-            pltpu.VMEM((Hkv, rows, Dh), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qg, kp, vp, ksp, vsp, mp)
+    )(layer, qg, kp, vp, ksp, vsp, mp)
 
 
 def pow2_rows(group: int) -> int:
@@ -248,28 +267,53 @@ def _pad_s(x, block_s, axis=1, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _int8_operands(k, v, k_scale, v_scale, layer, block_s):
+    """``(layer [1] int32, k, v, k_scale, v_scale)`` as
+    :func:`_quantized_attention` takes them.  ``layer`` None: one
+    entry's leaves ([B, Hkv, S, ...]), padded to the block and run as a
+    stack of one.  Else the leaves are a stacked cache's
+    ([Lyr, B, Hkv, S, ...]) and go in as they are: a pad there would
+    copy the WHOLE cache a layer and step, so a misaligned one is an
+    error (allocate at ``ALIGN_S``)."""
+    leaves = (k, v, k_scale, v_scale)
+    if layer is None:
+        leaves = tuple(_pad_s(a, block_s, axis=2)[None] for a in leaves)
+        layer = 0
+    elif k.shape[-2] % block_s:
+        raise ValueError(
+            f"stacked int8 cache of {k.shape[-2]} slots is not a multiple "
+            f"of the kernel's block {block_s}: allocate it at ALIGN_S "
+            f"({ALIGN_S}); padding it here would copy the whole cache"
+        )
+    return (jnp.asarray(layer, jnp.int32).reshape(1),) + leaves
+
+
 def decode_attention(
     q, k, v, mask, scale,
     k_scale=None, v_scale=None,
     block_s=None,
     interpret: bool = False,
     mesh=None,
+    layer=None,
 ):
     """q [B, H, Dh], mask [B, S] -> [B, H, Dh].
 
     k/v: [B, S, Hkv, Dh] bf16, or — when ``k_scale`` is given — the int8
     cache layout [B, Hkv, S, Dh] (int8 tiles natively as (32, 128) over
     the last two dims; the bf16 axis order would hand Mosaic (1, 128)-row
-    int8 blocks, measured ~70x slower).  Scales [B, Hkv, S].
+    int8 blocks, measured ~70x slower).  Scales [B, Hkv, S].  With
+    ``layer`` (int8 only) k/v and the scales are a stacked cache's
+    leaves, one leading [Lyr] axis more, and that layer is attended
+    (see the module docstring).
     """
     B, H, Dh = q.shape
     quantized = k_scale is not None
     block_s = (
-        _pick_block(k.shape[2], block_s, k.shape[1] * k.shape[3]) if quantized
+        _pick_block(k.shape[-2], block_s, k.shape[-3] * k.shape[-1]) if quantized
         else _pick_block(k.shape[1], block_s)
     )
     if quantized:
-        Hkv = k.shape[1]
+        Hkv = k.shape[-3]
         group = H // Hkv
         # Non-power-of-two GQA groups (14B: H=40/Hkv=8 -> 5) pad their
         # query rows up to the next power of two — the kernel then only
@@ -281,21 +325,18 @@ def decode_attention(
         if g2 != group:
             qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g2 - group), (0, 0)))
         out = _quantized_attention(
-            qg,
-            _pad_s(k, block_s, axis=2),
-            _pad_s(v, block_s, axis=2),
-            _pad_s(k_scale, block_s, axis=2),
-            _pad_s(v_scale, block_s, axis=2),
+            qg, *_int8_operands(k, v, k_scale, v_scale, layer, block_s),
             _pad_s(mask, block_s, axis=1)[:, None, :],
             scale, block_s, interpret, mesh,
         )
         if g2 != group:
             out = out[:, :, :group]
         return out.reshape(B, H, Dh)
-    if mesh is not None:
+    if mesh is not None or layer is not None:
         raise ValueError(
             "decode_attention: only the int8 cache layout shards over a "
-            "mesh (the bf16 kernel's head axis is not block-major)"
+            "mesh (the bf16 kernel's head axis is not block-major) or is "
+            "read out of a stack by layer index"
         )
     S, Hkv = k.shape[1], k.shape[2]
     kp = _pad_s(k, block_s)
@@ -346,13 +387,14 @@ def chunk_decode_attention(
     block_s=None,
     interpret: bool = False,
     mesh=None,
+    layer=None,
 ):
     """Fast-forward chunk decode over the (possibly int8) cache.
 
     q [B, K, H, Dh] (K chunk positions), mask [B, K, S] -> [B, K, H, Dh];
-    k/v [B, S, Hkv, Dh] bf16 or the int8 cache layout [B, Hkv, S, Dh]
-    (see :func:`decode_attention`).  Same streaming/online-softmax/
-    in-VMEM-dequant design as :func:`decode_attention`, with an
+    k/v [B, S, Hkv, Dh] bf16 or the int8 cache layout [B, Hkv, S, Dh],
+    stacked with ``layer`` (see :func:`decode_attention`).  Same
+    streaming/online-softmax/in-VMEM-dequant design, with an
     [K*group, Dh] query tile per (batch, kv-head) program — K=4, group=2
     is an 8-row MXU tile, where the prefill flash kernel would pad the
     4 chunk rows to a 128-row query block (32x wasted work).
@@ -360,11 +402,11 @@ def chunk_decode_attention(
     B, K, H, Dh = q.shape
     quantized = k_scale is not None
     block_s = (
-        _pick_block(k.shape[2], block_s, k.shape[1] * k.shape[3]) if quantized
+        _pick_block(k.shape[-2], block_s, k.shape[-3] * k.shape[-1]) if quantized
         else _pick_block(k.shape[1], block_s)
     )
     if quantized:
-        Hkv = k.shape[1]
+        Hkv = k.shape[-3]
         group = H // Hkv
         # Pre-repeat the mask per query row (position-major: row
         # k*group+g = mask[k]) and lay q out [B, Hkv, K*group, Dh] to
@@ -381,11 +423,7 @@ def chunk_decode_attention(
             )
         qg = qg.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, K * g2, Dh)
         out = _quantized_attention(
-            qg,
-            _pad_s(k, block_s, axis=2),
-            _pad_s(v, block_s, axis=2),
-            _pad_s(k_scale, block_s, axis=2),
-            _pad_s(v_scale, block_s, axis=2),
+            qg, *_int8_operands(k, v, k_scale, v_scale, layer, block_s),
             mp, scale, block_s, interpret, mesh,
         )
         out = out.reshape(B, Hkv, K, g2, Dh)
@@ -396,10 +434,10 @@ def chunk_decode_attention(
             .transpose(0, 2, 1, 3, 4)
             .reshape(B, K, H, Dh)
         )
-    if mesh is not None:
+    if mesh is not None or layer is not None:
         raise ValueError(
             "chunk_decode_attention: only the int8 cache layout shards "
-            "over a mesh"
+            "over a mesh or is read out of a stack by layer index"
         )
     Hkv = k.shape[2]
     kp = _pad_s(k, block_s)

@@ -115,6 +115,64 @@ def test_chunk_int8_close_to_fp():
     assert err < 0.05, err
 
 
+def _int8_stack(key, layers, B, S, Hkv, Dh):
+    """A stacked int8 cache's leaves, every layer drawn differently:
+    k/v [Lyr, B, Hkv, S, Dh] int8, scales [Lyr, B, Hkv, S] f32."""
+    kk, kv = jax.random.split(key)
+    out = []
+    for kx in (kk, kv):
+        q, sc = quantize_kv(jax.random.normal(kx, (layers, B, S, Hkv, Dh)))
+        out += [q.transpose(0, 1, 3, 2, 4), sc.transpose(0, 1, 3, 2)]
+    k, ks, v, vs = out
+    return k, v, ks, vs
+
+
+@pytest.mark.parametrize("group", [4, 1, 5])     # 5: rows padded to 8
+@pytest.mark.parametrize("chunk", [False, True], ids=["step", "chunk"])
+def test_stacked_layer_equals_per_entry(chunk, group):
+    """The stacked form (the layer scan's carry with a prefetched layer
+    index: no layer's entry sliced out) reads the same blocks as the
+    per-entry form handed that layer's slice — bit for bit, at every
+    layer index."""
+    from bcg_tpu.ops.decode_attention import chunk_decode_attention
+
+    layers, B, K, S, Hkv, Dh = 3, 2, 4, 256, 2, 128
+    key = jax.random.PRNGKey(21)
+    if chunk:
+        q, _, _, mask = _chunk_case(key, B, K, S, Hkv * group, Hkv, Dh)
+        attend = chunk_decode_attention
+    else:
+        q, _, _, mask = _case(key, B, S, Hkv * group, Hkv, Dh)
+        attend = decode_attention
+    k, v, ks, vs = _int8_stack(jax.random.PRNGKey(22), layers, B, S, Hkv, Dh)
+    scale = 1.0 / np.sqrt(Dh)
+    outs = []
+    for li in range(layers):
+        entry = attend(q, k[li], v[li], mask, scale, k_scale=ks[li],
+                       v_scale=vs[li], block_s=128, interpret=True)
+        stacked = attend(q, k, v, mask, scale, k_scale=ks, v_scale=vs,
+                         block_s=128, interpret=True, layer=jnp.int32(li))
+        np.testing.assert_array_equal(np.asarray(stacked), np.asarray(entry))
+        outs.append(np.asarray(entry))
+    assert not np.array_equal(outs[0], outs[1])   # the layers do differ
+
+
+def test_stacked_cache_off_the_block_is_an_error():
+    """A per-entry cache off the kernel's block is padded; padding a
+    stacked one would copy the whole cache every layer and step."""
+    layers, B, S, Hkv, Dh = 2, 1, 200, 2, 128
+    q, _, _, mask = _case(jax.random.PRNGKey(23), B, S, Hkv, Hkv, Dh)
+    k, v, ks, vs = _int8_stack(jax.random.PRNGKey(24), layers, B, S, Hkv, Dh)
+    decode_attention(q, k[0], v[0], mask, 1.0, k_scale=ks[0], v_scale=vs[0],
+                     block_s=128, interpret=True)
+    with pytest.raises(ValueError, match="ALIGN_S"):
+        decode_attention(q, k, v, mask, 1.0, k_scale=ks, v_scale=vs,
+                         block_s=128, interpret=True, layer=0)
+    with pytest.raises(ValueError, match="int8"):   # no bf16 stacked form
+        decode_attention(q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
+                         mask, 1.0, block_s=128, interpret=True, layer=0)
+
+
 def test_quantize_roundtrip():
     x = jax.random.normal(jax.random.PRNGKey(2), (3, 16, 2, 64)) * 4.0
     q, s = quantize_kv(x)

@@ -350,19 +350,48 @@ class TestAgainstReference:
         assert float(jnp.abs(kept["S"] - moved["S"]).max()) > 1e-3
         assert float(jnp.abs(kept["conv"] - moved["conv"]).max()) == 0.0   # 8 real inputs last
 
-    def test_scan_matches_list_form(self):
-        toks = rows(self.LENGTHS)
-        tokens, valid = left_padded(toks, 192)
-        one, cache = T.prefill(f32_params(), SPEC, jnp.asarray(tokens), jnp.asarray(valid),
-                               T.init_kv_cache(SPEC, 3, 192, dtype=jnp.float32))
-        scanned, stacked = T.prefill(
-            f32_params(stacked=True), SPEC, jnp.asarray(tokens), jnp.asarray(valid),
-            T.init_kv_cache(SPEC, 3, 192, dtype=jnp.float32, stacked=True))
-        np.testing.assert_allclose(one, scanned, atol=F32_TOL)
-        lin = [e for e, k in zip(cache, SPEC.layer_types) if k == LINEAR_ATTENTION]
-        for j, entry in enumerate(lin):
-            np.testing.assert_allclose(
-                stacked[LINEAR_ATTENTION]["S"][j], entry["S"], atol=F32_TOL)
+    @pytest.mark.parametrize("kv", [False, "int8"])
+    def test_scan_matches_list_form(self, kv):
+        """Three 64-wide chunk programs, then four decode steps: the
+        scan over periods (the full layer's K/V written in place in the
+        carry, the linear layers' state sliced out and written back)
+        against the list form.  Same logits at every step; the same K,
+        V, scales, ``S`` and conv tail at the end.  An int8 value may
+        sit one step apart where its float lay on a rounding edge."""
+        toks, steps = rows(self.LENGTHS), 4
+        heads = [t[:-steps] for t in toks]
+        L, C = 192, 64
+        tokens, valid = left_padded(heads, L)
+        got = {}
+        for stacked in (False, True):
+            params = f32_params(stacked=stacked)
+            cache = T.init_kv_cache(SPEC, 3, L + steps, dtype=jnp.float32,
+                                    quantized=kv, stacked=stacked)
+            logits, cache = chunked_prefill(params, tokens, valid, cache, C)
+            seen, mask = [logits], np.zeros((3, L + steps), bool)
+            mask[:, :L] = valid
+            for j in range(steps):
+                mask[:, L + j] = True
+                logits, cache = T.decode_step(
+                    params, SPEC, jnp.asarray([t[len(t) - steps + j] for t in toks]),
+                    jnp.int32(L + j), jnp.asarray([len(h) + j for h in heads], jnp.int32),
+                    cache, jnp.asarray(mask))
+                seen.append(logits)
+            got[stacked] = (seen, cache)
+        tol = 2e-2 if kv else F32_TOL   # logits of order 3; a flipped int8 step moves one 6e-3
+        for one, scanned in zip(got[False][0], got[True][0]):
+            np.testing.assert_allclose(one, scanned, atol=tol)
+        cache, stacked = got[False][1], got[True][1]
+        at = dict.fromkeys(stacked, 0)
+        for entry, kind in zip(cache, SPEC.layer_types):
+            for name, leaf in entry.items():
+                there = stacked[kind][name][at[kind]]
+                if leaf.dtype == jnp.int8:
+                    diff = np.abs(np.asarray(leaf, np.int32) - np.asarray(there, np.int32))
+                    assert diff.max() <= 1 and (diff > 0).mean() < 1e-3, name
+                else:
+                    np.testing.assert_allclose(leaf, there, atol=F32_TOL, err_msg=name)
+            at[kind] += 1
         assert stacked[FULL_ATTENTION]["k"].shape[0] == 1
 
     def test_w8a8_tracks_bf16_within_the_quantiser(self):

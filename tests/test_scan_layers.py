@@ -7,6 +7,8 @@ cache contents, same logits — and must shard on a mesh.
 """
 
 import dataclasses
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,11 +16,14 @@ import numpy as np
 import pytest
 
 from bcg_tpu.models import init_params, prefill, spec_for_model
+from bcg_tpu.models import transformer as T
 from bcg_tpu.models.transformer import (
     decode_chunk,
+    decode_chunk_spec,
     decode_step,
     init_kv_cache,
     layers_stacked,
+    prefill_chunk_at,
     prefill_with_prefix,
     stack_layer_params,
 )
@@ -213,3 +218,160 @@ def test_engine_scan_with_prefix_caching():
     out_plain = eng_plain.batch_generate_json(prompts, temperature=0.0, max_tokens=24)
     assert out_cached == out_plain
     assert len(eng_cached._prefix_cache) == 2
+
+
+# ---------------------------------------- the stacked cache, updated in place
+
+HYBRID = spec_for_model("bcg-tpu/tiny-hybrid")
+
+
+def _layer_of(stack, li):
+    return jax.tree.map(lambda a: a[li], stack)
+
+
+@pytest.mark.parametrize("rows", [False, True], ids=["scalar_pos", "row_pos"])
+@pytest.mark.parametrize("kv", [False, "int8", "int4"])
+def test_write_into_stack_equals_write_into_entry(kv, rows):
+    """``_write_cache`` on a stacked cache's layer (``_Layer``: the
+    stack and an index, nothing sliced out) leaves in that layer, bit
+    for bit, what it leaves in the layer's own entry, and no other
+    layer moves."""
+    B, S, Tn, li = 3, 24, 2, 1
+    stack = init_kv_cache(SPEC, B, S, quantized=kv, stacked=True)
+    key = jax.random.PRNGKey(5)
+    stack = jax.tree.map(   # a cache already written to, not zeros
+        lambda a: jax.random.randint(key, a.shape, -9, 9).astype(a.dtype), stack)
+    k, v = jax.random.normal(key, (2, B, Tn, SPEC.num_kv_heads, SPEC.head_dim))
+    pos = jnp.asarray([3, 11, 20], jnp.int32) if rows else jnp.int32(7)
+    write = jax.jit(T._write_cache)
+    new = write(T._Layer(stack, jnp.int32(li)), k, v, pos).stack
+    want = write(_layer_of(stack, li), k, v, pos)
+    for name, leaf in want.items():
+        np.testing.assert_array_equal(np.asarray(new[name][li]), np.asarray(leaf))
+        np.testing.assert_array_equal(   # tiny-test has two layers
+            np.asarray(new[name][0]), np.asarray(stack[name][0]))
+        assert not np.array_equal(np.asarray(leaf), np.asarray(stack[name][li]))
+
+
+def _close_caches(list_cache, stacked):
+    """K, V and scales of every layer, list form against stacked, up to
+    bf16 reassociation noise (scan and the unrolled loop fuse
+    differently); int8 values are compared as what they stand for."""
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    for li, entry in enumerate(list_cache):
+        for name in entry:
+            a, b = f32(entry[name]), f32(stacked[name][li])
+            if f"{name}_scale" in entry:
+                a = a * f32(entry[f"{name}_scale"])[..., None]
+                b = b * f32(stacked[f"{name}_scale"][li])[..., None]
+            np.testing.assert_allclose(a, b, rtol=6e-2, atol=6e-2, err_msg=name)
+
+
+@pytest.mark.parametrize("rows", [False, True], ids=["scalar_pos", "row_pos"])
+@pytest.mark.parametrize("kv", [False, "int8"])
+def test_chunk_then_decode_equivalence(params, stacked, kv, rows):
+    """Two chunk programs, then three decode steps (``decode_step`` at
+    one shared slot, or ``decode_chunk_spec`` at per-row slots): list
+    and stacked caches hold the same K, V and scales at every layer and
+    give the same logits at every step."""
+    tokens, valid = _prompt(B=2, L=16, seed=7)
+    B, L = tokens.shape
+    C, S, steps = 8, 32, 3
+    caches = {
+        "list": (params, init_kv_cache(SPEC, B, S, quantized=kv)),
+        "stacked": (stacked, init_kv_cache(SPEC, B, S, quantized=kv, stacked=True)),
+    }
+    out = {}
+    for form, (p, cache) in caches.items():
+        got = []
+        for start in range(0, L, C):
+            hist = jnp.zeros((B, L - C), bool).at[:, :start].set(valid[:, :start])
+            logits, cache = prefill_chunk_at(
+                p, SPEC, tokens[:, start:start + C], valid[:, start:start + C],
+                cache, hist, valid[:, :start].sum(axis=1).astype(jnp.int32),
+                jnp.int32(start))
+        got.append(logits)
+        lens = valid.sum(axis=1).astype(jnp.int32)
+        mask = jnp.zeros((B, S), bool).at[:, :L].set(valid)
+        for j in range(steps):
+            tok = jnp.asarray([5 + j, 9 + j], jnp.int32)
+            if rows:   # rows write at their own slots, one apart
+                at = jnp.asarray([L + j, L + 4 + j], jnp.int32)
+                logits, cache = decode_chunk_spec(
+                    p, SPEC, tok[:, None], jnp.ones((B, 1), bool), at,
+                    (lens + j)[:, None], cache, mask)
+                logits = logits[:, 0]
+                mask = mask.at[jnp.arange(B), at].set(True)
+            else:
+                mask = mask.at[:, L + j].set(True)
+                logits, cache = decode_step(
+                    p, SPEC, tok, jnp.int32(L + j), lens + j, cache, mask)
+            got.append(logits)
+        out[form] = (got, cache)
+    for a, b in zip(out["list"][0], out["stacked"][0]):
+        np.testing.assert_allclose(a, b, rtol=6e-2, atol=6e-2)
+    _close_caches(out["list"][1], out["stacked"][1])
+
+
+def _interpreted_kernel(monkeypatch):
+    """The int8 decode kernel in interpret mode wherever the model calls
+    it (the model passes no such flag: the test steers it)."""
+    from bcg_tpu.ops import decode_attention as da
+
+    monkeypatch.setattr(
+        da, "decode_attention", functools.partial(da.decode_attention, interpret=True))
+
+
+def _decode_lowering(spec, impl, S, B=2):
+    """The lowered text of one stacked int8 decode step."""
+    params = jax.eval_shape(
+        lambda k: stack_layer_params(init_params(spec, k), spec=spec),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    cache = jax.eval_shape(functools.partial(
+        init_kv_cache, spec, B, S, quantized="int8", stacked=True))
+    step = functools.partial(decode_step, impl=impl)
+    return jax.jit(step, static_argnums=1).lower(
+        params, spec, jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32), jax.ShapeDtypeStruct((B,), jnp.int32),
+        cache, jax.ShapeDtypeStruct((B, S), jnp.bool_)).as_text()
+
+
+def _moved_int8(text, op):
+    """Dims of what each ``stablehlo.<op>`` on an int8 tensor moves: a
+    ``dynamic_slice``'s result, a ``dynamic_update_slice``'s update."""
+    moved = []
+    for line in text.splitlines():
+        if f"stablehlo.{op} " not in line and f"stablehlo.{op}(" not in line:
+            continue
+        types = re.findall(r"tensor<([0-9x]+)xi8>", line.split(" : ")[-1])
+        if types:
+            part = types[-1] if op == "dynamic_slice" else types[1]
+            moved.append(tuple(int(d) for d in part.split("x")))
+    return moved
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_no_layer_of_the_stacked_cache_is_copied(family, impl, monkeypatch):
+    """Static guard on the lowered decode step: the layer scan writes
+    ONE token's K and V into the stacked int8 cache in place and moves
+    no layer's whole entry.  S = 1536 is three blocks of the kernel and
+    no other extent of the program.  Under the kernel (interpret mode
+    here) nothing int8 with all S slots is sliced or written at all;
+    the XLA twin reads the one layer it dequantises, never the stack."""
+    S = 1536
+    spec = SPEC if family == "dense" else HYBRID
+    if impl == "pallas":
+        _interpreted_kernel(monkeypatch)
+    text = _decode_lowering(
+        spec, T.HybridImpl(impl, "xla") if spec.hybrid else impl, S)
+    writes = _moved_int8(text, "dynamic_update_slice")
+    reads = _moved_int8(text, "dynamic_slice")
+    # K and V: one token of one layer, once in the scan's body (interpret
+    # mode's own block buffers are written besides; never S slots wide)
+    token = (1, 2, spec.num_kv_heads, 1, spec.head_dim)
+    assert writes.count(token) == 2 and all(S not in d for d in writes), writes
+    if impl == "pallas":
+        assert reads and all(S not in dims for dims in reads), reads
+    else:
+        assert len(reads) == 2 and all(d[0] == 1 and S in d for d in reads), reads

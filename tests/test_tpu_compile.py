@@ -76,11 +76,11 @@ def _int8_cache_shapes(rows_shape):
     )
 
 
-def _decode_int8(q, k, v, ks, vs, mask, mesh=None):
+def _decode_int8(q, k, v, ks, vs, mask, mesh=None, layer=None):
     from bcg_tpu.ops.decode_attention import decode_attention
 
     return decode_attention(q, k, v, mask, SCALE, k_scale=ks, v_scale=vs,
-                            mesh=mesh)
+                            mesh=mesh, layer=layer)
 
 
 def _chunk_int8(q, k, v, ks, vs, mask, mesh=None):
@@ -142,6 +142,33 @@ class TestOneChip:
             ((B, hkv, s_cache), jnp.float32), ((B, hkv, s_cache), jnp.float32),
             ((B, s_cache), jnp.bool_),
         )
+
+    @pytest.mark.parametrize("hkv, rows", [(8, 4), (30, 1)])
+    def test_int8_decode_reads_its_layer_of_a_stack(self, one, hkv, rows):
+        # The layer scan's form: the whole stacked cache as the operand,
+        # the layer index scalar-prefetched, the layer's blocks found by
+        # the K/V/scale index maps (no slice of the layer beforehand).
+        # Both cells' geometry: 8 KV heads of group 4 at the 1024 block,
+        # 30 of group 1 at 512.  The output shape is how the benchmark's
+        # ``trace_names.decode_attention`` finds the kernel.
+        from bcg_tpu.ops.decode_attention import _pick_block, decode_attention
+
+        layers, s_cache = 4, 5120
+        assert _pick_block(s_cache, None, hkv * DH) == (1024 if hkv == 8 else 512)
+
+        def stacked(q, k, v, ks, vs, mask, layer):
+            return decode_attention(q, k, v, mask, SCALE, k_scale=ks,
+                                    v_scale=vs, layer=layer)
+
+        kv = ((layers, B, hkv, s_cache, DH), jnp.int8)
+        sc = ((layers, B, hkv, s_cache), jnp.float32)
+        text = _compile(
+            stacked, one, ((B, hkv * rows, DH), jnp.bfloat16), kv, kv, sc, sc,
+            ((B, s_cache), jnp.bool_), ((), jnp.int32),
+        ).as_text()
+        assert f"bf16[{B},{hkv},{rows},{DH}]" in text
+        # nothing the size of a layer is produced on the way to the kernel
+        assert f"s8[{B},{hkv},{s_cache},{DH}]" not in text
 
     def test_gated_delta_prefill_chunk(self, one):
         # One 512-token prefill chunk of a delta-rule layer at the
@@ -236,14 +263,22 @@ class TestFourChips:
         # Heads are independent: the kernel needs no collective.
         assert "all-gather" not in compiled.as_text()
 
-    def test_int8_decode_tp4(self, mesh):
-        kv = NamedSharding(mesh, P(None, "tp", None, None))
-        sc = NamedSharding(mesh, P(None, "tp", None))
+    @pytest.mark.parametrize("layers", [(), (2,)], ids=["entry", "stacked"])
+    def test_int8_decode_tp4(self, mesh, layers):
+        # ``stacked``: the layer scan's operands, a leading [Lyr] axis
+        # that replicates and a prefetched layer index.
+        lead = (None,) * len(layers)
+        kv = NamedSharding(mesh, P(*lead, None, "tp", None, None))
+        sc = NamedSharding(mesh, P(*lead, None, "tp", None))
+        q, *cache = _int8_cache_shapes(((B, H, DH), jnp.bfloat16))
+        fn = functools.partial(_decode_int8, mesh=mesh)
+        if layers:
+            fn = functools.partial(fn, layer=1)
         _compile(
-            functools.partial(_decode_int8, mesh=mesh),
+            fn,
             (NamedSharding(mesh, P(None, "tp", None)), kv, kv, sc, sc,
              NamedSharding(mesh, P())),
-            *_int8_cache_shapes(((B, H, DH), jnp.bfloat16)),
+            q, *[(layers + shape, dtype) for shape, dtype in cache],
             ((B, S_CACHE), jnp.bool_),
         )
 
@@ -322,8 +357,9 @@ class TestShardedKernelsOnCpuMesh:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-6, rtol=1e-6)
 
+    @pytest.mark.parametrize("stacked", [False, True])
     @pytest.mark.parametrize("chunk", [False, True])
-    def test_int8_decode_matches_unsharded(self, mesh, chunk):
+    def test_int8_decode_matches_unsharded(self, mesh, chunk, stacked):
         from bcg_tpu.ops.decode_attention import (
             chunk_decode_attention, decode_attention, quantize_kv,
         )
@@ -342,6 +378,10 @@ class TestShardedKernelsOnCpuMesh:
             scale=SCALE, block_s=128, interpret=True,
         )
         ref = fn(q, kq, vq, mask, k_scale=ksc, v_scale=vsc)
+        if stacked:   # the entry as layer 1 of a stack whose layer 0 is zeros
+            kq, vq, ksc, vsc = (
+                jnp.stack([jnp.zeros_like(a), a]) for a in (kq, vq, ksc, vsc))
+            fn = functools.partial(fn, layer=1)
         out = jax.jit(functools.partial(fn, mesh=mesh))(
             q, kq, vq, mask, k_scale=ksc, v_scale=vsc)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
