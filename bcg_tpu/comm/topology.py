@@ -71,9 +71,8 @@ class NetworkTopology:
     def receiver_mask(self) -> np.ndarray:
         """Dense [n, n] bool mask in RECEIVER orientation:
         ``mask[i, j]`` = receiver i hears sender j — the transpose of
-        :meth:`neighbor_mask`, which is what the delivery paths
-        (``runtime/orchestrator._broadcast_receive_spmd`` and the fused
-        mega-round's ``parallel/game_step.masked_exchange``) consume.
+        :meth:`neighbor_mask`, which is what the SPMD delivery path
+        (``runtime/orchestrator._broadcast_receive_spmd``) consumes.
         Kept as a named surface so the orientation convention lives in
         one place instead of ad-hoc ``.T`` at every call site."""
         return self.neighbor_mask().T.copy()

@@ -231,18 +231,6 @@ class AgentConfig:
     # a2a_sim (identical inboxes) to be sound, which the orchestrator
     # additionally enforces.
     shared_core_votes: bool = False
-    # On-device mega-round (ROADMAP item 1, engine/megaround.py): run
-    # each consensus round as ONE fused jit entry — prompt assembly from
-    # device-resident game state, guided decode, in-jit decision parse,
-    # topology-masked exchange, vote tally — with a single per-round
-    # readback instead of the lockstep path's 2 calls x 3 syncs.  Uses
-    # the compact fixed-width mega-round prompt family (NOT the lockstep
-    # history prompts), so it is an experiment-fidelity switch, not a
-    # pure optimization; requires structured output + batched inference
-    # + an a2a_sim-protocol engine whose tokenizer is byte-stable — any
-    # unsupported configuration falls back to lockstep with a one-time
-    # warning.  Env override: BCG_TPU_MEGAROUND=1.
-    megaround: bool = False
 
 
 @dataclass(frozen=True)

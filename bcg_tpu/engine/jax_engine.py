@@ -915,16 +915,6 @@ class JaxEngine(InferenceEngine):
         # (B, S) -> jitted sharded-zero cache initializer (see
         # _init_cache_sharded; memoized so each batch shape compiles once).
         self._cache_init_jits: Dict[Tuple[int, int], Any] = {}
-        # Fused mega-round programs (engine/megaround.py), memoized per
-        # plan STATIC layout + guided signature — values/inbox/round are
-        # traced args, so a steady-state game reuses one compile
-        # (engine.retrace.megaround must stay 0).  _megaround_arrays
-        # keeps each plan's token buffers device-resident across rounds.
-        self._megaround_programs: Dict[Tuple, Any] = {}
-        self._megaround_arrays: Dict[int, Tuple] = {}
-        self._megaround_guided_memo: Dict[Tuple, Any] = {}
-        self.megaround_rounds = 0
-        self.megaround_seconds = 0.0
         _assemble_fn = (
             self._assemble_cache_stacked_fn
             if self.scan_layers
@@ -2112,19 +2102,6 @@ class JaxEngine(InferenceEngine):
         :meth:`_maybe_record_sampler_tpu_lowering`) build both variants
         of the same program without touching the executed loops' cache
         or compile counters."""
-        return jax.jit(
-            self._decode_loop_fn(impl, max_new, top_p, ring, sampler_impl),
-            static_argnames=("L",), donate_argnums=(1,),
-        )
-
-    def _decode_loop_fn(self, impl: str, max_new: int, top_p: float,
-                        ring=None, sampler_impl: Optional[str] = None):
-        """The RAW (unjitted) standard decode loop body —
-        :meth:`_build_decode_loop` wraps it in ``jax.jit`` for the
-        lockstep path; the mega-round program (engine/megaround.py)
-        inlines it directly into the fused round jit, so both paths
-        execute the SAME loop (the gate's decision-identity check
-        depends on there being exactly one implementation)."""
         spec = self.spec
         eos_id = self.tokenizer.eos_id
         sampler = self._make_masked_sampler(eos_id, top_p, impl=sampler_impl)
@@ -2183,7 +2160,7 @@ class JaxEngine(InferenceEngine):
             # (measured: pushed an 8B compile 8 GB past HBM capacity).
             return out, (rng, i), cache
 
-        return loop
+        return jax.jit(loop, static_argnames=("L",), donate_argnums=(1,))
 
     def _maybe_record_paged_tpu_lowering(self, max_new: int, top_p: float,
                                          args: tuple) -> None:
@@ -3661,207 +3638,6 @@ class JaxEngine(InferenceEngine):
         )
         return [t.strip() for t in texts]
 
-    # ------------------------------------------------------------ mega-round
-
-    def prepare_megaround(self, n_agents: int, lo: int, hi: int,
-                          max_rounds: int):
-        """Build (and slot-splice-VERIFY) the fused-round plan for this
-        engine's tokenizer + chat template, or raise
-        ``MegaroundUnsupported`` so the orchestrator falls back to the
-        lockstep path.  Dense single-device engines only: the fused
-        program allocates its own per-phase caches in-trace (a paged
-        pool's donation discipline and a multi-device mesh's sharding
-        would both need their own round program — fallback matrix in
-        DESIGN.md)."""
-        from bcg_tpu.engine.megaround import (
-            MegaroundTemplate,
-            MegaroundUnsupported,
-            build_plan,
-        )
-
-        if self.spec.hybrid:
-            raise MegaroundUnsupported(
-                f"{self.spec.name} is a hybrid (layer_types) spec: the "
-                "fused round's in-trace caches hold K/V only"
-            )
-        if self._paged is not None:
-            raise MegaroundUnsupported(
-                "paged-KV engine (the fused round allocates dense "
-                "per-phase caches in-trace)"
-            )
-        if self._mesh_devices > 1:
-            raise MegaroundUnsupported(
-                f"multi-device mesh ({self._mesh_devices} devices)"
-            )
-
-        def chat_parts(system: str, user: str):
-            return format_chat_parts(
-                self.config.model_name, system, user,
-                self.config.disable_qwen3_thinking,
-            )
-
-        return build_plan(
-            MegaroundTemplate(n_agents=n_agents, lo=lo, hi=hi,
-                              max_rounds=max_rounds),
-            self.tokenizer, chat_parts, self.max_model_len, _LEN_BUCKETS,
-        )
-
-    def _megaround_guided(self, schema: Dict, n: int):
-        """Device guided-decode tables for one schema replicated over
-        ``n`` rows, memoized per (schema, n) so steady-state rounds
-        re-dispatch the same device arrays (no per-round H2D)."""
-        key = (json.dumps(schema, sort_keys=True), n)
-        got = self._megaround_guided_memo.get(key)
-        if got is None:
-            guide = compile_schema(
-                schema, self._token_bytes, vocab_id=self.tokenizer.vocab_id,
-                compact=getattr(self.config, "guided_compact_json", False),
-            )
-            batch = GuidedBatch([guide] * n)
-            sig = (batch.num_unique, batch.tables.shape[1],
-                   batch.tables.shape[2])
-            got = (
-                tuple(jnp.asarray(a) for a in (
-                    batch.tables, batch.accepting, batch.min_budget,
-                    batch.dfa_ids, batch.init_states,
-                )),
-                sig,
-            )
-            self._megaround_guided_memo[key] = got
-        return got
-
-    def run_megaround(self, plan, values, inbox, round_num: int,
-                      receiver_mask, is_byzantine, initial_values,
-                      equivocators=None):
-        """Run one WHOLE consensus round as a single jit entry and
-        return its :class:`~bcg_tpu.engine.megaround.MegaroundResult`
-        after ONE packed readback (``engine.hostsync.site.
-        round_readback``, attributed to the ``megaround`` entry).
-
-        Every per-round quantity is a traced argument — the compile key
-        is the plan's static layout + guided signatures only, so
-        varying round number, inbox contents, or convergence state can
-        NEVER retrace (gated: engine.retrace.megaround == 0)."""
-        from bcg_tpu.engine.megaround import (
-            MegaroundResult,
-            build_round_program,
-        )
-
-        t0 = time.perf_counter()
-        n = plan.n_agents
-        dev = self._megaround_arrays.get(id(plan))
-        if dev is None:
-            from bcg_tpu.models.transformer import init_kv_cache
-
-            phase_dev = []
-            for phase in (plan.decide, plan.vote):
-                base = jnp.asarray(phase.base)
-                valid = jnp.asarray(phase.valid)
-                # Static-prefix KV, prefilled ONCE per plan: columns
-                # [0, prefix_len) never change across rounds, so every
-                # fused round prefills only the slot-bearing suffix
-                # against this cache (prefill_with_prefix in the round
-                # program) — the fused path's analogue of the lockstep
-                # radix prefix cache.
-                P = phase.prefix_len
-                S = phase.L + phase.max_new + 1
-                S += (-S) % self._kv_align
-                self._note_jit_shape(
-                    "megaround_prefix", (n, P, S),
-                    names=("rows", "prefix_len", "cache_len"),
-                )
-                cache = init_kv_cache(
-                    self.spec, n, S, quantized=self.kv_quantized,
-                    stacked=self.scan_layers,
-                )
-                _, cache = self._prefill(
-                    self.params, tokens=base[:, :P], valid=valid[:, :P],
-                    cache=cache,
-                )
-                phase_dev.extend([base, valid, jax.block_until_ready(cache)])
-            dev = tuple(phase_dev) + (
-                jnp.asarray(plan.val_table), jnp.asarray(plan.round_table),
-            )
-            # One resident plan per engine: a game swaps plans rarely
-            # (re-prepare), so don't accumulate dead token buffers.
-            self._megaround_arrays = {id(plan): dev}
-        guided_d, sig_d = self._megaround_guided(plan.decide.schema, n)
-        guided_v, sig_v = self._megaround_guided(plan.vote.schema, n)
-        key = plan.static_key() + (
-            sig_d, sig_v, self._resolved_loop_impl(), self._sampler_loop_impl,
-        )
-        prog = self._megaround_programs.get(key)
-        if prog is None:
-            self._note_jit_shape(
-                "megaround", key,
-                names=("agents", "lo", "hi", "max_rounds", "decide_layout",
-                       "vote_layout", "decide_sig", "vote_sig", "attn_impl",
-                       "sampler_impl"),
-            )
-            prog = jax.jit(build_round_program(plan, self))
-            self._megaround_programs[key] = prog
-        self._key, sub = jax.random.split(self._key)
-        with obs_tracer.span(
-            "engine.megaround", args={"agents": n, "round": int(round_num)}
-        ):
-            with obs_compile.time_block("megaround"):
-                outs = obs_hlo.wrap("megaround", prog)(
-                    self.params, *dev,
-                    jnp.asarray(np.asarray(values, np.int32)),
-                    jnp.asarray(np.asarray(inbox, np.int32)),
-                    jnp.int32(round_num),
-                    jnp.asarray(np.asarray(receiver_mask, bool)),
-                    jnp.asarray(np.asarray(is_byzantine, bool)),
-                    jnp.asarray(np.asarray(initial_values, np.int32)),
-                    # Equivocators enter TRACED (like is_byzantine): a
-                    # strategy switch can never retrace; all-False keeps
-                    # the exchange the plain broadcast matrix.
-                    jnp.asarray(
-                        np.zeros(n, bool) if equivocators is None
-                        else np.asarray(equivocators, bool)
-                    ),
-                    guided_d, guided_v, sub,
-                )
-            # THE round's one device->host sync: everything the host
-            # needs (values, deliveries, votes, tally, consensus) comes
-            # back in this packed tuple.
-            obs_hostsync.note("round_readback", entry="megaround")
-            outs = [np.asarray(o) for o in jax.block_until_ready(outs)]
-        (proposed, new_values, received, deliveries, vote_raw, votes,
-         stop, cont, term, cons_ok, cons_val, cons_pct,
-         steps_d, steps_v) = outs
-        steps = int(steps_d) + int(steps_v)
-        self.last_decode_steps = steps
-        self.total_decode_steps += steps
-        self.megaround_rounds += 1
-        self.megaround_seconds += time.perf_counter() - t0
-        obs_counters.inc("engine.megaround.rounds")
-        obs_hostsync.publish()
-        from bcg_tpu.runtime import metrics as _metrics
-
-        _metrics.publish_megaround(self.megaround_stats())
-        return MegaroundResult(
-            proposed=proposed, values=new_values, received=received,
-            deliveries=deliveries, vote_raw=vote_raw, votes=votes,
-            stop=int(stop), cont=int(cont), terminate=bool(term),
-            has_consensus=bool(cons_ok), consensus_value=int(cons_val),
-            agreement_pct=float(cons_pct), syncs=1,
-        )
-
-    def megaround_stats(self) -> Dict[str, Any]:
-        """The bench JSON ``megaround`` block: fused-round volume, the
-        per-round sync profile (1 by construction — exactly one
-        ``round_readback`` note per fused round), and fused-round
-        throughput over engine wall-clock."""
-        return {
-            "fused_rounds": self.megaround_rounds,
-            "syncs_per_round": 1.0 if self.megaround_rounds else 0.0,
-            "rounds_per_sec": (
-                self.megaround_rounds / self.megaround_seconds
-                if self.megaround_seconds > 0 else 0.0
-            ),
-        }
-
     def kv_pool_stats(self) -> Optional[Dict[str, Any]]:
         """Paged-pool snapshot (block counts, free-block headroom bytes,
         radix prefix hit rate, the ACTIVE attention impl + kernel knobs)
@@ -3912,9 +3688,6 @@ class JaxEngine(InferenceEngine):
     def shutdown(self) -> None:
         self.params = None
         self._decode_loops.clear()
-        self._megaround_programs.clear()
-        self._megaround_arrays.clear()
-        self._megaround_guided_memo.clear()
         self._prefix_cache.clear()
         if self._paged is not None:
             self._paged.close()

@@ -200,58 +200,6 @@ class HFTokenizer(Tokenizer):
         return out
 
 
-def is_byte_stable(tokenizer: Tokenizer, probe: str = "") -> bool:
-    """True when ``encode`` maps every character of a template to
-    exactly its UTF-8 bytes — the property the mega-round's
-    template-token assembly needs: slot token positions equal byte
-    offsets, and substituting one fixed-width slot's text can never
-    re-segment neighbouring tokens.  Checked empirically on the probe
-    (plus a digit/punctuation alphabet) rather than by isinstance, so
-    any future byte-faithful tokenizer qualifies and any BPE merge
-    disqualifies itself.  BPE vocabularies fail here and the mega-round
-    falls back to the lockstep path (DESIGN.md fallback matrix)."""
-    text = probe + "0123456789 .:;-_{}\"'\nagent value Round"
-    toks = tokenizer.encode(text)
-    if list(toks) != list(text.encode("utf-8")):
-        return False
-    # Concat stability: per-fragment encodes must concatenate to the
-    # whole — a merge across a fragment boundary breaks slot splicing.
-    mid = len(text) // 2
-    return (
-        tokenizer.encode(text[:mid]) + tokenizer.encode(text[mid:])
-        == list(toks)
-    )
-
-
-def number_token_table(
-    tokenizer: Tokenizer, lo: int, hi: int, width: Optional[int] = None,
-):
-    """Pre-tokenized fixed-width decimal slot table for template
-    assembly: row k (k in [0, hi-lo]) holds the tokens of ``lo+k``
-    zero-padded to ``width`` chars; the FIRST row (index 0 of the
-    returned table) is the all-dashes "absent" slot (``'-' * width``),
-    so a device-side gather with index ``where(v >= 0, v - lo + 1, 0)``
-    assembles present and absent slots from one table.  Returns
-    ``(table [hi-lo+2, width] int32, width)``.  Requires a byte-stable
-    tokenizer (:func:`is_byte_stable`) — widths are then exact."""
-    import numpy as np
-
-    width = width or len(str(hi))
-    rows = ["-" * width] + [
-        str(v).zfill(width) for v in range(lo, hi + 1)
-    ]
-    table = np.zeros((len(rows), width), dtype=np.int32)
-    for i, text in enumerate(rows):
-        toks = tokenizer.encode(text)
-        if len(toks) != width:
-            raise ValueError(
-                f"slot text {text!r} tokenized to {len(toks)} != width "
-                f"{width} tokens — tokenizer is not byte-stable"
-            )
-        table[i] = toks
-    return table, width
-
-
 def tokenizer_for_model(model_name: str, model_path: Optional[str] = None) -> Tokenizer:
     if model_name.startswith("bcg-tpu/"):
         from bcg_tpu.models.configs import spec_for_model

@@ -218,91 +218,6 @@ def _build_numpy(char_dfa: CharDFA, token_bytes: Sequence[bytes]) -> np.ndarray:
     return out
 
 
-def digit_token_tables(token_bytes: Sequence[bytes]):
-    """Per-token decimal tables for the IN-JIT integer parse
-    (:func:`parse_int_values`): ``digit_len[t]`` = number of decimal
-    digit characters token t contributes (0 for any token containing a
-    non-digit byte — including ``b""`` pads), ``digit_val[t]`` = the
-    integer value of those digits.  Built once per tokenizer on the
-    host; the byte tokenizer's single-char digit tokens give
-    ``digit_len`` in {0, 1}, a trained-BPE vocabulary's multi-digit
-    tokens land their full width."""
-    vocab = len(token_bytes)
-    digit_len = np.zeros(vocab, dtype=np.int32)
-    digit_val = np.zeros(vocab, dtype=np.int32)
-    for i, t in enumerate(token_bytes):
-        if t and all(0x30 <= b <= 0x39 for b in t):
-            digit_len[i] = len(t)
-            digit_val[i] = int(t.decode("ascii"))
-    return digit_len, digit_val
-
-
-def walk_token_dfa(
-    tables,        # [U, S, V] int per-unique-guide transition tables
-    dfa_ids,       # [B] int32 row -> unique-guide index
-    init_states,   # [B] int32 start states
-    out_tokens,    # [B, T] int32 emitted tokens (EOS-filled past the end)
-    eos_id: int,
-):
-    """Walk each row's emitted tokens through its token DFA inside jit,
-    returning the terminal state per row (-1 once any transition was
-    forbidden — the row can never reach accepting, matching the host
-    parse failing).  EOS ends the walk: the decode loop EOS-fills past
-    each row's end, and EOS itself is a sampler-level stop, not a table
-    transition.  A ``lax.scan`` of two gathers per emitted position —
-    the decision budgets are tens of tokens, so this is noise next to
-    one decode step."""
-    import jax
-    import jax.numpy as jnp
-
-    def step(states, tok_col):
-        live = (tok_col != eos_id) & (states >= 0)
-        nxt = tables[dfa_ids, jnp.maximum(states, 0), tok_col].astype(jnp.int32)
-        return jnp.where(live, nxt, states), None
-
-    final_states, _ = jax.lax.scan(
-        step, init_states.astype(jnp.int32), out_tokens.T
-    )
-    return final_states
-
-
-def parse_int_values(
-    out_tokens,    # [B, T] int32 emitted tokens (EOS-filled past the end)
-    eos_id: int,
-    digit_len,     # [V] int32 (digit_token_tables)
-    digit_val,     # [V] int32
-    final_states,  # [B] int32 terminal DFA states from the decode loop
-    accepting,     # [U, S] bool per-unique-guide accepting table
-    dfa_ids,       # [B] int32 row -> unique-guide index
-):
-    """Parse each row's emitted integer ENTIRELY inside jit — the
-    mega-round's replacement for the host-side ``json.loads``: decimal
-    digits are accumulated positionally (each digit token's value scaled
-    by 10^(digits to its right)), guarded by the terminal DFA state so a
-    row whose automaton did not reach an accepting state parses to -1
-    (abstain), exactly like a host-side JSON failure.  Correct for any
-    integer-valued schema whose NON-digit skeleton contains no digit
-    characters (the guided ``{"value": N}`` schemas) on any tokenizer
-    whose digit-carrying tokens are digit-ONLY (checked by
-    :func:`digit_token_tables` construction: mixed tokens contribute 0
-    digits and would surface as a parse mismatch in the perf_gate
-    oracle-identity scenario, never silently)."""
-    import jax.numpy as jnp
-
-    # Accept host numpy tables: numpy fancy-indexing rejects tracers.
-    digit_len = jnp.asarray(digit_len)
-    digit_val = jnp.asarray(digit_val)
-    accepting = jnp.asarray(accepting)
-    toks = out_tokens
-    past_eos = jnp.cumsum((toks == eos_id).astype(jnp.int32), axis=1) > 0
-    dl = jnp.where(past_eos, 0, digit_len[toks])        # [B, T]
-    # Digits to the RIGHT of each position: reverse exclusive cumsum.
-    suffix = jnp.flip(jnp.cumsum(jnp.flip(dl, axis=1), axis=1), axis=1) - dl
-    acc = (digit_val[toks] * jnp.where(dl > 0, 10 ** suffix, 0)).sum(axis=1)
-    ok = accepting[dfa_ids, final_states] & (dl.sum(axis=1) > 0)
-    return jnp.where(ok, acc, -1).astype(jnp.int32)
-
-
 def build_token_dfa(
     char_dfa: CharDFA,
     token_bytes: Sequence[bytes],

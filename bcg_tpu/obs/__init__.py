@@ -22,8 +22,7 @@ slabs / prefix entries / spec slots (``hbm.*`` gauges).
 ``bcg_tpu.obs.hostsync`` — runtime host↔device transfer auditor
 (``BCG_TPU_HOSTSYNC``): per-sync span/jit-entry attribution
 (``engine.hostsync.*``), the ``game.host_syncs`` per-round histogram,
-and the perf_gate ``hostsync`` drift gate for ROADMAP item 1's
-host-syncs-per-round target.
+and the perf_gate ``hostsync`` drift gate on syncs per round.
 
 None of these modules import jax at module scope: flag-only consumers
 (bench.py's error path) stay light.  Enable tracing with

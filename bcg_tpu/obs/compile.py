@@ -1,9 +1,8 @@
 """Compile-cost observability (``BCG_TPU_COMPILE_OBS``) + profiler
 capture windows (``BCG_TPU_PROFILE`` / ``BCG_TPU_PROFILE_ROUNDS``).
 
-ROADMAP item 1 fuses the whole consensus round into one
-``lax``-controlled jit entry, which makes COMPILATION the next dominant
-invisible cost: the ``engine.compile.<entry>`` / ``engine.retrace.<entry>``
+COMPILATION is a dominant invisible cost of a boot and of any call
+off the warmed shapes: the ``engine.compile.<entry>`` / ``engine.retrace.<entry>``
 counters (PR 4) say *that* a trace-cache miss happened but never *why*
 or *how long it took*, and the sweep tier multiplies distinct jit
 signatures across tenants.  This module closes the gap the way

@@ -1,8 +1,7 @@
 """Runtime host↔device transfer auditor (``BCG_TPU_HOSTSYNC``).
 
-ROADMAP item 1 ("on-device mega-round") names its target metric —
-*host-syncs per round → ~1* — but until this module nothing at runtime
-COUNTED the device→host round-trips the game loop actually performs:
+Until this module nothing at runtime COUNTED the device→host
+round-trips the game loop actually performs:
 ``BCG-HOST-SYNC`` is a static AST rule over traced regions, blind to
 the eager seams (decode readback, ``block_until_ready`` barriers,
 ``np.asarray`` coercions, the guided parse) where the real per-decision
@@ -84,9 +83,8 @@ from bcg_tpu.runtime import envflags
 # ``serve.request`` flatten to ``serve_request``.
 _SANITIZE_RE = re.compile(r"[^a-z0-9_]")
 
-# Per-round sync histogram bounds.  Today's lockstep round performs a
-# handful of syncs per batched engine call; the mega-round target is ~1,
-# so the ladder resolves both the current regime and the fused one.
+# Per-round sync histogram bounds.  A lockstep round performs a
+# handful of syncs per batched engine call (six a round).
 ROUND_SYNC_BOUNDS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
                      512.0)
 
@@ -213,8 +211,7 @@ class HostSyncAuditor:
         the same time (concurrent games sharing one serving engine)
         marks BOTH windows overlapped: the process-wide total cannot
         split a shared dispatch batch's syncs between games, and an
-        overcounted observation would corrupt exactly the metric the
-        mega-round work drives down."""
+        overcounted observation would corrupt the per-round metric."""
         with self._round_lock:
             window = _RoundWindow(self.total())
             if self._open_rounds:

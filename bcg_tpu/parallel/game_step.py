@@ -24,34 +24,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-
 def _masked_receive(all_vals: jax.Array, mask_rows: jax.Array) -> jax.Array:
-    """The exchange body shared by every delivery path: row i of the
-    result holds agent j's value iff ``mask_rows[i, j]`` AND j proposed
-    (``all_vals[j] >= 0``), else -1.  ``all_vals`` is the full [n] value
-    vector, ``mask_rows`` the (possibly sharded) receiver-mask rows —
-    the shard_map collectives and the dense mega-round program both call
-    this, so topology semantics can never fork between them."""
+    """The exchange body shared by the shard_map delivery paths: row i
+    of the result holds agent j's value iff ``mask_rows[i, j]`` AND j
+    proposed (``all_vals[j] >= 0``), else -1.  ``all_vals`` is the full
+    [n] value vector, ``mask_rows`` the (possibly sharded) receiver-mask
+    rows."""
     return jnp.where(mask_rows & (all_vals >= 0)[None, :], all_vals[None, :], -1)
-
-
-def masked_exchange(
-    values: jax.Array,         # [n] int32, -1 = abstain
-    receiver_mask: jax.Array,  # [n, n] bool, mask[i, j] = i receives from j
-) -> Tuple[jax.Array, jax.Array]:
-    """Dense (replicated, jit-composable) topology-masked exchange — the
-    mega-round form of :func:`exchange_values`: no mesh, no collective,
-    so it inlines into the fused round program.  Returns ``(received,
-    deliveries)`` where ``received[i, j]`` is agent j's value as seen by
-    agent i (-1 = not delivered) and ``deliveries[i]`` is the number of
-    proposals delivered to receiver i — the adjacency mask applied as a
-    masked matmul over the proposed-indicator vector, which is also the
-    per-receiver count the orchestrator's ``deliveries`` game event and
-    message accounting read."""
-    received = _masked_receive(values, receiver_mask)
-    proposed = (values >= 0).astype(jnp.int32)
-    deliveries = receiver_mask.astype(jnp.int32) @ proposed
-    return received, deliveries
 
 
 def _masked_receive_matrix(
@@ -64,100 +43,6 @@ def _masked_receive_matrix(
     holds that value iff ``mask_rows[i, j]`` AND the sender proposed
     (``proposals[i, j] >= 0``), else -1."""
     return jnp.where(mask_rows & (proposals >= 0), proposals, -1)
-
-
-def masked_exchange_matrix(
-    proposals: jax.Array,      # [n, n] int32, [i, j] = j's value for i
-    receiver_mask: jax.Array,  # [n, n] bool, mask[i, j] = i receives from j
-) -> Tuple[jax.Array, jax.Array]:
-    """Per-receiver form of :func:`masked_exchange` — the mask·values
-    matmul generalized to an elementwise mask over a proposal MATRIX,
-    which is what equivocating adversaries need (ROADMAP item 2: one
-    sender, different values to different receivers).  When every
-    column of ``proposals`` is constant (nobody equivocates) this is
-    numerically identical to ``masked_exchange(proposals[0], mask)``
-    (tested), so the fused mega-round program routes ALL rounds through
-    it without changing the non-equivocating semantics."""
-    received = _masked_receive_matrix(proposals, receiver_mask)
-    delivered = receiver_mask & (proposals >= 0)
-    deliveries = delivered.astype(jnp.int32).sum(axis=1)
-    return received, deliveries
-
-
-def equivocate_proposals(
-    values: jax.Array,        # [n] int32 base proposals, -1 = abstain
-    equivocators: jax.Array,  # [n] bool, True = sender equivocates
-    lo: int,
-    hi: int,
-) -> jax.Array:
-    """Expand base proposals to the per-receiver proposal matrix:
-    column j is constant (the broadcast value) for honest/non-
-    equivocating senders, and the deterministic per-receiver spread
-    :func:`bcg_tpu.scenarios.strategies.equivocation_value` for
-    equivocating senders that proposed.  Abstaining senders stay -1
-    for every receiver.  Pure jnp, inlines into the fused round
-    program; the all-False case is exactly ``broadcast_to(values)``,
-    preserving the mega-round's greedy identity to the lockstep
-    oracle."""
-    from bcg_tpu.scenarios.strategies import equivocation_value
-
-    n = values.shape[0]
-    broadcast = jnp.broadcast_to(values[None, :], (n, n))
-    receiver_idx = jnp.arange(n, dtype=values.dtype)[:, None]
-    spread = equivocation_value(values[None, :], receiver_idx, lo, hi)
-    return jnp.where(
-        equivocators[None, :] & (values >= 0)[None, :], spread, broadcast
-    )
-
-
-def tally_votes_dense(votes: jax.Array) -> Dict[str, jax.Array]:
-    """Dense form of :func:`tally_votes` (same vote conventions, same
-    2n/3 rule from reference byzantine_consensus.py:373-398) — scalar
-    outputs, no mesh, so the mega-round program can inline it."""
-    stop = (votes == 1).sum()
-    cont = (votes == 0).sum()
-    abstain = (votes == -1).sum()
-    total = stop + cont + abstain
-    return {
-        "stop": stop,
-        "continue": cont,
-        "abstain": abstain,
-        "terminate": stop * 3 >= total * 2,
-        "half_stop": stop * 2 >= total,
-    }
-
-
-def check_consensus_dense(
-    values: jax.Array,          # [n] int32 current values, -1 = none
-    is_byzantine: jax.Array,    # [n] bool
-    initial_values: jax.Array,  # [n] int32 honest initials, -1 for Byz
-) -> Dict[str, jax.Array]:
-    """Dense form of :func:`check_consensus_spmd` — the reference's
-    exact rule (byzantine_consensus.py:182-249): ALL honest agents hold
-    the same value AND it is some honest agent's initial value.  Scalar
-    outputs; shares the pairwise-equality modal count with the spmd
-    body so the two paths cannot diverge semantically."""
-    honest_valid = (~is_byzantine) & (values >= 0)
-    n_honest = honest_valid.sum()
-    same = honest_valid[:, None] & honest_valid[None, :] & (
-        values[:, None] == values[None, :]
-    )
-    counts = jnp.where(honest_valid, same.sum(axis=1), 0)
-    modal_idx = jnp.argmax(counts)
-    ref = values[modal_idx]
-    modal_count = counts[modal_idx]
-    agreement = jnp.where(
-        n_honest > 0, modal_count / jnp.maximum(n_honest, 1) * 100.0, 0.0
-    )
-    all_equal = (modal_count == n_honest) & (n_honest > 0)
-    from_initial = (
-        (initial_values == ref) & ~is_byzantine & (initial_values >= 0)
-    ).any()
-    return {
-        "has_consensus": all_equal & from_initial,
-        "consensus_value": ref,
-        "agreement_pct": agreement,
-    }
 
 
 def exchange_values(
@@ -193,8 +78,7 @@ def exchange_proposals(
     scalar, so the gather runs over sender columns and each shard then
     masks its own receiver rows.  With every column constant this
     returns exactly what ``exchange_values(proposals[0], mask, mesh)``
-    returns (tested) — the SPMD twin of
-    :func:`masked_exchange_matrix`."""
+    returns (tested)."""
     n = proposals.shape[0]
     rows_per = n // mesh.shape[axis_name]
 
@@ -257,7 +141,7 @@ def exchange_values_global(
         # each HOST can read the whole round locally.
         return jax.lax.all_gather(received, axis_name, tiled=True)
 
-    # check_rep=False: the trailing all_gather DOES replicate the
+    # check_vma=False: the trailing all_gather DOES replicate the
     # output over dp, but shard_map's static replication checker cannot
     # see through a tiled gather to prove it.
     f = jax.shard_map(
@@ -265,7 +149,7 @@ def exchange_values_global(
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name, None)),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )
     out = f(values, mask)
     return np.asarray(out.addressable_shards[0].data)

@@ -205,20 +205,8 @@ _register(
     "seams (plus intercepted jax.device_get), attributed to the active "
     "tracer span or jit entry — engine.hostsync.* counters, the "
     "game.host_syncs per-round histogram, and the perf_gate 'hostsync' "
-    "scenario's syncs-per-round baseline (ROADMAP item 1's target "
-    "metric — the on-device mega-round).  Off: zero surface — nothing "
+    "scenario's syncs-per-round baseline.  Off: zero surface — nothing "
     "registered, nothing intercepted.",
-)
-_register(
-    "BCG_TPU_MEGAROUND", "bool", False,
-    "On-device mega-round (ROADMAP item 1, engine/megaround.py): run "
-    "each consensus round as ONE fused jit entry (prompt assembly, "
-    "guided decode, in-jit parse, masked exchange, vote tally) with a "
-    "single per-round readback.  Equivalent to AgentConfig.megaround; "
-    "unsupported configurations (free-text decisions, sequential "
-    "orchestrator, paged/multi-device engines, non-byte-stable "
-    "tokenizers) fall back to the lockstep path with a one-time "
-    "warning.",
 )
 
 # BCG_TPU_HLO_CENSUS / METRICS / EVENTS — device-cost observability

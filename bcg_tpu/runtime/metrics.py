@@ -111,22 +111,6 @@ def publish_hostsync(snapshot: Optional[Dict]) -> None:
     LAST_HOSTSYNC = snapshot
 
 
-# Latest fused mega-round summary (engine ``megaround_stats``:
-# fused-round count, syncs per fused round, rounds/sec) — published by
-# JaxEngine.run_megaround / FakeEngine.run_megaround after every fused
-# round so bench.py can attach the ``megaround`` block on success AND
-# error paths, mirroring LAST_HOSTSYNC.  None until a fused round runs
-# (i.e. always None unless the mega-round is enabled).
-LAST_MEGAROUND: Optional[Dict] = None
-
-
-def publish_megaround(snapshot: Optional[Dict]) -> None:
-    """Record the most recent fused mega-round summary (called by the
-    engines' ``run_megaround``)."""
-    global LAST_MEGAROUND
-    LAST_MEGAROUND = snapshot
-
-
 # Latest compile-cost summary (obs/compile.summary: per-entry compile
 # milliseconds, first-compile vs retrace split, cache-entry population,
 # retrace-cause records) — published by the observer at every

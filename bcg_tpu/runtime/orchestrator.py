@@ -26,7 +26,6 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
-import warnings
 from typing import Dict, List, Optional, Tuple
 
 from bcg_tpu.agents import create_agent
@@ -213,16 +212,6 @@ class BCGSimulation:
         self._spmd_mask_np = None
         self._spmd_multiprocess = False
         self._spmd_message_count = 0
-        # On-device mega-round (AgentConfig.megaround / BCG_TPU_MEGAROUND):
-        # the whole Decide -> Exchange -> Vote pipeline as ONE jit entry
-        # (engine.run_megaround).  Eligibility is resolved once on the
-        # first round (_maybe_megaround); the inbox matrix carries each
-        # round's delivered ABSOLUTE values into the next round's prompts.
-        self._megaround_plan = None
-        self._megaround_resolved = False
-        self._megaround_inbox = None   # [n, n] int32, -1 = no delivery
-        self._megaround_mask = None    # receiver-view adjacency [n, n]
-        self._megaround_rounds = 0
 
     @staticmethod
     def _next_run_number(json_dir: str) -> str:
@@ -575,9 +564,8 @@ class BCGSimulation:
 
         When the host-sync auditor is on (BCG_TPU_HOSTSYNC), the
         device->host transfers observed inside the round span land in
-        the ``game.host_syncs`` per-round histogram — ROADMAP item 1's
-        target metric (host-syncs per round -> ~1, reached by the fused
-        mega-round path), measured where the round actually runs.
+        the ``game.host_syncs`` per-round histogram (six a lockstep
+        round), measured where the round actually runs.
         Rounds of concurrent games overlapping in
         one process are counted (engine.hostsync.rounds_overlapped)
         instead of observed — the process-wide total cannot split a
@@ -615,15 +603,6 @@ class BCGSimulation:
         self.logger.log("=" * 60)
         if self._recorder:
             self._recorder.round_start(round_num)
-
-        # On-device mega-round: Decide -> Exchange -> Vote runs as ONE
-        # jit entry (engine.run_megaround) and the host only applies the
-        # packed result — the lockstep phases below never execute.  Any
-        # failed gate falls back to lockstep with a one-time warning.
-        if self._maybe_megaround() is not None:
-            agent_votes = self._run_megaround_phases(round_num)
-            self._advance_and_record(round_num, agent_votes)
-            return
 
         phase = Phase.PROPOSE
         game_state = self.game.get_game_state()
@@ -761,14 +740,6 @@ class BCGSimulation:
                     vote = agent.vote_to_terminate(game_state)
                     agent_votes[aid] = vote
 
-        self._advance_and_record(round_num, agent_votes)
-
-    def _advance_and_record(
-        self, round_num: int, agent_votes: Dict[str, Optional[bool]]
-    ) -> None:
-        """Round tail shared by the lockstep and mega-round paths: vote
-        events, game/network advance, per-round bookkeeping, checkpoints.
-        """
         if self._recorder:
             for aid, vote in agent_votes.items():
                 self._recorder.vote(
@@ -884,10 +855,10 @@ class BCGSimulation:
             dp = next(d for d in range(min(n, n_dev), 0, -1) if n % d == 0)
             self._spmd_mesh = build_mesh(dp=dp)
             # Receiver view: row i holds the senders whose OUT-edges
-            # reach i — the transpose of neighbor_mask()'s mask[s, adj[s]]
-            # — matching the host protocol's broadcast_to_neighbors
-            # delivery for asymmetric custom adjacency.
-            self._spmd_mask_np = self.topology.neighbor_mask().T.copy()
+            # reach i, matching the host protocol's
+            # broadcast_to_neighbors delivery for asymmetric custom
+            # adjacency.
+            self._spmd_mask_np = self.topology.receiver_mask()
             self._spmd_mask = jnp.asarray(self._spmd_mask_np)
             # dp-across-hosts (the sweep tier's cooperative one-big-game
             # mode): every rank runs this same lockstep loop, so the
@@ -976,197 +947,6 @@ class BCGSimulation:
             [self.game.agents[a].proposed_value is not None for a in ids]
         )
         self._spmd_message_count += int((mask_np & proposed[None, :]).sum())
-
-    # ------------------------------------------------------- mega-round path
-
-    def _maybe_megaround(self):
-        """Resolve (once per simulation) whether rounds run fused.
-
-        Returns the prepared :class:`~bcg_tpu.engine.megaround
-        .MegaroundPlan` when every gate passes, else None (lockstep).
-        The fallback matrix (DESIGN.md "Mega-round"):
-
-        * free-text decisions / sequential dispatch — the fused program
-          only speaks guided integer JSON, so it requires both
-          ``use_batched_inference`` and ``use_structured_output``;
-        * lossy or delayed channels — drop/delay realizations are host
-          protocol semantics the dense on-device exchange does not model;
-        * engine capability — the engine must expose
-          ``prepare_megaround``/``run_megaround`` AND accept this game's
-          shape (paged KV pools, multi-device meshes, non-byte-stable
-          tokenizers and negative value ranges all raise
-          ``MegaroundUnsupported``/``ValueError`` at plan build).
-
-        A requested-but-unavailable mega-round warns ONCE and the game
-        proceeds lockstep — flipping BCG_TPU_MEGAROUND on can never make
-        a previously-working configuration crash.
-        """
-        if self._megaround_plan is not None:
-            return self._megaround_plan
-        if self._megaround_resolved:
-            return None
-        self._megaround_resolved = True
-        want = bool(self.config.agent.megaround) or envflags.get_bool(
-            "BCG_TPU_MEGAROUND"
-        )
-        if not want:
-            return None
-        reason = None
-        if not (
-            self.config.agent.use_batched_inference
-            and self.config.agent.use_structured_output
-        ):
-            reason = (
-                "free-text / sequential decisions cannot fuse (requires "
-                "use_batched_inference + use_structured_output)"
-            )
-        elif self.config.communication.protocol_type != "a2a_sim":
-            reason = (
-                f"protocol_type={self.config.communication.protocol_type!r}:"
-                " lossy/delayed channel semantics live in the host protocol"
-            )
-        elif not hasattr(self.engine, "prepare_megaround"):
-            reason = (
-                f"engine {type(self.engine).__name__} has no fused round "
-                "entry"
-            )
-        if reason is None:
-            from bcg_tpu.engine.megaround import MegaroundUnsupported
-
-            lo, hi = self.config.game.value_range
-            try:
-                self._megaround_plan = self.engine.prepare_megaround(
-                    n_agents=len(self.game.agents),
-                    lo=lo,
-                    hi=hi,
-                    max_rounds=self.game.max_rounds,
-                )
-            except (MegaroundUnsupported, ValueError) as exc:
-                # Only the plan's own refusals mean "unavailable"; a
-                # compiler or runtime error is a failure and propagates.
-                reason = f"{type(exc).__name__}: {exc}"
-        if self._megaround_plan is None:
-            warnings.warn(
-                "megaround requested but unavailable — falling back to "
-                f"lockstep rounds: {reason}",
-                RuntimeWarning,
-                stacklevel=3,
-            )
-            self.logger.log(f"[megaround] lockstep fallback: {reason}")
-            return None
-        self.logger.log("[megaround] fused round path enabled")
-        return self._megaround_plan
-
-    def _run_megaround_phases(
-        self, round_num: int
-    ) -> Dict[str, Optional[bool]]:
-        """Apply ONE fused-round result to game/agent/event state.
-
-        The engine already ran gather-assembly, both guided decode loops,
-        the in-jit parses, the masked exchange and the vote tally on
-        device; everything below is host bookkeeping over the single
-        packed readback — no further device syncs in this method.
-        """
-        import numpy as np
-
-        plan = self._megaround_plan
-        ids = sorted(self.agents)
-        n = len(ids)
-        if self._megaround_inbox is None:
-            self._megaround_inbox = np.full((n, n), -1, dtype=np.int32)
-            # Receiver view (row i = senders whose out-edges reach i) —
-            # the same orientation as the SPMD exchange mask.
-            self._megaround_mask = self.topology.receiver_mask()
-
-        values = np.asarray(
-            [
-                int(self.game.agents[a].current_value)
-                if self.game.agents[a].current_value is not None
-                else -1
-                for a in ids
-            ],
-            dtype=np.int32,
-        )
-        initials = np.asarray(
-            [
-                int(self.game.agents[a].initial_value)
-                if self.game.agents[a].initial_value is not None
-                else -1
-                for a in ids
-            ],
-            dtype=np.int32,
-        )
-        is_byz = np.asarray(
-            [self.game.agents[a].is_byzantine for a in ids], dtype=bool
-        )
-
-        self.logger.log("[Mega-Round - fused Decide/Exchange/Vote on device]")
-        with self.profiler.phase("megaround"):
-            result = self.engine.run_megaround(
-                plan,
-                values,
-                self._megaround_inbox,
-                round_num,
-                self._megaround_mask,
-                is_byz,
-                initials,
-                equivocators=self._equivocators_np(ids),
-            )
-
-        proposed = np.asarray(result.proposed)
-        received = np.asarray(result.received)
-        for i, aid in enumerate(ids):
-            val = int(proposed[i])
-            if self._recorder:
-                # A -1 is a non-accepting DFA walk — the fused analogue
-                # of a host-side JSON parse failure ("invalid"/abstain).
-                self._recorder.decision(
-                    round_num,
-                    aid,
-                    self.agents[aid].is_byzantine,
-                    val if val >= 0 else None,
-                    "valid" if val >= 0 else "invalid",
-                )
-            if val >= 0:
-                self.game.update_agent_proposal(aid, val)
-                self.logger.log(f"  {aid}: -> {val}")
-            else:
-                self.logger.log(f"  {aid}: ABSTAINING")
-
-        for i, aid in enumerate(ids):
-            proposals = [
-                (
-                    ids[j],
-                    int(received[i, j]),
-                    f"Proposing value: {int(received[i, j])}",
-                )
-                for j in range(n)
-                if received[i, j] >= 0
-            ]
-            agent = self.agents[aid]
-            agent.receive_proposals(proposals)
-            agent.my_value = self.game.agents[aid].proposed_value
-            if self._recorder:
-                self._recorder.deliveries(
-                    round_num, aid, [p[0] for p in proposals],
-                    values=[p[1] for p in proposals],
-                )
-            self.logger.log(
-                f"  {aid}: received {len(proposals)} proposals (fused), "
-                "updated state"
-            )
-        # Host-protocol-equivalent message accounting (one message per
-        # delivered proposer->receiver edge) rides the SPMD counter so
-        # display/save totals need no new plumbing.
-        self._spmd_message_count += int(np.asarray(result.deliveries).sum())
-
-        self._update_round_summaries(round_num)
-
-        # Next round's prompts read this round's delivered ABSOLUTE
-        # values (row 0 of the value token table renders absences).
-        self._megaround_inbox = received
-        self._megaround_rounds += 1
-        return result.vote_dict(ids)
 
     # ----------------------------------------------------------------- output
 

@@ -18,10 +18,9 @@ needs across the stack:
   agent's system/round prompts on the real-LLM path (``None`` keeps
   the reference-shaped default persona byte-identical);
 * ``equivocates`` — routes the exchange through the per-receiver
-  proposal MATRIX (``parallel/game_step.masked_exchange_matrix`` dense
-  / ``exchange_proposals`` SPMD / the fused mega-round's generalized
-  masked matmul), so one sender can deliver different values to
-  different receivers;
+  proposal MATRIX (the host protocol in ``comm/a2a_sim.py``, or
+  ``parallel/game_step.exchange_proposals`` on the SPMD path), so one
+  sender can deliver different values to different receivers;
 * ``clique`` — the byzantine set shares one seed-derived secret target
   (:func:`clique_target`), the scripted and prompt layers both
   converge on it.
@@ -50,7 +49,7 @@ def equivocation_value(base, receiver_idx, lo: int, hi: int):
     tabulates from the per-receiver ``deliveries`` events.
 
     Pure arithmetic: works elementwise on ints, numpy, and traced jax
-    arrays (used inside the fused mega-round jit program).
+    arrays.
     """
     span = hi - lo + 1
     return lo + (base - lo + receiver_idx) % span

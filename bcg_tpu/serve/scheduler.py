@@ -455,8 +455,8 @@ class SchedulerStats:
             # Host-sync view (BCG_TPU_HOSTSYNC): device->host transfers
             # this scheduler's dispatches performed, normalized per
             # dispatch and per completed request — the serve-side form
-            # of ROADMAP item 1's syncs-per-round metric.  None when
-            # the auditor is off (kv_pool idiom).
+            # of the syncs-per-round metric.  None when the auditor is
+            # off (kv_pool idiom).
             "hostsync": (
                 {
                     "syncs": self.dispatch_syncs,

@@ -158,10 +158,6 @@ _CONFIG_OVERRIDE_ENVS = (
     "BCG_TPU_CHAOS", "BCG_TPU_FAULT_RATE", "BCG_TPU_FAULT_SEED",
     "BCG_TPU_SERVE_MAX_DISPATCH_RETRIES", "BCG_TPU_SERVE_WATCHDOG_S",
     "BCG_TPU_SERVE_DEFER_WAIT_S", "BCG_TPU_SWEEP_MAX_JOB_RETRIES",
-    # The fused mega-round replaces the lockstep decide/exchange/vote
-    # host loop with one jit entry per round — a different measured
-    # execution shape, so a megaround run is never a default-config row.
-    "BCG_TPU_MEGAROUND",
     # A scenario overlay rewrites the game shape, adversary strategy,
     # topology, and channel — a registry-driven run measures a
     # different game than the default config.
@@ -246,15 +242,6 @@ def _hostsync_stats_or_none():
     from bcg_tpu.runtime import metrics as _metrics
 
     return _metrics.LAST_HOSTSYNC
-
-
-def _megaround_stats_or_none():
-    """Fused mega-round summary (fused_rounds, syncs_per_round — 1.0 by
-    construction, rounds_per_sec) when the BCG_TPU_MEGAROUND path ran
-    any fused rounds; None otherwise."""
-    from bcg_tpu.runtime import metrics as _metrics
-
-    return _metrics.LAST_MEGAROUND
 
 
 def _compile_stats_or_none():
@@ -662,10 +649,6 @@ def _run_attempt(cfg, model: str, backend: str, concurrency: int,
             # attributed transfers, syncs per phase site, syncs/round,
             # top attribution spans); None when the auditor is off.
             "hostsync": _hostsync_stats_or_none(),
-            # BCG_TPU_MEGAROUND: fused mega-round profile (fused_rounds,
-            # syncs_per_round — 1.0 by construction, rounds_per_sec);
-            # None when no round took the fused path.
-            "megaround": _megaround_stats_or_none(),
             # BCG_TPU_COMPILE_OBS: compile-cost profile (per-entry
             # compile_ms totals, first-compile vs retrace split,
             # cache-entry population, retrace causes); None when the

@@ -39,7 +39,10 @@ from typing import Dict, List, Optional
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-ARMS = ("plain", "ff", "spec", "paged", "paged_pallas", "fused", "megaround")
+ARMS = ("scan", "plain", "ff", "spec", "paged", "paged_pallas", "fused")
+# What the scan arm's entries pin: the structure a layer scan exists
+# for, not the CPU compiler's fusion choices inside it.
+SCAN_PINNED = ("whiles", "step_collectives", "step_custom_calls")
 _MODEL = "bcg-tpu/tiny-test"
 _SCHEMA = {
     "type": "object",
@@ -87,11 +90,15 @@ def run_scenario(arms=ARMS) -> Dict[str, Dict]:
     from bcg_tpu.engine.jax_engine import JaxEngine
     from bcg_tpu.obs import hlo as obs_hlo
 
+    # The scan arm records under the engine's own entry names
+    # (decode_loop, prefill_chunk), which the list-form arms use too: it
+    # runs first, alone in the recorder, and its records are merged
+    # below under names of their own.
+    scan = scan_census() if "scan" in arms else {}
     obs_hlo.enable(True)
     base = BCGConfig().engine
     for arm in arms:
-        if arm == "megaround":
-            _run_megaround_arm(base)
+        if arm == "scan":
             continue
         cfg = dataclasses.replace(
             base,
@@ -127,45 +134,52 @@ def run_scenario(arms=ARMS) -> Dict[str, Dict]:
             )
         finally:
             engine.shutdown()
-    return obs_hlo.snapshot()
+    census = obs_hlo.snapshot()
+    for entry in ("decode_loop", "prefill_chunk"):
+        if entry in scan:
+            rec = scan[entry]
+            census[f"scan_{entry}"] = (
+                rec if "error" in rec
+                else {**{m: rec[m] for m in SCAN_PINNED + ("backend",)},
+                      "pinned": list(SCAN_PINNED)}
+            )
+    return census
 
 
-def _run_megaround_arm(base) -> None:
-    """One fused consensus round (ROADMAP item 1): pins the whole-round
-    program under the ``megaround`` entry — guided decode loops for both
-    phases, the DFA decision parse, the masked-matmul exchange, and the
-    vote tally all lower into ONE jit module, so a kernel added anywhere
-    in the round shows up here.  Also records the per-phase
-    static-prefix ``prefill_suffix``-style programs the plan caches
-    (``prefill`` family — shared entry, first arm to run wins)."""
-    import dataclasses as _dc
-
-    import numpy as np
-
+def scan_census(num_layers: Optional[int] = None) -> Dict[str, Dict]:
+    """The census of one guided call in the form the benchmark's cells
+    compile: layers under ``lax.scan`` over a stacked int8 cache, the
+    prompt through the chunk program.  Entry names are the engine's own
+    (``decode_loop``, ``prefill_chunk``), so this starts from an empty
+    recorder and leaves one behind: whatever the recorder held is
+    dropped.  ``num_layers`` deepens the tiny spec: a scan's programs
+    must not grow with it."""
+    _force_cpu()
+    from bcg_tpu.config import BCGConfig
     from bcg_tpu.engine.jax_engine import JaxEngine
+    from bcg_tpu.models.configs import spec_for_model
+    from bcg_tpu.obs import hlo as obs_hlo
 
-    cfg = _dc.replace(
-        base, model_name=_MODEL, backend="jax", max_model_len=2048,
+    spec = spec_for_model(_MODEL)
+    if num_layers is not None:
+        spec = dataclasses.replace(spec, num_layers=num_layers)
+    cfg = dataclasses.replace(
+        BCGConfig().engine, model_name=_MODEL, backend="jax",
+        max_model_len=512, scan_layers=True, kv_cache_dtype="int8",
+        prefill_chunk=64, prefix_caching=False,
     )
-    engine = JaxEngine(cfg)
+    obs_hlo.reset()
+    obs_hlo.enable(True)
+    engine = JaxEngine(cfg, spec=spec)
     try:
-        n = 2
-        plan = engine.prepare_megaround(
-            n_agents=n, lo=0, hi=100, max_rounds=6,
+        engine.batch_generate_json(
+            [(sysp, user, _SCHEMA) for sysp, user in _PROMPTS],
+            temperature=0.0, max_tokens=24,
         )
-        mask = np.ones((n, n), bool)
-        np.fill_diagonal(mask, False)
-        engine.run_megaround(
-            plan,
-            np.asarray([42, 41], np.int32),
-            np.full((n, n), -1, np.int32),
-            1,
-            mask,
-            np.zeros(n, bool),
-            np.asarray([42, 41], np.int32),
-        )
+        return obs_hlo.snapshot()
     finally:
         engine.shutdown()
+        obs_hlo.reset()
 
 
 # ---------------------------------------------------------------- baseline
@@ -223,7 +237,7 @@ def check_drift(census: Dict[str, Dict], baseline: Optional[Dict]) -> List[str]:
                 + version_note
             )
             continue
-        for metric in COUNT_METRICS:
+        for metric in pinned.get("pinned", COUNT_METRICS):
             want = pinned.get("counts", {}).get(metric)
             got = recorded.get(metric)
             if want is None or got is None:
@@ -276,6 +290,9 @@ def update_baseline(census: Dict[str, Dict], path: Optional[str] = None) -> str:
                 m: recorded[m] for m in COUNT_METRICS if m in recorded
             },
         }
+        if "pinned" in recorded:
+            # Pinned by part of the counts only (the scan arm's).
+            entries[entry]["pinned"] = recorded["pinned"]
         for metric in ("flops", "bytes_accessed"):
             if metric in recorded:
                 entries[entry][metric] = recorded[metric]

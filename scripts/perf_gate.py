@@ -55,7 +55,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # kill absolute-gauge leftovers (headroom, heartbeats, stragglers)
 # that earlier scenarios legitimately leave behind.
 SCENARIOS = ("serve", "engine", "paged", "sampler", "int4", "consensus",
-             "fleet", "hostsync", "megaround", "compile", "sweep", "chaos",
+             "fleet", "hostsync", "compile", "sweep", "chaos",
              "scenarios", "hlo", "alerts")
 REGRESSIONS = ("none", "spec-off", "fail-rows", "events-off",
                "straggler-off", "hostsync-off", "compile-off",
@@ -763,22 +763,15 @@ def run_fleet_scenario(inject: str = "none") -> Dict[str, float]:
 
 
 def run_hostsync_scenario(inject: str = "none") -> Dict[str, float]:
-    """Runtime host-sync auditor (bcg_tpu/obs/hostsync.py) gates — the
-    drift baseline for ROADMAP item 1's on-device mega-round (host-syncs
-    per round -> ~1), pinned the way the while-body kernel census pinned
-    PRs 8/10's fusion claims:
+    """Runtime host-sync auditor (bcg_tpu/obs/hostsync.py) gates,
+    pinned the way the while-body kernel census pinned PRs 8/10's
+    fusion claims:
 
     * ``syncs_per_round`` — mean of the ``game.host_syncs`` per-round
-      histogram over one hermetic FakeEngine consensus game run on the
-      PRODUCTION round path: the fused mega-round (BCG_TPU_MEGAROUND),
-      whose mirror notes exactly ONE ``round_readback`` per round —
-      the fusion target reached, pinned at 1.0.
-    * ``syncs_per_round_lockstep`` — the same game on the lockstep
-      path: 2 batched engine calls per round (decide + vote) x 3
-      mirrored decode-path syncs = 6.0.  Still pinned: every fallback
-      configuration in the mega-round matrix (free-text, sequential,
-      lossy channels, BPE tokenizers) runs THIS structure, so its
-      drift is as load-bearing as the fused number.
+      histogram over one hermetic FakeEngine consensus game: 2 batched
+      engine calls per round (decide + vote) x 3 mirrored decode-path
+      syncs = 6.0, the number the benchmark's ledger reports under the
+      same name.
     * ``syncs_per_decision`` — observed transfers per agent decision on
       the tiny REAL engine's guided-JSON benchmark (one batched call,
       3 decisions): the decode path's actual materialization count
@@ -806,7 +799,6 @@ def run_hostsync_scenario(inject: str = "none") -> Dict[str, float]:
     # Save/restore the RAW values (None vs "") — registry accessors
     # cannot round-trip "was unset".
     prior = os.environ.get("BCG_TPU_HOSTSYNC")  # lint: ignore[BCG-ENV-RAW]
-    prior_mega = os.environ.get("BCG_TPU_MEGAROUND")  # lint: ignore[BCG-ENV-RAW]
     if inject == "hostsync-off":
         os.environ.pop("BCG_TPU_HOSTSYNC", None)
     else:
@@ -816,9 +808,7 @@ def run_hostsync_scenario(inject: str = "none") -> Dict[str, float]:
     attr_before = obs_counters.value("engine.hostsync.attributed")
     try:
         # Arm 1: hermetic FakeEngine game (same geometry as the
-        # consensus scenario's converging seed), once per round path —
-        # fused mega-round (the production profile, 1 readback/round)
-        # and lockstep (the fallback-matrix profile, 2 calls x 3 syncs).
+        # consensus scenario's converging seed).
         cfg = dataclasses.replace(
             BCGConfig(),
             game=GameConfig(num_honest=4, num_byzantine=1,
@@ -827,27 +817,17 @@ def run_hostsync_scenario(inject: str = "none") -> Dict[str, float]:
             metrics=MetricsConfig(save_results=False),
             verbose=False,
         )
-        per_round = {}
-        for path_name, mega in (("fused", "1"), ("lockstep", None)):
-            if mega is None:
-                os.environ.pop("BCG_TPU_MEGAROUND", None)
-            else:
-                os.environ["BCG_TPU_MEGAROUND"] = mega
-            rounds_before = obs_counters.value("game.host_syncs.count")
-            round_syncs_before = obs_counters.value("game.host_syncs.sum")
-            sim = BCGSimulation(config=cfg)
-            try:
-                sim.run()
-            finally:
-                sim.close()
-            rounds = (
-                obs_counters.value("game.host_syncs.count") - rounds_before
-            )
-            round_syncs = (
-                obs_counters.value("game.host_syncs.sum")
-                - round_syncs_before
-            )
-            per_round[path_name] = round_syncs / rounds if rounds else 0.0
+        rounds_before = obs_counters.value("game.host_syncs.count")
+        round_syncs_before = obs_counters.value("game.host_syncs.sum")
+        sim = BCGSimulation(config=cfg)
+        try:
+            sim.run()
+        finally:
+            sim.close()
+        rounds = obs_counters.value("game.host_syncs.count") - rounds_before
+        round_syncs = (
+            obs_counters.value("game.host_syncs.sum") - round_syncs_before
+        )
 
         # Arm 2: tiny real engine, guided-JSON decision benchmark
         # (deterministic at temperature 0 — the engine scenario's
@@ -888,14 +868,9 @@ def run_hostsync_scenario(inject: str = "none") -> Dict[str, float]:
             os.environ.pop("BCG_TPU_HOSTSYNC", None)
         else:
             os.environ["BCG_TPU_HOSTSYNC"] = prior
-        if prior_mega is None:
-            os.environ.pop("BCG_TPU_MEGAROUND", None)
-        else:
-            os.environ["BCG_TPU_MEGAROUND"] = prior_mega
         obs_hostsync.reset()
     return {
-        "hostsync.syncs_per_round": per_round.get("fused", 0.0),
-        "hostsync.syncs_per_round_lockstep": per_round.get("lockstep", 0.0),
+        "hostsync.syncs_per_round": round_syncs / rounds if rounds else 0.0,
         "hostsync.syncs_per_decision": decision_syncs / len(prompts),
         "hostsync.attribution_coverage": (
             attributed / total if total else 0.0
@@ -904,131 +879,10 @@ def run_hostsync_scenario(inject: str = "none") -> Dict[str, float]:
     }
 
 
-def run_megaround_scenario(inject: str = "none") -> Dict[str, float]:
-    """Fused mega-round gates (bcg_tpu/engine/megaround.py) — ROADMAP
-    item 1's decision-identity + retrace-pinning + throughput claims,
-    on the tiny real engine:
-
-    * ``decision_mismatches`` / ``vote_mismatches`` — greedy decision
-      identity vs the lockstep oracle (max 0 EXACT): each fused round's
-      proposals and votes must equal what ``batch_generate_json`` at
-      temperature 0 produces over the SAME rendered template prompts
-      with the SAME token budget.  The fused path shares the decode-loop
-      body (``_decode_loop_fn``) with the lockstep jit, so any
-      divergence is an assembly/parse bug, not sampler drift.
-    * ``steady_retraces`` — compile + retrace counter movement on the
-      ``megaround`` entry across rounds 2..R (must be 0 EXACT): values,
-      inbox, round number, and convergence state are traced arguments,
-      so steady-state rounds reuse ONE compiled program.
-    * ``round_speedup`` — warm fused-round wall-clock vs the warm
-      lockstep pair (decide + vote ``batch_generate_json`` over the
-      same prompts, measured in THIS process on the same warm engine).
-      Banded min > 1: the fusion must beat the path it replaces or the
-      claim is noise.
-    """
-    import time
-
-    import numpy as np
-
-    _force_cpu()
-    from bcg_tpu.config import EngineConfig
-    from bcg_tpu.engine.jax_engine import JaxEngine
-    from bcg_tpu.obs import counters as obs_counters
-
-    n, lo, hi, max_rounds = 4, 0, 50, 6
-    eng = JaxEngine(EngineConfig(
-        backend="jax", model_name="bcg-tpu/tiny-test", max_model_len=2048,
-    ))
-    try:
-        plan = eng.prepare_megaround(
-            n_agents=n, lo=lo, hi=hi, max_rounds=max_rounds
-        )
-        template = plan.template
-        mask = np.ones((n, n), dtype=bool)
-        np.fill_diagonal(mask, False)
-        is_byz = np.zeros(n, dtype=bool)
-        is_byz[-1] = True
-        values = np.array([3, 17, 3, 42], dtype=np.int32)
-        initials = values.copy()
-        inbox = np.full((n, n), -1, dtype=np.int32)
-
-        def parse(row, lo_, hi_):
-            if not isinstance(row, dict) or "error" in row:
-                return -1
-            v = row.get("value")
-            if isinstance(v, bool) or not isinstance(v, int):
-                return -1
-            return v if lo_ <= v <= hi_ else -1
-
-        decision_mismatches = vote_mismatches = 0
-        fused_warm = oracle_warm = 0.0
-        compile_after_first = retrace_after_first = 0.0
-        for r in range(1, 4):
-            t0 = time.perf_counter()
-            res = eng.run_megaround(
-                plan, values, inbox, r, mask, is_byz, initials
-            )
-            t_fused = time.perf_counter() - t0
-            # Lockstep oracle: the SAME rendered prompts through the
-            # ordinary batched guided path at temperature 0, with each
-            # phase's exact fused token budget (so guaranteed-parse
-            # masking binds identically).
-            t0 = time.perf_counter()
-            oracle_dec = eng.batch_generate_json(
-                template.decision_prompts(values, inbox, r),
-                temperature=0.0, max_tokens=plan.decide.max_new,
-            )
-            oracle_vote = eng.batch_generate_json(
-                template.vote_prompts(res.values, res.received, r),
-                temperature=0.0, max_tokens=plan.vote.max_new,
-            )
-            t_oracle = time.perf_counter() - t0
-            want_dec = [parse(row, lo, hi) for row in oracle_dec]
-            want_vote = [
-                1 if parse(row, 0, 1) == 1 else 0 for row in oracle_vote
-            ]
-            decision_mismatches += int(
-                (np.asarray(want_dec, dtype=np.int32) != res.proposed).sum()
-            )
-            vote_mismatches += int(
-                (np.asarray(want_vote, dtype=np.int32) != res.votes).sum()
-            )
-            if r == 1:
-                compile_after_first = obs_counters.value(
-                    "engine.compile.megaround"
-                )
-                retrace_after_first = obs_counters.value(
-                    "engine.retrace.megaround"
-                )
-            else:
-                # Rounds 2+ are warm on both paths (round 1 paid every
-                # compile): the throughput comparison.
-                fused_warm += t_fused
-                oracle_warm += t_oracle
-            values, inbox = res.values, res.received
-        steady = (
-            obs_counters.value("engine.compile.megaround")
-            - compile_after_first
-        ) + (
-            obs_counters.value("engine.retrace.megaround")
-            - retrace_after_first
-        )
-    finally:
-        eng.shutdown()
-    return {
-        "megaround.decision_mismatches": float(decision_mismatches),
-        "megaround.vote_mismatches": float(vote_mismatches),
-        "megaround.steady_retraces": float(steady),
-        "megaround.round_speedup": (
-            oracle_warm / fused_warm if fused_warm > 0 else 0.0
-        ),
-    }
-
-
 def run_compile_scenario(inject: str = "none") -> Dict[str, float]:
     """Compile-cost observability (bcg_tpu/obs/compile.py) gates — the
-    drift baseline for ROADMAP item 1's mega-round and the sweep tier's
-    per-tenant signature multiplication, pinned the way hostsync pinned
+    drift baseline for the sweep tier's per-tenant signature
+    multiplication, pinned the way hostsync pinned
     the transfer structure:
 
     * ``steady_state_retraces`` — compile + retrace counter movement
@@ -1844,7 +1698,6 @@ _RUNNERS = {
     "consensus": run_consensus_scenario,
     "fleet": run_fleet_scenario,
     "hostsync": run_hostsync_scenario,
-    "megaround": run_megaround_scenario,
     "compile": run_compile_scenario,
     "sweep": run_sweep_scenario,
     "chaos": run_chaos_scenario,

@@ -159,10 +159,6 @@ def render_report(trace: dict, top: int = 20) -> str:
     if hostsync:
         lines.append("")
         lines.append(hostsync)
-    fusion_line = round_fusion_line(counters)
-    if fusion_line:
-        lines.append("")
-        lines.append(fusion_line)
     compile_time = compile_time_section(counters)
     if compile_time:
         lines.append("")
@@ -438,24 +434,6 @@ def retrace_cause_section(counters: Dict[str, float]) -> str:
     for name, value in rows:
         lines.append(f"{name:<{name_w}}  {value:>8.0f}")
     return "\n".join(lines)
-
-
-def round_fusion_line(counters: Dict[str, float]) -> str:
-    """One-line fused mega-round summary when the export carries fused
-    rounds (engine.megaround.rounds); '' otherwise.  Syncs/round comes
-    from the game.host_syncs per-round histogram flats — 1.0 on the
-    fused path (one packed readback per round) vs 6.0 lockstep, the
-    ROADMAP item 1 headline."""
-    fused = counters.get("engine.megaround.rounds")
-    if not fused:
-        return ""
-    rounds = counters.get("game.host_syncs.count", 0)
-    syncs = counters.get("game.host_syncs.sum", 0)
-    per_round = f", {syncs / rounds:.1f} sync(s)/round" if rounds else ""
-    return (
-        f"== round fusion: {fused:.0f} fused round(s) — one jit entry "
-        f"per consensus round{per_round} =="
-    )
 
 
 def spec_acceptance(counters: Dict[str, float]) -> str:

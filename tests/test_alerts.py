@@ -676,12 +676,14 @@ class TestZeroSurface:
             return proc.stdout
 
         def mask_wall_clock(expo: str) -> str:
-            # The serve run's *_ms histogram SUMS are wall-clock and
-            # differ between any two runs; every other line (names,
-            # bucket counts, event counters) must stay byte-exact.
+            # The serve run's *_ms histograms are wall-clock: their sums
+            # differ between any two runs, and under load so does the
+            # bucket a duration falls in.  Every other line (names,
+            # labels, counts, event counters) must stay byte-exact.
             return "\n".join(
                 line.split(" ")[0] + " <wall>"
-                if "_ms_sum" in line.split(" ")[0] else line
+                if ("_ms_sum" in line.split(" ")[0]
+                    or "_ms_bucket" in line.split(" ")[0]) else line
                 for line in expo.splitlines()
             ) + "\n"
 

@@ -195,8 +195,14 @@ class TestEngineIntegration:
         pytest.param({}, id="plain"),
         pytest.param({"decode_fast_forward": True}, id="ff"),
         pytest.param({"spec_decode": True}, id="spec"),
+        # The benchmark cell's options: on the chip they run the kernel.
+        pytest.param("qwen3-8b-int8", id="cell_options"),
     ])
-    def test_greedy_parity_across_loop_families(self, family_kw):
+    def test_greedy_parity_across_loop_families(self, family_kw,
+                                                cell_engine_options):
+        if isinstance(family_kw, str):
+            family_kw = cell_engine_options(family_kw)
+            del family_kw["model_name"]     # _cfg's own: the same preset
         ref = JaxEngine(_cfg(**family_kw))
         fused = JaxEngine(_cfg(fused_sampler="pallas", **family_kw))
         try:

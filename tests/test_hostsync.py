@@ -268,8 +268,7 @@ class TestRoundHistogram:
     def test_game_observes_syncs_per_round(self, audited, untraced):
         """The orchestrator observes each round's sync delta into
         game.host_syncs: a lockstep FakeEngine round is 2 batched
-        engine calls (decide + vote) x 3 mirrored decode-path syncs —
-        ROADMAP item 1's baseline structure."""
+        engine calls (decide + vote) x 3 mirrored decode-path syncs."""
         rounds_before = obs_counters.value("game.host_syncs.count")
         syncs_before = obs_counters.value("game.host_syncs.sum")
         out = _run_game()
@@ -277,6 +276,35 @@ class TestRoundHistogram:
         syncs = obs_counters.value("game.host_syncs.sum") - syncs_before
         assert rounds == out["metrics"]["total_rounds"]
         assert syncs / rounds == 6.0
+
+    def test_real_engine_round_costs_six_syncs(self, audited, untraced,
+                                               cell_engine_options):
+        """The ledger's ``syncs_per_round`` on the REAL engine: a
+        lockstep game of up to three rounds on the tiny model under the
+        options the benchmark cell's file names is six syncs a round
+        (prefill barrier, decode readback, steps readback; decide and
+        vote), however many rounds the random-weight votes play."""
+        from bcg_tpu.config import (
+            BCGConfig, EngineConfig, GameConfig, MetricsConfig,
+        )
+        from bcg_tpu.runtime.orchestrator import BCGSimulation
+
+        sim = BCGSimulation(config=BCGConfig(
+            game=GameConfig(num_honest=2, num_byzantine=1, max_rounds=3, seed=0),
+            engine=EngineConfig(backend="jax", max_model_len=2048,
+                                **cell_engine_options("qwen3-8b-int8")),
+            metrics=MetricsConfig(save_results=False),
+        ))
+        rounds_before = obs_counters.value("game.host_syncs.count")
+        syncs_before = obs_counters.value("game.host_syncs.sum")
+        try:
+            stats = sim.run()
+        finally:
+            sim.engine.shutdown()
+        rounds = obs_counters.value("game.host_syncs.count") - rounds_before
+        syncs = obs_counters.value("game.host_syncs.sum") - syncs_before
+        assert rounds == stats["total_rounds"] >= 1
+        assert syncs == 6 * rounds
 
     def test_game_syncs_attribute_fully(self, audited, untraced):
         before_total = obs_counters.value("engine.hostsync.total")
@@ -466,10 +494,9 @@ class TestPerfGateHostsync:
 
     def test_acceptance_values(self, hostsync_gate):
         _, measured = hostsync_gate
-        # ONE packed readback per fused mega-round (ROADMAP item 1);
-        # the 2-calls x 3-syncs lockstep profile is pinned separately.
-        assert measured["hostsync.syncs_per_round"] == 1.0
-        assert measured["hostsync.syncs_per_round_lockstep"] == 6.0
+        # 2 batched calls x 3 syncs a lockstep round: the ledger's
+        # syncs_per_round.
+        assert measured["hostsync.syncs_per_round"] == 6.0
         # 3 real-engine materializations / 3 decisions in one call.
         assert measured["hostsync.syncs_per_decision"] == 1.0
         # Acceptance criterion: >= 95% attributed (tracing off here, so
@@ -498,7 +525,6 @@ class TestPerfGateHostsync:
         assert sorted(hostsync_entries) == [
             "hostsync.attribution_coverage", "hostsync.error_rows",
             "hostsync.syncs_per_decision", "hostsync.syncs_per_round",
-            "hostsync.syncs_per_round_lockstep",
         ]
         for removed in hostsync_entries:
             pruned = json.loads(json.dumps(baseline))

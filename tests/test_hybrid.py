@@ -294,7 +294,7 @@ class TestAgainstReference:
             logits, cache = T.decode_step(
                 params, SPEC, jnp.asarray([t[len(t) - steps + j] for t in toks]),
                 jnp.int32(L + j), jnp.asarray([len(h) + j for h in heads], jnp.int32),
-                cache, jnp.asarray(mask))
+                cache, jnp.array(mask))   # a copy: the next step writes to `mask`
 
     def test_chunked_left_padded_prefill(self, want):
         """Four 64-wide chunk programs over a left-padded batch of
@@ -375,7 +375,10 @@ class TestAgainstReference:
                 logits, cache = T.decode_step(
                     params, SPEC, jnp.asarray([t[len(t) - steps + j] for t in toks]),
                     jnp.int32(L + j), jnp.asarray([len(h) + j for h in heads], jnp.int32),
-                    cache, jnp.asarray(mask))
+                    # A copy: on the CPU ``jnp.asarray`` aliases a NumPy
+                    # array that lies on a 64-byte boundary, and the next
+                    # step writes to ``mask`` while this one is in flight.
+                    cache, jnp.array(mask))
                 seen.append(logits)
             got[stacked] = (seen, cache)
         tol = 2e-2 if kv else F32_TOL   # logits of order 3; a flipped int8 step moves one 6e-3

@@ -4,6 +4,9 @@ option that is not raises at boot by name (``tests/test_hybrid.py``
 holds the model against its reference)."""
 
 import dataclasses
+import glob
+import json
+import os
 
 import jax
 import numpy as np
@@ -22,6 +25,40 @@ VOTE = {
 }
 LONG_ROW = ("sys " * 40, "user prompt " * 20, VOTE)
 SHORT_ROW = ("sys", "short", VOTE)
+
+
+CELL_FILES = sorted(glob.glob(os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmark", "configs", "*.json",
+)))
+
+
+@pytest.mark.parametrize(
+    "path", CELL_FILES,
+    ids=[os.path.splitext(os.path.basename(p))[0] for p in CELL_FILES],
+)
+def test_cell_file_names_engine_fields_and_boots_at_tiny_size(
+        path, cell_engine_options):
+    """Every key of a benchmark configuration's ``program.engine`` is an
+    ``EngineConfig`` field, and the tiny preset of its family boots and
+    serves a guided call under them: a configuration added as files
+    alone brings its own case."""
+    from bcg_tpu.engine.jax_engine import JaxEngine
+
+    with open(path) as f:
+        named = json.load(f)["program"]["engine"]
+    assert set(named) <= {f.name for f in dataclasses.fields(EngineConfig)}
+    name = os.path.splitext(os.path.basename(path))[0]
+    engine = JaxEngine(EngineConfig(
+        backend="jax", max_model_len=1024, **cell_engine_options(name)))
+    try:
+        out = engine.batch_generate_json(
+            [LONG_ROW, SHORT_ROW], temperature=0.0, max_tokens=24)
+    finally:
+        engine.shutdown()
+    assert all(o.get("decision") in ("stop", "continue") for o in out)
+    for key in set(named) - {"prefill_chunk"}:
+        assert getattr(engine.config, key) == named[key], key
 
 
 def engine_config(**kw):
@@ -66,14 +103,14 @@ class TestEngine:
             JaxEngine(engine_config())
 
     @pytest.fixture(scope="class")
-    def served(self):
-        """The deployment's options at tiny size: W8A8, int8 KV, layer
-        scan, chunked prefill; one engine for the class."""
+    def served(self, cell_engine_options):
+        """The deployment's options at tiny size, from the cell's own
+        file: W8A8, int8 KV, layer scan, chunked prefill, compact JSON;
+        one engine for the class."""
         from bcg_tpu.engine.jax_engine import JaxEngine
 
         engine = JaxEngine(engine_config(
-            quantization="int8", kv_cache_dtype="int8", scan_layers=True,
-            prefill_chunk=64))
+            **cell_engine_options("olmo-hybrid-7b-int8")))
         yield engine
         engine.shutdown()
 
@@ -96,8 +133,7 @@ class TestEngine:
     def test_chunked_greedy_output_matches_single_pass(self, served):
         from bcg_tpu.engine.jax_engine import JaxEngine
 
-        one = JaxEngine(engine_config(
-            quantization="int8", kv_cache_dtype="int8", scan_layers=True))
+        one = JaxEngine(dataclasses.replace(served.config, prefill_chunk=0))
         try:
             rows = [LONG_ROW, SHORT_ROW]
             assert served.batch_generate_json(rows, temperature=0.0, max_tokens=24) == \
@@ -115,12 +151,6 @@ class TestEngine:
             assert 1 <= served.cap_for(1024) < 10
         finally:
             served._mem_limit = None
-
-    def test_fused_round_is_refused_by_name(self, served):
-        from bcg_tpu.engine.megaround import MegaroundUnsupported
-
-        with pytest.raises(MegaroundUnsupported, match="hybrid"):
-            served.prepare_megaround(n_agents=3, lo=0, hi=50, max_rounds=4)
 
     def test_a_game_round_runs_on_the_normal_path(self, served):
         from bcg_tpu.config import BCGConfig

@@ -17,10 +17,20 @@ from bcg_tpu.engine.jax_engine import JaxEngine
 from bcg_tpu.engine.tokenizer import ByteTokenizer
 
 
-@pytest.fixture(scope="module")
-def engine():
-    return JaxEngine(EngineConfig(backend="jax", model_name="bcg-tpu/tiny-test",
-                                  max_model_len=2048))
+@pytest.fixture(scope="module", params=["defaults", "qwen3-8b-int8"])
+def engine(request, cell_engine_options):
+    """The tiny model under ``EngineConfig``'s defaults, and under the
+    options the benchmark cell's file names (what the chip runs)."""
+    options = (
+        {} if request.param == "defaults"
+        else cell_engine_options(request.param)
+    )
+    engine = JaxEngine(EngineConfig(**{
+        "backend": "jax", "model_name": "bcg-tpu/tiny-test",
+        "max_model_len": 2048, **options,
+    }))
+    yield engine
+    engine.shutdown()
 
 
 VOTE_SCHEMA = {
@@ -44,12 +54,18 @@ DECISION_SCHEMA = {
 
 
 class TestMaxNumSeqs:
-    @pytest.mark.slow
-    def test_oversized_batch_chunks(self, monkeypatch):
-        engine = JaxEngine(EngineConfig(
-            backend="jax", model_name="bcg-tpu/tiny-test", max_model_len=1024,
-            max_num_seqs=2,
-        ))
+    @pytest.mark.parametrize("cell", [
+        pytest.param(None, marks=pytest.mark.slow, id="defaults"),
+        # 7 s on the CPU: the cell's options run in tier-1.
+        pytest.param("qwen3-8b-int8", id="qwen3-8b-int8"),
+    ])
+    def test_oversized_batch_chunks(self, monkeypatch, cell,
+                                    cell_engine_options):
+        engine = JaxEngine(EngineConfig(**{
+            "backend": "jax", "model_name": "bcg-tpu/tiny-test",
+            "max_model_len": 1024, "max_num_seqs": 2,
+            **(cell_engine_options(cell) if cell else {}),
+        }))
         calls = []
         orig = engine._decode_batch
 
@@ -247,6 +263,67 @@ class TestSimulationOnJaxEngine:
         sim.engine.shutdown()
 
 
+class TestLockstepGameOnCellOptions:
+    """One lockstep game of up to three rounds on the tiny real engine
+    under the options the benchmark cell's file names (W8A8, stacked
+    int8 cache, layer scan, chunked prefill, compact JSON, no prefix
+    cache)."""
+
+    @pytest.fixture(scope="class")
+    def game(self, cell_engine_options):
+        """(final statistics, compile/retrace counter movement a round)."""
+        from bcg_tpu.obs import counters as obs_counters
+        from bcg_tpu.runtime.orchestrator import BCGSimulation
+
+        sim = BCGSimulation(config=BCGConfig(
+            # The votes of a random-weight model decide when a game
+            # stops: seed 0 plays all three rounds today, and the tests
+            # below hold for any count above one.
+            game=GameConfig(num_honest=2, num_byzantine=1, max_rounds=3, seed=0),
+            engine=EngineConfig(backend="jax", max_model_len=2048,
+                                **cell_engine_options("qwen3-8b-int8")),
+            metrics=MetricsConfig(save_results=False),
+        ))
+        compiled = []
+        play = sim.run_round
+
+        def counted_round():
+            before = obs_counters.snapshot()
+            play()
+            compiled.append({
+                k: v for k, v in obs_counters.delta(before).items()
+                if v and k.startswith(("engine.compile.", "engine.retrace."))
+            })
+
+        sim.run_round = counted_round
+        try:
+            yield sim.run(), compiled
+        finally:
+            sim.engine.shutdown()
+
+    def test_full_game_on_tiny_model(self, game):
+        """``TestSimulationOnJaxEngine``'s game under the cell's options
+        at tp=1: every response schema-valid, a clean termination."""
+        stats, _ = game
+        assert 1 <= stats["total_rounds"] <= 3
+        assert stats["termination_reason"] in (
+            "vote_with_consensus", "vote_without_consensus", "max_rounds",
+        )
+        for r in stats["rounds_data"]:
+            for v in r["honest_values"] + r["byzantine_values"]:
+                assert 0 <= v <= 50
+
+    def test_rounds_after_the_first_compile_nothing(self, game):
+        """What the benchmark's window raises on, seen in tier-1 first:
+        round 1 loads every program, the rounds after it move no
+        ``engine.compile.*`` or ``engine.retrace.*`` counter."""
+        stats, compiled = game
+        assert len(compiled) == stats["total_rounds"] >= 2, \
+            "the game stopped after round 1: pick a seed that plays on"
+        assert compiled[0].get("engine.compile.decode_loop", 0) >= 1
+        assert all(c == {} for c in compiled[1:]), compiled[1:]
+
+
 class TestGuaranteedParse:
     """Force-completion: guided output parses even when the budget is far
     too small for the model's rambling (random weights never emit EOS)."""
@@ -409,6 +486,7 @@ class TestChunkedPrefillSkipsDeadChunks:
     # Byte tokens: the chat template adds some 80 to a prompt's own.
     LONG_ROW = ("sys " * 40, "user prompt " * 12, VOTE_SCHEMA)
     SHORT_ROW = ("other sys " * 5, "short", VOTE_SCHEMA)
+    STACKED_INT8 = {"scan_layers": True, "kv_cache_dtype": "int8"}
 
     @pytest.fixture
     def traced(self, monkeypatch):
@@ -501,7 +579,19 @@ class TestChunkedPrefillSkipsDeadChunks:
         # The short row's pad covers chunks the long row's tokens reach:
         # only chunks dead in EVERY row go.
         ([LONG_ROW, SHORT_ROW], {}),
-    ], ids=["full_prompt", "prefix_cached", "non_divisor_chunk", "mixed_rows"])
+        # The cells' cache: skipped slots keep a STACKED int8 cache's
+        # init zeros and unit scales.  The last two are the prompts of
+        # TestChunkedPrefill's single-pass and non-divisor cases.
+        ([LONG_ROW], STACKED_INT8),
+        ([LONG_ROW, SHORT_ROW], STACKED_INT8),
+        ([("sys " * 40, "user prompt " * 30, VOTE_SCHEMA),
+          ("other sys " * 25, "short", VOTE_SCHEMA)], STACKED_INT8),
+        ([("sys " * 50, "user words " * 25, VOTE_SCHEMA)],
+         {**STACKED_INT8, "prefill_chunk": 100}),
+    ], ids=["full_prompt", "prefix_cached", "non_divisor_chunk", "mixed_rows",
+            "full_prompt-stacked_int8", "mixed_rows-stacked_int8",
+            "ragged_multi_chunk-stacked_int8",
+            "non_divisor_chunk-stacked_int8"])
     def test_greedy_output_matches_single_pass(self, rows, overrides):
         from bcg_tpu.obs import counters as obs_counters
 

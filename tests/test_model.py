@@ -56,7 +56,7 @@ def test_decode_step_matches_prefill(params):
         logits, cache = decode_step(
             params, SPEC,
             tokens[:, t], jnp.int32(t), jnp.asarray([t]),
-            cache, jnp.asarray(valid_mask),
+            cache, jnp.array(valid_mask),   # a copy: the loop writes to it
         )
     np.testing.assert_allclose(
         np.asarray(ref_logits), np.asarray(logits), rtol=2e-2, atol=2e-2

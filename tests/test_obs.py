@@ -525,13 +525,21 @@ class TestRetraceCounters:
         "additionalProperties": False,
     }
 
-    def test_steady_state_zero_then_new_shape_exactly_one(self):
+    @pytest.mark.parametrize("cell", [None, "qwen3-8b-int8"],
+                             ids=["defaults", "qwen3-8b-int8"])
+    def test_steady_state_zero_then_new_shape_exactly_one(
+            self, cell, cell_engine_options):
         from bcg_tpu.config import EngineConfig
         from bcg_tpu.engine.jax_engine import JaxEngine
 
-        engine = JaxEngine(EngineConfig(
-            backend="jax", model_name="bcg-tpu/tiny-test", max_model_len=512,
-        ))
+        engine = JaxEngine(EngineConfig(**{
+            "backend": "jax", "model_name": "bcg-tpu/tiny-test",
+            "max_model_len": 512,
+            **(cell_engine_options(cell) if cell else {}),
+        }))
+        # Without a prefix cache (the cell's option) every call
+        # allocates its cache anew: engine.cache.* counts those bytes.
+        progress = _PROGRESS + (("engine.cache.",) if cell else ())
         prompts = [("sys", "vote please", self.VOTE)]
         engine.batch_generate_json(prompts, temperature=0.0, max_tokens=16)
         after_first = obs_counters.snapshot()
@@ -545,7 +553,7 @@ class TestRetraceCounters:
             # call; this test pins the compile/retrace/spec families,
             # where any steady-state movement is a regression.  So are
             # engine.decode.tokens / .row_steps (the loop's yield).
-            and not k.startswith(_PROGRESS)
+            and not k.startswith(progress)
         }
         assert steady == {}, f"steady-state decode retraced: {steady}"
         # A new token budget is a new decode-loop signature: exactly +1
@@ -561,7 +569,7 @@ class TestRetraceCounters:
         repeat = {
             k: v for k, v in obs_counters.delta(before_repeat).items()
             if k.startswith("engine.")
-            and not k.startswith(_PROGRESS)  # per-call progress
+            and not k.startswith(progress)  # per-call progress
         }
         assert repeat == {}, repeat
         engine.shutdown()
@@ -705,6 +713,13 @@ def _tiny_engine(**overrides):
     ))
 
 
+def _case_options(options, cell_engine_options):
+    """A case's engine options: its own dict, or a benchmark cell's by
+    the cell's name."""
+    return (cell_engine_options(options) if isinstance(options, str)
+            else options)
+
+
 class TestEngineSpans:
     """The engine call's span table (DESIGN.md "Observability") and the
     two always-on counters of the decode loop's yield."""
@@ -713,9 +728,12 @@ class TestEngineSpans:
         {"prefix_caching": False},
         {"prefix_caching": True},
         {"paged_kv": True},
-    ], ids=["full_prompt", "prefixed", "paged"])
-    def test_engine_call_span_table(self, traced, overrides):
-        engine = _tiny_engine(**overrides)
+        "qwen3-8b-int8",
+    ], ids=["full_prompt", "prefixed", "paged", "cell_options"])
+    def test_engine_call_span_table(self, traced, overrides,
+                                    cell_engine_options):
+        cell = isinstance(overrides, str)
+        engine = _tiny_engine(**_case_options(overrides, cell_engine_options))
         try:
             # First call: token DFAs, compiles, prefix entries.
             engine.batch_generate_json(_ROWS, temperature=0.0, max_tokens=48)
@@ -742,13 +760,23 @@ class TestEngineSpans:
         # engine.decode's exit, the shapes engine.prefill's.
         assert ends["engine.guides"] == {"schemas": 1, "built": 0}
         assert steps > 0 and ends["engine.decode"] == {"steps": steps}
-        assert ends["engine.prefill"]["chunks"] == 1
+        if cell:
+            # The cell's chunk program, 64 wide here, over the window.
+            assert (ends["engine.prefill"]["chunks"]
+                    + ends["engine.prefill"]["chunks_skipped"]
+                    == -(-ends["engine.prefill"]["prompt_window"] // 64) > 1)
+        else:
+            assert ends["engine.prefill"]["chunks"] == 1
         assert ends["engine.prefill"]["prompt_window"] > 0
         assert ends["engine.prefill"]["cache_len"] > 0
 
-    @pytest.mark.parametrize("fast_forward", [False, True],
-                             ids=["plain_loop", "fast_forward"])
-    def test_decode_yield_counters(self, untraced, monkeypatch, fast_forward):
+    @pytest.mark.parametrize("options", [
+        {"decode_fast_forward": False}, {"decode_fast_forward": True},
+        # The benchmark's decode_tokens_per_row_step, on its options.
+        "qwen3-8b-int8",
+    ], ids=["plain_loop", "fast_forward", "cell_options"])
+    def test_decode_yield_counters(self, untraced, monkeypatch, options,
+                                   cell_engine_options):
         """``engine.decode.tokens`` over ``engine.decode.row_steps``: at
         most 1 on the plain loop (rows sit finished while the longest
         decodes), above 1 under fast-forward on a schema with a forced
@@ -758,8 +786,10 @@ class TestEngineSpans:
 
         monkeypatch.setenv("BCG_TPU_HOSTSYNC", "1")
         obs_hostsync.reset()
-        engine = _tiny_engine(prefix_caching=False, guided_compact_json=True,
-                              decode_fast_forward=fast_forward)
+        options = _case_options(options, cell_engine_options)
+        fast_forward = options["decode_fast_forward"]
+        engine = _tiny_engine(**{
+            "prefix_caching": False, "guided_compact_json": True, **options})
         try:
             before = obs_counters.snapshot()
             out = engine.batch_generate_json(

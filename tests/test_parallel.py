@@ -482,93 +482,92 @@ class TestSPMDGameStep:
         assert received[4, 5] == -1  # agent 5 abstained
         assert received[3, 3] == -1  # no self-delivery
 
+    @staticmethod
+    def _topology_n64(topo_name):
+        return {
+            "ring": lambda: NetworkTopology.ring(64),
+            "grid": lambda: NetworkTopology.grid(8, 8),
+            "full": lambda: NetworkTopology.fully_connected(64),
+        }[topo_name]()
+
     @pytest.mark.parametrize("topo_name", ["ring", "grid", "full"])
     def test_masked_exchange_matches_spmd_body_n64(self, topo_name):
-        """ISSUE-16 satellite: the mega-round's dense masked-matmul
-        exchange (masked_exchange) must be value-identical to the
-        shard_map collective form (exchange_values) at the 64-agent
-        one-agent-per-chip scale, for every stock topology — same mask
-        matrix into both, per-cell received values AND the per-receiver
-        ``deliveries`` counts the orchestrator's delivery events read."""
-        from bcg_tpu.parallel.game_step import masked_exchange
-
+        """The shard_map exchange (exchange_values) at the 64-agent
+        one-agent-per-chip scale, for every stock topology, against the
+        host expression of the same mask in NumPy: a received cell is
+        the sender's value where ``mask[i, j]`` and the sender did not
+        abstain, else -1, and a receiver's deliveries are the count of
+        such cells (what the orchestrator's message accounting reads)."""
         n = 64
-        topo = {
-            "ring": lambda: NetworkTopology.ring(n),
-            "grid": lambda: NetworkTopology.grid(8, 8),
-            "full": lambda: NetworkTopology.fully_connected(n),
-        }[topo_name]()
-        mask = topo.receiver_mask()
+        mask = self._topology_n64(topo_name).receiver_mask()
         rng = np.random.default_rng(16)
         values_np = rng.integers(0, 50, size=n).astype(np.int32)
         values_np[rng.choice(n, size=7, replace=False)] = -1  # abstainers
         spmd = np.asarray(exchange_values(
             jnp.asarray(values_np), jnp.asarray(mask), self.mesh
         ))
-        received, deliveries = masked_exchange(
-            jnp.asarray(values_np), jnp.asarray(mask)
-        )
-        np.testing.assert_array_equal(np.asarray(received), spmd)
-        # deliveries[i] == number of proposals receiver i actually got
-        # in the collective form (delivered cells are exactly the >= 0
-        # cells: abstainers and non-neighbours read -1).
+        delivered = mask & (values_np >= 0)[None, :]
         np.testing.assert_array_equal(
-            np.asarray(deliveries), (spmd >= 0).sum(axis=1)
+            spmd, np.where(delivered, values_np[None, :], -1)
+        )
+        np.testing.assert_array_equal(
+            (spmd >= 0).sum(axis=1), delivered.sum(axis=1)
         )
 
     @pytest.mark.parametrize("topo_name", ["ring", "grid", "full"])
     def test_matrix_exchange_matches_spmd_form_n64(self, topo_name):
-        """ISSUE-18 satellite: the equivocation-capable proposal-MATRIX
-        exchange must agree between its dense mega-round form
-        (masked_exchange_matrix) and its shard_map collective form
-        (exchange_proposals) at the 64-agent scale — same equivocated
-        matrix into both, per-cell received values identical; and with
-        nobody equivocating both reduce to the scalar-broadcast
-        exchange (the identity that keeps non-adversary rounds
-        byte-stable on the fused path)."""
-        from bcg_tpu.parallel.game_step import (
-            equivocate_proposals,
-            exchange_proposals,
-            masked_exchange_matrix,
-        )
+        """The equivocation-capable proposal-MATRIX exchange
+        (exchange_proposals) at the 64-agent scale against the host
+        expression in NumPy: the equivocated matrix is built from
+        ``scenarios/strategies.py::equivocation_value`` as the host
+        path builds it, and a received cell is the matrix's where
+        ``mask[i, j]`` and the sender did not abstain, else -1; with
+        nobody equivocating the matrix form reduces to the
+        scalar-broadcast exchange."""
+        from bcg_tpu.parallel.game_step import exchange_proposals
+        from bcg_tpu.scenarios.strategies import equivocation_value
 
         n, lo, hi = 64, 0, 50
-        topo = {
-            "ring": lambda: NetworkTopology.ring(n),
-            "grid": lambda: NetworkTopology.grid(8, 8),
-            "full": lambda: NetworkTopology.fully_connected(n),
-        }[topo_name]()
-        mask = jnp.asarray(topo.receiver_mask())
+        mask_np = self._topology_n64(topo_name).receiver_mask()
+        mask = jnp.asarray(mask_np)
         rng = np.random.default_rng(18)
         values_np = rng.integers(lo, hi + 1, size=n).astype(np.int32)
         values_np[rng.choice(n, size=7, replace=False)] = -1  # abstainers
         equiv_np = np.zeros(n, dtype=bool)
         equiv_np[rng.choice(n, size=9, replace=False)] = True
-        matrix = equivocate_proposals(
-            jnp.asarray(values_np), jnp.asarray(equiv_np), lo, hi
+        plain_np = np.broadcast_to(values_np[None, :], (n, n))
+        matrix_np = np.where(
+            equiv_np[None, :] & (values_np >= 0)[None, :],
+            equivocation_value(
+                values_np[None, :], np.arange(n, dtype=np.int32)[:, None],
+                lo, hi,
+            ),
+            plain_np,
+        ).astype(np.int32)
+        spmd = np.asarray(
+            exchange_proposals(jnp.asarray(matrix_np), mask, self.mesh)
         )
-        dense, _ = masked_exchange_matrix(matrix, mask)
-        spmd = np.asarray(exchange_proposals(matrix, mask, self.mesh))
-        np.testing.assert_array_equal(np.asarray(dense), spmd)
+        np.testing.assert_array_equal(
+            spmd, np.where(mask_np & (matrix_np >= 0), matrix_np, -1)
+        )
         # An equivocating non-abstaining sender delivers receiver-
         # dependent values to its delivered cells; receiver 0's cell
         # (when delivered) carries the base value.
-        mask_np = np.asarray(mask)
         for j in np.flatnonzero(equiv_np & (values_np >= 0)):
             delivered = spmd[mask_np[:, j], j]
             if delivered.size > 1:
                 assert len(set(delivered.tolist())) > 1, j
             if mask_np[0, j]:
                 assert spmd[0, j] == values_np[j]
-        # Nobody equivocating: matrix paths reduce to the scalar form.
-        plain = equivocate_proposals(
-            jnp.asarray(values_np), jnp.zeros(n, dtype=bool), lo, hi
-        )
+        # Nobody equivocating: the matrix path reduces to the scalar form.
         scalar = np.asarray(exchange_values(
             jnp.asarray(values_np), mask, self.mesh
         ))
         np.testing.assert_array_equal(
-            np.asarray(exchange_proposals(plain, mask, self.mesh)), scalar
+            np.asarray(exchange_proposals(
+                jnp.asarray(plain_np), mask, self.mesh
+            )),
+            scalar,
         )
 
     def test_exchange_values_global_matches_sharded_form(self):

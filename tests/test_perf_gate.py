@@ -45,7 +45,6 @@ NAMESPACE_OWNERS = {
     "int4": "tests/test_int4_kv.py",
     "fleet": "tests/test_fleet.py",
     "hostsync": "tests/test_hostsync.py",
-    "megaround": "tests/test_megaround.py",
     "compile": "tests/test_compile_obs.py",
     "sweep": "tests/test_sweep.py",
     "chaos": "tests/test_resilience.py",

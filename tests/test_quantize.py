@@ -78,11 +78,14 @@ class TestQuantizedModel:
 
 
 class TestQuantizedEngine:
-    def test_guided_json_still_valid(self):
-        engine = JaxEngine(EngineConfig(
-            backend="jax", model_name="bcg-tpu/tiny-test",
-            max_model_len=1024, quantization="int8",
-        ))
+    @pytest.mark.parametrize("cell", [None, "qwen3-8b-int8"],
+                             ids=["int8_weights", "qwen3-8b-int8"])
+    def test_guided_json_still_valid(self, cell, cell_engine_options):
+        engine = JaxEngine(EngineConfig(**{
+            "backend": "jax", "model_name": "bcg-tpu/tiny-test",
+            "max_model_len": 1024, "quantization": "int8",
+            **(cell_engine_options(cell) if cell else {}),
+        }))
         schema = {
             "type": "object",
             "properties": {"decision": {"type": "string", "enum": ["stop", "continue"]}},

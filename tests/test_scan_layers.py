@@ -220,6 +220,51 @@ def test_engine_scan_with_prefix_caching():
     assert len(eng_cached._prefix_cache) == 2
 
 
+@pytest.mark.parametrize("loop", [
+    {"decode_fast_forward": True}, {"spec_decode": True},
+], ids=["fast_forward", "speculative"])
+def test_engine_loops_serve_plain_greedy_on_stacked_int8(loop):
+    """The fast-forward and speculative loops address the stacked int8
+    cache through the same seam as the plain loop (``_carried_entry`` /
+    ``_carry_with``) and no benchmark cell drives them: each serves the
+    plain loop's greedy tokens, from forced chains and accepted drafts."""
+    from bcg_tpu.config import EngineConfig
+    from bcg_tpu.engine.jax_engine import JaxEngine
+
+    schema = {
+        "type": "object",
+        "properties": {
+            "decision": {"type": "string", "enum": ["stop", "continue"]},
+            "value": {"type": "integer", "minimum": 0, "maximum": 50},
+        },
+        "required": ["decision", "value"],
+        "additionalProperties": False,
+    }
+    base = EngineConfig(
+        model_name="bcg-tpu/tiny-test", backend="jax", max_model_len=2048,
+        prefix_caching=False, scan_layers=True, kv_cache_dtype="int8",
+    )
+    prompts = [
+        ("You are agent_1.", 'Round 2. {"decision": "continue", "value": 41}', schema),
+        ("You are agent_2. " + "Rules. " * 20, "Pick a value.", schema),
+    ]
+    eng_plain = JaxEngine(base)
+    eng_loop = JaxEngine(dataclasses.replace(base, **loop))
+    try:
+        out_plain = eng_plain.batch_generate_json(
+            prompts, temperature=0.0, max_tokens=40)
+        steps_plain = eng_plain.total_decode_steps
+        out_loop = eng_loop.batch_generate_json(
+            prompts, temperature=0.0, max_tokens=40)
+    finally:
+        eng_plain.shutdown()
+        eng_loop.shutdown()
+    assert all("error" not in r for r in out_plain), out_plain
+    assert out_loop == out_plain
+    # The loop did its own work: fewer device iterations for the same tokens.
+    assert 0 < eng_loop.total_decode_steps < steps_plain
+
+
 # ---------------------------------------- the stacked cache, updated in place
 
 HYBRID = spec_for_model("bcg-tpu/tiny-hybrid")

@@ -223,12 +223,20 @@ class TestTenantScheduling:
         assert snap["completed"] == 5
 
     def test_priority_class_beats_fairness(self):
-        from bcg_tpu.serve.scheduler import Scheduler
-
         sched = self._scheduler(bucket_rows=4, strict_admission=False)
         sched.register_tenant("lowprio", priority=0)
         sched.register_tenant("highprio", priority=5)
+        # The order of dispatch, read where it happens: the tag of each
+        # batch the engine is handed (two threads woken by two `done`
+        # events race under load).
         order = []
+        serve = sched._engine.batch_generate_json
+
+        def recording(prompts, *args, **kw):
+            order.append(prompts[0][1][0])
+            return serve(prompts, *args, **kw)
+
+        sched._engine.batch_generate_json = recording
         release, plug = self._plug(sched)
         try:
             seed = sched.submit(("json",), [self._row("l")] * 4, [0.0] * 4,
@@ -238,24 +246,14 @@ class TestTenantScheduling:
                               [64] * 4, tenant="lowprio")
             hi = sched.submit(("json",), [self._row("h")] * 4, [0.0] * 4,
                               [64] * 4, tenant="highprio")
-
-            def track(req, name):
-                req.done.wait(30)
-                order.append(name)
-
-            ts = [threading.Thread(target=track, args=(lo, "lo")),
-                  threading.Thread(target=track, args=(hi, "hi"))]
-            for t in ts:
-                t.start()
         finally:
             release.set()
             plug.join(10)
-        for t in ts:
-            t.join(30)
-        seed.done.wait(30)
+        for r in (seed, lo, hi):
+            assert r.done.wait(30)
         sched.close()
         # highprio submitted AFTER lowprio but dispatched first.
-        assert order[0] == "hi", order
+        assert order == ["l", "h", "l"], order
 
     def test_untenanted_requests_share_one_fair_account(self):
         """On a tenanted scheduler, untenanted (and unregistered-name)

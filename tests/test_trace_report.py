@@ -88,41 +88,6 @@ def test_report_derives_spec_acceptance(tmp_path):
     assert "speculative" not in proc2.stdout
 
 
-def test_report_derives_round_fusion_line(tmp_path):
-    """engine.megaround.rounds in an export turns into the one-line
-    round-fusion summary with syncs/round from the game.host_syncs
-    histogram flats (and the line is absent without fused rounds)."""
-    trace = {
-        "traceEvents": [],
-        "otherData": {"counters": {
-            "engine.megaround.rounds": 4,
-            "game.host_syncs.count": 4,
-            "game.host_syncs.sum": 4,
-        }},
-    }
-    path = tmp_path / "megaround_trace.json"
-    path.write_text(json.dumps(trace))
-    proc = subprocess.run(
-        [sys.executable, SCRIPT, str(path)],
-        capture_output=True, text=True, timeout=60,
-    )
-    assert proc.returncode == 0, proc.stderr
-    assert "round fusion: 4 fused round(s)" in proc.stdout
-    assert "1.0 sync(s)/round" in proc.stdout
-    # No fused rounds -> no line (a lockstep game must not render one).
-    bare = tmp_path / "bare.json"
-    bare.write_text(json.dumps({
-        "traceEvents": [],
-        "otherData": {"counters": {"game.host_syncs.count": 4,
-                                   "game.host_syncs.sum": 24}},
-    }))
-    proc2 = subprocess.run(
-        [sys.executable, SCRIPT, str(bare)],
-        capture_output=True, text=True, timeout=60,
-    )
-    assert "round fusion" not in proc2.stdout
-
-
 def test_report_renders_hlo_census_table(tmp_path):
     """engine.hlo.* gauges in an export render as the per-jit-entry
     kernel-census table — still with no bcg_tpu import (the report must
