@@ -42,7 +42,7 @@ from bcg_tpu.engine.speculative import (
 )
 from bcg_tpu.engine.tokenizer import Tokenizer, tokenizer_for_model
 from bcg_tpu.guided.processor import GuidedBatch, compile_schema
-from bcg_tpu.ops import PallasTP
+from bcg_tpu.ops import PallasTP, is_pallas
 from bcg_tpu.ops.guided_sampler import (
     PALLAS as _GS_PALLAS,
     PALLAS_INTERPRET as _GS_PALLAS_INTERPRET,
@@ -457,8 +457,8 @@ class JaxEngine(InferenceEngine):
         # misaligned cache — a full copy of every k/v/scale array per
         # layer per step, measured as int8 KV losing ~4x to bf16
         # (BENCH_NOTES rounds 1-2).  Allocating the cache pre-aligned
-        # makes that pad a no-op; the extra masked slots cost only their
-        # streaming bandwidth (<= BLOCK_S-1 slots).
+        # makes that pad a no-op; the extra masked slots cost their
+        # memory and a grid step of nothing per block.
         # Sequence-parallel decode shards the cache over sp, so the
         # allocated length must divide by sp — the length-bucket ladders
         # are all even but S = bucket + max_new + 1 is odd, which would
@@ -472,9 +472,10 @@ class JaxEngine(InferenceEngine):
         elif self.decode_attention_impl == "pallas":
             from bcg_tpu.ops.decode_attention import ALIGN_S
 
-            # ALIGN_S (1024) also unlocks the kernels' large-block path
-            # (block 512 measured 1.7x slower per step than 1024 —
-            # per-program overhead).
+            # A multiple of the kernels' block whatever block they
+            # pick (ops/decode_attention.BLOCK_S): the slots it adds
+            # past a row's last token are never attended, and the
+            # kernel skips their blocks.
             self._kv_align = ALIGN_S
         else:
             self._kv_align = 1
@@ -3196,15 +3197,18 @@ class JaxEngine(InferenceEngine):
         # the auditor is off) — a crash after this call keeps the sync
         # profile in the bench error JSON.
         obs_hostsync.publish()
-        # Perf accounting.  Decode streams the whole ALLOCATED cache
-        # window every step (einsum and Pallas paths both read all S
-        # slots, masked), plus one full weight pass per loop iteration.
-        spec = self.spec
-        slot_bytes = self._kv_slot_bytes
+        # Perf accounting.  A decode step streams the cache slots of
+        # each row's LIVE blocks where the int8 kernel attends
+        # (_decode_kv_slots) and the whole ALLOCATED window elsewhere
+        # (the einsum paths read all S slots, masked), plus one full
+        # weight pass per loop iteration.
         self.prefill_tokens += B * (L if (prepped is None and not paged) else Ls)
         self.prefill_seconds += t1 - t0
         self.decode_seconds += t2 - t1
-        self.decode_kv_bytes += steps * B * S * slot_bytes * self._kv_layers
+        self.decode_kv_bytes += (
+            self._decode_kv_slots(valid_mask, L, steps, use_spec or use_ff)
+            * self._kv_slot_bytes * self._kv_layers
+        )
         self.decode_weight_passes += steps
         texts = []
         served = 0
@@ -3224,6 +3228,40 @@ class JaxEngine(InferenceEngine):
         obs_counters.inc("engine.decode.tokens", served)
         obs_counters.inc("engine.decode.row_steps", steps * real_B)
         return texts
+
+    def _decode_kv_slots(self, valid_mask: np.ndarray, L: int, steps: int,
+                         chunked: bool) -> int:
+        """Cache slots one attention layer streamed over a decode loop
+        of ``steps`` iterations, summed over rows and steps, from what
+        the host holds: the call's mask at the loop's start ([B, S]) and
+        the first decode slot ``L``.  Where the int8 kernel attends (a
+        dense int8 cache, no ``sp`` ring) that is each row's live blocks
+        (``ops/decode_attention.live_block_range``: the rule the kernel's
+        own bound comes from), also added, over the attention layers, to
+        ``engine.decode.kv_blocks_live`` beside the grid's
+        ``kv_blocks_grid``; every other path reads the allocation.  The
+        plain loop's step ``i`` has written slot ``L + i``; a chunk
+        loop's write position is the device's, so its steps count to the
+        allocation's end (an upper bound)."""
+        B, S = valid_mask.shape
+        if not (is_pallas(self._resolved_loop_impl(chunk=chunked))
+                and self.kv_quantized and self._sp_devices <= 1):
+            return steps * B * S
+        from bcg_tpu.ops.decode_attention import (
+            kernel_block, live_block_count, live_slots,
+        )
+
+        block = kernel_block(self.spec.num_kv_heads, self.spec.head_dim)
+        first, last = live_slots(valid_mask, xp=np)
+        at_step = np.full(steps, S - 1) if chunked else L + np.arange(steps)
+        live = live_block_count(
+            np.minimum(first[:, None], at_step),
+            np.maximum(last[:, None], at_step), block)
+        obs_counters.inc("engine.decode.kv_blocks_live", live * self._kv_layers)
+        obs_counters.inc(
+            "engine.decode.kv_blocks_grid",
+            steps * B * -(-S // block) * self._kv_layers)
+        return live * block
 
     def _cache_bytes(self, B: int, S: int) -> Dict[str, int]:
         """Bytes of a [B, S] decode cache by kind of state, read off the

@@ -748,10 +748,36 @@ def _cache_attention(q, entry, mask, scale, impl: str):
     return _xla_attention(q, k, v, mask[:, None, :], scale)
 
 
+def _live_mask(mask, cache, impl, ring):
+    """A decode step's (or chunk's) mask as the int8 decode kernels take
+    it where they are what attends under it (a dense int8 cache, a
+    Pallas ``impl``, no ``sp`` ring): with each row's first and last
+    attendable slot beside it (``ops/decode_attention.LiveMask``),
+    reduced HERE, once a step, and not once a layer inside the layer
+    scan.  Every other path gets the mask as it came."""
+    if isinstance(impl, HybridImpl):
+        impl = impl.attention
+    entry = _kv_entry(cache)
+    if (ring is not None or not is_pallas(impl)
+            or "k_scale" not in entry or "tbl" in entry):
+        return mask
+    from bcg_tpu.ops.decode_attention import LiveMask, live_slots
+
+    return LiveMask(mask, live_slots(mask))
+
+
+def _kv_entry(cache) -> Dict:
+    """The leaves of a K/V entry of a cache in any of its forms: a
+    list's first attention entry, a stack, a hybrid's ``{kind: stack}``."""
+    if isinstance(cache, dict):
+        return cache.get(FULL_ATTENTION, cache)
+    return next(e for e in cache if "k" in e)
+
+
 def _cache_len(cache) -> int:
     """Allocated cache length S, across layouts: bf16 k is
     [(Lyr,) B, S, Hkv, Dh]; quantized storage is [(Lyr,) B, Hkv, S, Dh]."""
-    entry = cache if isinstance(cache, dict) else cache[0]
+    entry = _kv_entry(cache)
     return entry["k"].shape[-2 if "k_scale" in entry else -3]
 
 
@@ -1602,8 +1628,8 @@ def decode_step(
     x = params["embed"][token][:, None, :]  # [B, 1, D]
 
     x, new_cache = _run_layers(
-        params, spec, x, cos, sin, write_pos, cache, valid_mask, impl,
-        ring=ring,
+        params, spec, x, cos, sin, write_pos, cache,
+        _live_mask(valid_mask, cache, impl, ring), impl, ring=ring,
     )
     logits = _logits(params, spec, x)[:, 0, :]
     return logits, new_cache
@@ -1644,7 +1670,8 @@ def decode_chunk(
 
     x = params["embed"][tokens]
     x, new_cache = _run_layers(
-        params, spec, x, cos, sin, write_pos, cache, attn_mask, impl,
+        params, spec, x, cos, sin, write_pos, cache,
+        _live_mask(attn_mask, cache, impl, ring), impl,
         chunk=True, ring=ring,
     )
     # Per-row last valid chunk position -> one LM-head application.
@@ -1696,7 +1723,8 @@ def decode_chunk_spec(
 
     x = params["embed"][tokens]
     x, new_cache = _run_layers(
-        params, spec, x, cos, sin, row_write_pos, cache, attn_mask, impl,
+        params, spec, x, cos, sin, row_write_pos, cache,
+        _live_mask(attn_mask, cache, impl, ring), impl,
         chunk=True, ring=ring,
     )
     logits = _logits(params, spec, x)                              # [B, K1, V]

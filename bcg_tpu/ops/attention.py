@@ -42,8 +42,10 @@ def shard_heads(kernel, mesh: Mesh, batch: int, head_ranks):
     Head operands are ``[B, heads, ...]`` of the given ranks and shard
     their head axis over ``tp``; ``(rank, lead)`` is one with ``lead``
     replicated axes in front (a stacked cache's ``[Lyr, B, heads,
-    ...]``), ``None`` one replicated whole (a prefetched scalar).  The
-    trailing mask is ``[B, rows, S]`` with no head axis; the output is
+    ...]``), ``None`` one replicated whole (a prefetched scalar),
+    ``"rows"`` a prefetched ``[n, B]`` table with a column a batch row
+    and no head axis (split with the batch alone).  The trailing mask
+    is ``[B, rows, S]`` with no head axis; the output is
     ``[B, heads, rows, Dh]``.  Batch shards over ``dp`` when it divides;
     other mesh axes replicate.  Callers check head divisibility (the
     engine's boot rule)."""
@@ -56,6 +58,8 @@ def shard_heads(kernel, mesh: Mesh, batch: int, head_ranks):
     def operand(r):
         if r is None:
             return P()
+        if r == "rows":
+            return P(None, dp_ax)
         return heads(*r) if isinstance(r, tuple) else heads(r)
 
     return jax.shard_map(

@@ -1,11 +1,21 @@
 """Pallas decode-step attention (T = 1) with optional int8 KV cache.
 
-Decode reads the whole KV cache every step — it is HBM-bandwidth-bound
-(the reference's vLLM leans on FlashAttention/xFORMERS CUDA paged
-kernels for the same reason, ``vllm_agent.py:34-55``).  This kernel:
+Decode reads every LIVE block of the KV cache every step — it is bound
+by the cache it streams (the reference's vLLM leans on
+FlashAttention/xFORMERS CUDA paged kernels for the same reason,
+``vllm_agent.py:34-55``).  This kernel:
 
 * streams K/V blocks once from HBM, online-softmax accumulation in VMEM
   (the stock einsum path materializes f32 scores and re-reads V);
+* (int8) stops at the live slots: the grid spans the whole ALLOCATION,
+  but each row's first and last block that hold an attendable slot ride
+  the scalar prefetch (:func:`live_block_range`, read off the mask), a
+  grid step outside them re-addresses the block already resident (the
+  pipeline issues no copy) and runs no body.  Left pad and the unwritten
+  tail of the allocation cost a step of nothing each; slots dead INSIDE
+  the range (a fast-forward gap, the pad in the first live block) are
+  masked in the body.  A wholly masked block leaves the online softmax
+  as it was, so the output is that of the unbounded grid, bit for bit;
 * optionally reads **int8** K/V with per-(position, kv-head) scales and
   dequantizes in VMEM — halving the dominant HBM traffic with no
   full-precision cache copy ever materialized;
@@ -32,25 +42,35 @@ and step).  A per-entry cache runs the same call as a stack of one.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
 _NEG_INF = -1e30
 
-# S-axis block sizes the kernels stream by.  Callers that ALLOCATE the
-# cache should round its length up to a multiple of ALIGN_S: `_pad_s` on
-# a misaligned cache is a jnp.pad — a full copy of every k/v/scale array
-# PER LAYER PER DECODE STEP, which is how the int8 cache measured ~4x
-# slower than bf16 in round 1-2 (the bf16 einsum path never pads).
-# Block size is picked per call: 1024 when the (padded) length divides —
-# measured in-loop on v5e at bench shapes (B=10, Hkv=8, S=4096):
-# 1.18 ms/step at block 512 vs 0.70 at 1024 (per-program overhead
-# dominates small blocks); 2048/4096 gain <5% more.
-BLOCK_S = 512
+# The S-axis block the kernels stream by, and what callers that ALLOCATE
+# the cache round its length up to: `_pad_s` on a misaligned cache is a
+# jnp.pad — a full copy of every k/v/scale array PER LAYER PER DECODE
+# STEP, which is how the int8 cache measured ~4x slower than bf16 in
+# round 1-2 (the bf16 einsum path never pads).
+# The block is also the granularity at which the int8 kernel skips dead
+# slots (module docstring): the first and last live block of a row are
+# computed whole.  Measured on a v5e on the all-heads grid (B, nS) with
+# the bound, in both benchmark cells (10 rows, S 5120, 2.2-2.5k live
+# slots behind 1.5k of left pad; `round_s`, PERF.md section 6, PR 32):
+# 8 KV heads 15.61 s at block 1024, 15.07 at 512, 14.73 at 256; 30 KV
+# heads 14.66 at 512, 14.15 at 256.  A grid step outside the range
+# costs about half a microsecond, so 128 would pay more in empty steps
+# than its finer edges save (arithmetic, not measured).  The 1024 that
+# stood here was chosen on the PER-HEAD grid (B, Hkv, nS), 640 programs
+# of a ~2 us fixed cost each, unbounded (B=10, Hkv=8, S=4096: 1.18
+# ms/step at 512 vs 0.70 at 1024): that grid is gone.
+BLOCK_S = 256
 ALIGN_S = 1024
 
 
@@ -61,17 +81,65 @@ ALIGN_S = 1024
 _KV_BLOCKS_BUDGET = 8 << 20
 
 
-def _pick_block(S: int, requested, kv_row_bytes: int = 0) -> int:
+def _pick_block(requested, kv_row_bytes: int = 0) -> int:
     """``kv_row_bytes``: bytes of one cache position over all the kv
     heads a program holds (Hkv * Dh for the int8 layout; 0 where a
-    program holds one head).  8 heads of 128 keep the 1024 block; 30
-    (an MHA model's) halve it, or the kernel does not fit VMEM."""
+    program holds one head).  ``BLOCK_S`` unless that many heads' K and
+    V blocks would not fit VMEM (more than 64 heads of 128)."""
     if requested is not None:
         return requested
-    block = ALIGN_S if S % ALIGN_S == 0 else BLOCK_S
+    block = BLOCK_S
     while block > 128 and 4 * block * kv_row_bytes > _KV_BLOCKS_BUDGET:
         block //= 2
     return block
+
+
+def kernel_block(num_kv_heads: int, head_dim: int) -> int:
+    """The block the int8 kernels stream a cache by when the caller
+    names none: what the engine's block counter counts in."""
+    return _pick_block(None, num_kv_heads * head_dim)
+
+
+class LiveMask(NamedTuple):
+    """A decode mask with :func:`live_slots` of it beside it.  The int8
+    forms take either this or the bare mask (and then reduce it
+    themselves); a caller that attends many layers under one mask makes
+    it once a step, outside the layer scan, so the reduction over S is
+    not repeated a layer."""
+
+    mask: jax.Array      # [B, S] or [B, K, S] bool
+    slots: jax.Array     # [2, B] int32
+
+
+def live_slots(mask, xp=jnp):
+    """``[2, B]`` int32: each row's first and last attendable slot, over
+    every query row of a chunk mask ([B, K, S]: the union).  A row with
+    none reads ``(S, -1)``.  ``xp=numpy`` for a mask the host holds."""
+    rows = mask if mask.ndim == 2 else mask.any(axis=1)
+    S = rows.shape[-1]
+    some = rows.any(axis=-1)
+    first = xp.where(some, xp.argmax(rows, axis=-1), S)
+    last = xp.where(some, S - 1 - xp.argmax(rows[:, ::-1], axis=-1), -1)
+    return xp.stack([first, last]).astype(xp.int32)
+
+
+def live_block_range(first_slot, last_slot, block_s: int, xp=jnp):
+    """``(first, last)`` block of ``block_s`` slots that holds an
+    attendable slot, from the first and last attendable slot (arrays
+    that broadcast).  No slot (``last_slot < first_slot``) is the empty
+    range ``(1, 0)``: no block is live and a clip to it addresses block
+    0.  The kernel's bound and the engine's block counter
+    (``engine.decode.kv_blocks_live``) share this one definition."""
+    empty = last_slot < first_slot
+    return (xp.where(empty, 1, first_slot // block_s),
+            xp.where(empty, 0, last_slot // block_s))
+
+
+def live_block_count(first_slot, last_slot, block_s: int) -> int:
+    """Blocks inside :func:`live_block_range`, summed over everything
+    the two (NumPy) arrays broadcast to: rows by steps, on the host."""
+    first, last = live_block_range(first_slot, last_slot, block_s, xp=np)
+    return int((last - first + 1).sum())
 
 
 def _decode_kernel(
@@ -131,10 +199,13 @@ def _decode_kernel(
 
 
 def _decode_kernel_allheads(
-    q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, o_ref,
+    live_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, o_ref,
     m_scr, l_scr, acc_scr, *, scale, num_s_blocks, hkv,
 ):
     """int8 variant processing ALL kv heads per program: grid (B, nS).
+    ``live_ref`` ([2, B] in SMEM): the row's first and last live block;
+    a step outside them computes nothing (its blocks are the neighbour
+    step's, re-addressed by the index maps, and hold no attendable slot).
 
     The per-head grid (B, Hkv, nS) paid a ~2 us fixed cost per program
     invocation (v5e, measured in-loop round 3) — at decode block counts
@@ -143,7 +214,7 @@ def _decode_kernel_allheads(
     Mosaic-native int8 tiles, scratch is per-head-indexed on its leading
     dim (static index — no sublane-offset slicing).
     """
-    s = pl.program_id(1)
+    b, s = pl.program_id(0), pl.program_id(1)
 
     @pl.when(s == 0)
     def _init():
@@ -151,28 +222,31 @@ def _decode_kernel_allheads(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    mask = mask_ref[0]                       # [M, Sblk]; M = 1 or rows
-    maskf = mask.astype(jnp.float32)
-    for h in range(hkv):
-        q = q_ref[0, h]                      # [rows, Dh]
-        k = k_ref[0, h].astype(jnp.float32) * ks_ref[0, h][:, None]
-        v = v_ref[0, h].astype(jnp.float32) * vs_ref[0, h][:, None]
-        k = k.astype(q.dtype)
-        v = v.astype(q.dtype)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                            # [rows, Sblk]
-        scores = jnp.where(mask, scores, _NEG_INF)
-        m_prev = m_scr[h]                    # [rows, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new) * maskf
-        m_scr[h] = m_new
-        l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[h] = alpha * acc_scr[h] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    @pl.when((s >= live_ref[0, b]) & (s <= live_ref[1, b]))
+    def _compute():
+        mask = mask_ref[0]                   # [M, Sblk]; M = 1 or rows
+        maskf = mask.astype(jnp.float32)
+        for h in range(hkv):
+            q = q_ref[0, h]                  # [rows, Dh]
+            k = k_ref[0, h].astype(jnp.float32) * ks_ref[0, h][:, None]
+            v = v_ref[0, h].astype(jnp.float32) * vs_ref[0, h][:, None]
+            k = k.astype(q.dtype)
+            v = v.astype(q.dtype)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                        # [rows, Sblk]
+            scores = jnp.where(mask, scores, _NEG_INF)
+            m_prev = m_scr[h]                # [rows, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new) * maskf
+            m_scr[h] = m_new
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = alpha * acc_scr[h] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     @pl.when(s == num_s_blocks - 1)
     def _finish():
@@ -183,19 +257,24 @@ def _decode_kernel_allheads(
             ).astype(o_ref.dtype)
 
 
-def _quantized_attention(qg, layer, kp, vp, ksp, vsp, mp, scale, block_s,
-                         interpret, mesh=None):
+def _quantized_attention(qg, live, layer, kp, vp, ksp, vsp, mp, scale,
+                         block_s, interpret, mesh=None):
     """Shared pallas_call for the int8 single-step and chunk paths.
 
-    qg [B, Hkv, rows, Dh]; layer [1] int32; kp/vp [Lyr, B, Hkv, Sp, Dh]
-    int8; scales [Lyr, B, Hkv, Sp]; mp [B, M, Sp] with M == 1
-    (broadcast) or rows.  Returns [B, Hkv, rows, Dh].  ``layer`` is
-    scalar-prefetched: the K/V/scale index maps read that layer's
-    blocks, the stack's leading axis squeezed away, so the kernel body
-    sees one entry's blocks whatever the stack's depth.  ``mesh``: each
+    qg [B, Hkv, rows, Dh]; live [2, B] int32; layer [1] int32; kp/vp
+    [Lyr, B, Hkv, Sp, Dh] int8; scales [Lyr, B, Hkv, Sp]; mp [B, M, Sp]
+    with M == 1 (broadcast) or rows.  Returns [B, Hkv, rows, Dh].
+    ``live`` and ``layer`` are scalar-prefetched.  The K/V/scale index
+    maps read that layer's blocks, the stack's leading axis squeezed
+    away, so the kernel body sees one entry's blocks whatever the
+    stack's depth.  ``live`` is each row's first and last block with an
+    attendable slot (:func:`live_block_range` of ``mp``): every S-axis
+    index map addresses ``clip(s, first, last)``, so a grid step outside
+    the range names the block the step beside it holds, which the
+    pipeline does not copy again, and the body skips it.  ``mesh``: each
     ``tp`` device runs the kernel on its own Hkv/tp heads
-    (ops/attention.shard_heads) — every operand but the mask is laid
-    out kv-head-major for exactly this.
+    (ops/attention.shard_heads) — every operand but the mask and the
+    range is laid out kv-head-major for exactly this.
     """
     if mesh is not None:
         from bcg_tpu.ops.attention import shard_heads
@@ -205,26 +284,34 @@ def _quantized_attention(qg, layer, kp, vp, ksp, vsp, mp, scale, block_s,
                 _quantized_attention, scale=scale, block_s=block_s,
                 interpret=interpret,
             ),
-            mesh, qg.shape[0], (4, None, (5, 1), (5, 1), (4, 1), (4, 1)),
-        )(qg, layer, kp, vp, ksp, vsp, mp)
+            mesh, qg.shape[0],
+            (4, "rows", None, (5, 1), (5, 1), (4, 1), (4, 1)),
+        )(qg, live, layer, kp, vp, ksp, vsp, mp)
     B, Hkv, rows, Dh = qg.shape
     Sp = kp.shape[3]
     M = mp.shape[1]
     nS = Sp // block_s
-    kv_spec = pl.BlockSpec(
-        (None, 1, Hkv, block_s, Dh), lambda b, s, li: (li[0], b, 0, s, 0))
-    scale_spec = pl.BlockSpec(
-        (None, 1, Hkv, block_s), lambda b, s, li: (li[0], b, 0, s))
-    q_spec = pl.BlockSpec((1, Hkv, rows, Dh), lambda b, s, li: (b, 0, 0, 0))
 
-    def kernel(layer_ref, *refs):
+    def blk(b, s, lv):
+        return jnp.minimum(jnp.maximum(s, lv[0, b]), lv[1, b])
+
+    kv_spec = pl.BlockSpec(
+        (None, 1, Hkv, block_s, Dh),
+        lambda b, s, lv, li: (li[0], b, 0, blk(b, s, lv), 0))
+    scale_spec = pl.BlockSpec(
+        (None, 1, Hkv, block_s),
+        lambda b, s, lv, li: (li[0], b, 0, blk(b, s, lv)))
+    q_spec = pl.BlockSpec((1, Hkv, rows, Dh), lambda b, s, lv, li: (b, 0, 0, 0))
+
+    def kernel(live_ref, layer_ref, *refs):
         del layer_ref   # the index maps read it
-        _decode_kernel_allheads(*refs, scale=scale, num_s_blocks=nS, hkv=Hkv)
+        _decode_kernel_allheads(
+            live_ref, *refs, scale=scale, num_s_blocks=nS, hkv=Hkv)
 
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B, nS),
             in_specs=[
                 q_spec,
@@ -232,7 +319,9 @@ def _quantized_attention(qg, layer, kp, vp, ksp, vsp, mp, scale, block_s,
                 kv_spec,
                 scale_spec,
                 scale_spec,
-                pl.BlockSpec((1, M, block_s), lambda b, s, li: (b, 0, s)),
+                pl.BlockSpec(
+                    (1, M, block_s),
+                    lambda b, s, lv, li: (b, 0, blk(b, s, lv))),
             ],
             out_specs=q_spec,
             scratch_shapes=[
@@ -246,7 +335,7 @@ def _quantized_attention(qg, layer, kp, vp, ksp, vsp, mp, scale, block_s,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(layer, qg, kp, vp, ksp, vsp, mp)
+    )(live, layer, qg, kp, vp, ksp, vsp, mp)
 
 
 def pow2_rows(group: int) -> int:
@@ -288,6 +377,15 @@ def _int8_operands(k, v, k_scale, v_scale, layer, block_s):
     return (jnp.asarray(layer, jnp.int32).reshape(1),) + leaves
 
 
+def _bounded(mask, block_s):
+    """``(mask, live)`` of an int8 form's ``mask`` argument: the bare
+    mask and the [2, B] block range :func:`_quantized_attention`
+    prefetches, in units of THIS call's block."""
+    if not isinstance(mask, LiveMask):
+        mask = LiveMask(mask, live_slots(mask))
+    return mask.mask, jnp.stack(live_block_range(*mask.slots, block_s))
+
+
 def decode_attention(
     q, k, v, mask, scale,
     k_scale=None, v_scale=None,
@@ -296,7 +394,8 @@ def decode_attention(
     mesh=None,
     layer=None,
 ):
-    """q [B, H, Dh], mask [B, S] -> [B, H, Dh].
+    """q [B, H, Dh], mask [B, S] -> [B, H, Dh].  The int8 form also
+    takes the mask as a :class:`LiveMask`.
 
     k/v: [B, S, Hkv, Dh] bf16, or — when ``k_scale`` is given — the int8
     cache layout [B, Hkv, S, Dh] (int8 tiles natively as (32, 128) over
@@ -309,8 +408,8 @@ def decode_attention(
     B, H, Dh = q.shape
     quantized = k_scale is not None
     block_s = (
-        _pick_block(k.shape[-2], block_s, k.shape[-3] * k.shape[-1]) if quantized
-        else _pick_block(k.shape[1], block_s)
+        _pick_block(block_s, k.shape[-3] * k.shape[-1]) if quantized
+        else _pick_block(block_s)
     )
     if quantized:
         Hkv = k.shape[-3]
@@ -324,8 +423,9 @@ def decode_attention(
         qg = q.reshape(B, Hkv, group, Dh)
         if g2 != group:
             qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g2 - group), (0, 0)))
+        mask, live = _bounded(mask, block_s)
         out = _quantized_attention(
-            qg, *_int8_operands(k, v, k_scale, v_scale, layer, block_s),
+            qg, live, *_int8_operands(k, v, k_scale, v_scale, layer, block_s),
             _pad_s(mask, block_s, axis=1)[:, None, :],
             scale, block_s, interpret, mesh,
         )
@@ -391,8 +491,9 @@ def chunk_decode_attention(
 ):
     """Fast-forward chunk decode over the (possibly int8) cache.
 
-    q [B, K, H, Dh] (K chunk positions), mask [B, K, S] -> [B, K, H, Dh];
-    k/v [B, S, Hkv, Dh] bf16 or the int8 cache layout [B, Hkv, S, Dh],
+    q [B, K, H, Dh] (K chunk positions), mask [B, K, S] (int8: or a
+    :class:`LiveMask` of it) -> [B, K, H, Dh]; k/v [B, S, Hkv, Dh] bf16
+    or the int8 cache layout [B, Hkv, S, Dh],
     stacked with ``layer`` (see :func:`decode_attention`).  Same
     streaming/online-softmax/in-VMEM-dequant design, with an
     [K*group, Dh] query tile per (batch, kv-head) program — K=4, group=2
@@ -402,8 +503,8 @@ def chunk_decode_attention(
     B, K, H, Dh = q.shape
     quantized = k_scale is not None
     block_s = (
-        _pick_block(k.shape[-2], block_s, k.shape[-3] * k.shape[-1]) if quantized
-        else _pick_block(k.shape[1], block_s)
+        _pick_block(block_s, k.shape[-3] * k.shape[-1]) if quantized
+        else _pick_block(block_s)
     )
     if quantized:
         Hkv = k.shape[-3]
@@ -415,6 +516,7 @@ def chunk_decode_attention(
         # next power of two (see decode_attention); padded rows reuse
         # their chunk's mask and are sliced away below.
         g2 = pow2_rows(group)
+        mask, live = _bounded(mask, block_s)
         mp = jnp.repeat(_pad_s(mask, block_s, axis=2), g2, axis=1)
         qg = q.reshape(B, K, Hkv, group, Dh)
         if g2 != group:
@@ -423,7 +525,7 @@ def chunk_decode_attention(
             )
         qg = qg.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, K * g2, Dh)
         out = _quantized_attention(
-            qg, *_int8_operands(k, v, k_scale, v_scale, layer, block_s),
+            qg, live, *_int8_operands(k, v, k_scale, v_scale, layer, block_s),
             mp, scale, block_s, interpret, mesh,
         )
         out = out.reshape(B, Hkv, K, g2, Dh)

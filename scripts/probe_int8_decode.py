@@ -27,14 +27,13 @@ from bcg_tpu.ops.decode_attention import (
     quantize_kv,
 )
 
-# (name, B, H, Hkv, Dh, S).  S values cover BOTH kernel block
-# configurations: 2048/4096 divide ALIGN_S=1024 so they compile the
-# block-1024 path the engine actually serves (it aligns the int8 cache
-# to ALIGN_S), while 3584 exercises the block-512 fallback pick.
+# (name, B, H, Hkv, Dh, S).  2048/4096 are lengths the engine serves
+# (it aligns the int8 cache to ALIGN_S=1024); 3584 is off that alignment
+# and on the kernels' own block (BLOCK_S).
 CASES = [
     ("1b-shapes", 10, 16, 8, 128, 2048),
     ("8b-shapes", 10, 32, 8, 128, 4096),
-    ("block512-path", 10, 32, 8, 128, 3584),
+    ("off-align-path", 10, 32, 8, 128, 3584),
 ]
 
 # INFORMATIONAL cases: validated-if-they-pass, but failures do NOT gate

@@ -467,19 +467,19 @@ class TestCache:
 
 class TestDecodeKernelBlock:
     """The all-heads int8 decode kernel holds a K and a V block of every
-    kv head at once: 30 heads (an MHA model) at the 1024 block do not
-    fit a v5e's scoped VMEM, so the block follows the head count."""
+    kv head at once, double-buffered: the block follows the head count
+    where that would pass a v5e's scoped VMEM (30 heads at a 1024 block
+    did, before the block became 256 for every model the repo names)."""
 
     @pytest.mark.parametrize("hkv, dh, block", [
-        (8, 128, 1024), (30, 128, 512), (64, 128, 256), (8, 256, 1024)])
+        (8, 128, 256), (30, 128, 256), (64, 128, 256), (128, 128, 128)])
     def test_block_follows_the_heads_held(self, hkv, dh, block):
-        from bcg_tpu.ops.decode_attention import _pick_block
+        from bcg_tpu.ops.decode_attention import _pick_block, kernel_block
 
-        assert _pick_block(5120, None, hkv * dh) == block
+        assert _pick_block(None, hkv * dh) == kernel_block(hkv, dh) == block
 
-    def test_requested_and_unaligned(self):
-        from bcg_tpu.ops.decode_attention import _pick_block
+    def test_requested_and_one_head_a_program(self):
+        from bcg_tpu.ops.decode_attention import BLOCK_S, _pick_block
 
-        assert _pick_block(5120, 256, 30 * 128) == 256
-        assert _pick_block(4608, None) == 512
-        assert _pick_block(4096, None) == 1024
+        assert _pick_block(512, 30 * 128) == 512
+        assert _pick_block(None) == BLOCK_S == 256

@@ -400,7 +400,7 @@ def _moved_int8(text, op):
 def test_no_layer_of_the_stacked_cache_is_copied(family, impl, monkeypatch):
     """Static guard on the lowered decode step: the layer scan writes
     ONE token's K and V into the stacked int8 cache in place and moves
-    no layer's whole entry.  S = 1536 is three blocks of the kernel and
+    no layer's whole entry.  S = 1536 is six blocks of the kernel and
     no other extent of the program.  Under the kernel (interpret mode
     here) nothing int8 with all S slots is sliced or written at all;
     the XLA twin reads the one layer it dequantises, never the stack."""
@@ -420,3 +420,42 @@ def test_no_layer_of_the_stacked_cache_is_copied(family, impl, monkeypatch):
         assert reads and all(S not in dims for dims in reads), reads
     else:
         assert len(reads) == 2 and all(d[0] == 1 and S in d for d in reads), reads
+
+
+def _primitives(jaxpr, inside_scan=False):
+    """``(name, inside a scan's body)`` of every equation, sub-jaxprs
+    (a scan's body, a kernel's, a pjit's) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, inside_scan
+        inner = inside_scan or eqn.primitive.name == "scan"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub, inner)
+
+
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_live_range_is_reduced_outside_the_layer_scan(family, monkeypatch):
+    """The traced decode step under the cells' form (layer scan over a
+    stacked int8 cache, the kernel attending): each row's first and last
+    attendable slot are read off the mask ONCE a step, outside the scan
+    over layers; the scan's body holds the kernel and no reduction over
+    the mask's S slots."""
+    S = 1536
+    spec = SPEC if family == "dense" else HYBRID
+    _interpreted_kernel(monkeypatch)
+    impl = T.HybridImpl("pallas", "xla") if spec.hybrid else "pallas"
+    params = jax.eval_shape(
+        lambda k: stack_layer_params(init_params(spec, k), spec=spec),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    cache = jax.eval_shape(functools.partial(
+        init_kv_cache, spec, 2, S, quantized="int8", stacked=True))
+    jaxpr = jax.make_jaxpr(
+        lambda p, tok, pos, seq, c, m: decode_step(p, spec, tok, pos, seq, c, m, impl)
+    )(params, jax.ShapeDtypeStruct((2,), jnp.int32),
+      jax.ShapeDtypeStruct((), jnp.int32), jax.ShapeDtypeStruct((2,), jnp.int32),
+      cache, jax.ShapeDtypeStruct((2, S), jnp.bool_))
+    seen = list(_primitives(jaxpr.jaxpr))
+    assert ("pallas_call", True) in seen
+    # first and last slot: two argmax over S, both before the scan
+    assert seen.count(("argmax", False)) == 2, seen
+    assert ("argmax", True) not in seen and ("reduce_or", True) not in seen
+

@@ -132,8 +132,8 @@ class TestOneChip:
     def test_int8_decode_30_kv_heads(self, one):
         # An MHA model's full-attention layers (30 KV heads of 128,
         # group 1): the all-heads kernel holds every head's K and V
-        # block at once, and at the 1024 block that is over a v5e's
-        # scoped VMEM; the block follows the heads held (_pick_block).
+        # block at once (at a 1024 block that was over a v5e's scoped
+        # VMEM; the block follows the heads held, _pick_block).
         hkv, s_cache = 30, 5120
         _compile(
             _decode_int8, one,
@@ -143,28 +143,38 @@ class TestOneChip:
             ((B, s_cache), jnp.bool_),
         )
 
+    @pytest.mark.parametrize("ranged", ["by_the_kernel", "by_the_caller"])
     @pytest.mark.parametrize("hkv, rows", [(8, 4), (30, 1)])
-    def test_int8_decode_reads_its_layer_of_a_stack(self, one, hkv, rows):
+    def test_int8_decode_reads_its_layer_of_a_stack(self, one, hkv, rows, ranged):
         # The layer scan's form: the whole stacked cache as the operand,
         # the layer index scalar-prefetched, the layer's blocks found by
         # the K/V/scale index maps (no slice of the layer beforehand).
-        # Both cells' geometry: 8 KV heads of group 4 at the 1024 block,
-        # 30 of group 1 at 512.  The output shape is how the benchmark's
+        # Both cells' geometry: 8 KV heads of group 4 and 30 of group 1,
+        # at the block the kernel picks for both (256: 20 grid steps a row).  The output shape is how the benchmark's
         # ``trace_names.decode_attention`` finds the kernel.
-        from bcg_tpu.ops.decode_attention import _pick_block, decode_attention
+        # The kernel is bounded to each row's live blocks, which ride the
+        # scalar prefetch beside the layer index and steer every S-axis
+        # index map: read off the mask in the call, or handed over with
+        # it (``LiveMask``: the decode step reduces the mask once, outside
+        # the layer scan).
+        from bcg_tpu.ops.decode_attention import (
+            LiveMask, decode_attention, kernel_block,
+        )
 
         layers, s_cache = 4, 5120
-        assert _pick_block(s_cache, None, hkv * DH) == (1024 if hkv == 8 else 512)
+        assert kernel_block(hkv, DH) == 256
 
-        def stacked(q, k, v, ks, vs, mask, layer):
-            return decode_attention(q, k, v, mask, SCALE, k_scale=ks,
-                                    v_scale=vs, layer=layer)
+        def stacked(q, k, v, ks, vs, mask, layer, *slots):
+            return decode_attention(
+                q, k, v, LiveMask(mask, *slots) if slots else mask, SCALE,
+                k_scale=ks, v_scale=vs, layer=layer)
 
         kv = ((layers, B, hkv, s_cache, DH), jnp.int8)
         sc = ((layers, B, hkv, s_cache), jnp.float32)
         text = _compile(
             stacked, one, ((B, hkv * rows, DH), jnp.bfloat16), kv, kv, sc, sc,
             ((B, s_cache), jnp.bool_), ((), jnp.int32),
+            *([((2, B), jnp.int32)] if ranged == "by_the_caller" else []),
         ).as_text()
         assert f"bf16[{B},{hkv},{rows},{DH}]" in text
         # nothing the size of a layer is produced on the way to the kernel
@@ -357,20 +367,29 @@ class TestShardedKernelsOnCpuMesh:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-6, rtol=1e-6)
 
+    @pytest.mark.parametrize("dp", [1, 2])
     @pytest.mark.parametrize("stacked", [False, True])
     @pytest.mark.parametrize("chunk", [False, True])
-    def test_int8_decode_matches_unsharded(self, mesh, chunk, stacked):
+    def test_int8_decode_matches_unsharded(self, chunk, stacked, dp):
+        # Rows of different live ranges (blocks 0-1 and, behind a left
+        # pad, block 1 alone): the prefetched range is a column a batch
+        # row, so under ``dp`` it splits with the batch (``dp=2``: each
+        # device must find its own row's range in column 0).
         from bcg_tpu.ops.decode_attention import (
             chunk_decode_attention, decode_attention, quantize_kv,
         )
 
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(dp, 4 // dp, 1),
+                    ("dp", "tp", "sp"))
         b, s, h, hkv, kk = 2, 256, 8, 4, 4
         ks = jax.random.split(jax.random.PRNGKey(1), 3)
         q = jax.random.normal(
             ks[0], (b, kk, h, DH) if chunk else (b, h, DH), jnp.float32)
         kq, ksc = quantize_kv(jax.random.normal(ks[1], (b, hkv, s, DH)))
         vq, vsc = quantize_kv(jax.random.normal(ks[2], (b, hkv, s, DH)))
-        mask = jnp.arange(s)[None, :] < jnp.asarray([200, 77])[:, None]
+        slots = jnp.arange(s)[None, :]
+        mask = ((slots >= jnp.asarray([0, 150])[:, None])
+                & (slots < jnp.asarray([200, 240])[:, None]))
         if chunk:
             mask = mask[:, None, :].repeat(kk, 1)
         fn = functools.partial(
