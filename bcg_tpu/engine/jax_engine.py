@@ -2655,6 +2655,12 @@ class JaxEngine(InferenceEngine):
             else np.zeros(B, np.int64)
         )
         first_logits = None
+        # The flash kernel's grid steps, and those its skip guard lets
+        # compute, over rows, KV heads and attention layers (ring chunks
+        # attend through the sp decode kernels instead).
+        flash = self.attention_impl == "pallas" and self._sp_devices <= 1
+        if flash:
+            from bcg_tpu.ops.attention import chunk_steps
         for start in starts:
             Ct = min(C, L - start)
             H = P + L - Ct
@@ -2663,6 +2669,14 @@ class JaxEngine(InferenceEngine):
                 hist[:, :P] = prefix_valid
             hist[:, P:P + start] = valid[:, :start]
             pos_off = base_lens + valid[:, :start].sum(axis=1)
+            if flash:
+                live, grid = chunk_steps(
+                    hist, valid[:, start:start + Ct],
+                    self.spec.num_heads // self.spec.num_kv_heads,
+                    self.spec.head_dim)
+                heads = self.spec.num_kv_heads * self._kv_layers
+                obs_counters.inc("engine.prefill.attn_steps_live", live * heads)
+                obs_counters.inc("engine.prefill.attn_steps_grid", grid * heads)
             first_logits, cache = obs_hlo.wrap(
                 "prefill_chunk", self._prefill_chunk_at
             )(

@@ -29,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -74,6 +75,66 @@ def shard_heads(kernel, mesh: Mesh, batch: int, head_ranks):
 
 # ------------------------------------------------------------------ pallas
 
+# A grid step carries every query head of a GQA group against one K/V
+# block: ``group * block_q`` query rows, at most this many for a head dim
+# of 128 (fewer for a wider head).  At 2048 a 512-token chunk of a
+# group-4 model is one query block.  A 512-slot K/V block times faster
+# on a v5e (PERF.md) but adds each row's keys up in another order; at
+# 256 every query row meets its keys in the blocks and order the
+# per-head grid did, so the served tokens stay bit for bit.
+_ROWS = 2048
+BLOCK_KV = 256
+_MIN_BLOCK_Q = 128
+
+
+def flash_blocks(group: int, T: int, head_dim: int):
+    """``(block_q, block_kv)`` the flash kernel runs a query of ``T``
+    tokens at: ``T`` pads to a multiple of ``_MIN_BLOCK_Q``, and
+    ``block_q`` is the largest multiple of it that divides the padded
+    length and keeps ``group * block_q`` rows within the budget.  The
+    kernel wrapper and the engine's step counter
+    (:func:`chunk_steps`) share this one rule."""
+    rows = _ROWS * 128 // head_dim
+    Tp = -(-T // _MIN_BLOCK_Q) * _MIN_BLOCK_Q
+    for block_q in range(Tp, _MIN_BLOCK_Q, -_MIN_BLOCK_Q):
+        if Tp % block_q == 0 and group * block_q <= rows:
+            return block_q, BLOCK_KV
+    return _MIN_BLOCK_Q, BLOCK_KV
+
+
+def chunk_steps(hist_valid, valid, group: int, head_dim: int):
+    """``(live, grid)`` grid steps of the flash kernel over one layer and
+    KV head of a chunk program, summed over rows, for the mask
+    ``transformer.prefill_chunk_at`` builds from the host's ``hist_valid``
+    ([B, H]) and ``valid`` ([B, C]): query row ``i`` attends history slot
+    ``j`` where both are valid, and chunk key ``j <= i`` where both are.
+    A step is live where the kernel's ``blk_any`` guard lets it compute:
+    some query of its block attends some key of its block.  O(B * (H +
+    C)) on the host; the [B, C, H + C] mask is never built."""
+    B, H = hist_valid.shape
+    C = valid.shape[1]
+    block_q, block_kv = flash_blocks(group, C, head_dim)
+    Tp = -(-C // block_q) * block_q
+    Sp = -(-(H + C) // block_kv) * block_kv
+    nT, nS = Tp // block_q, Sp // block_kv
+    cols = np.arange(C)
+    # last valid query of each query block (-1: none)
+    q_last = np.full((B, Tp), -1)
+    q_last[:, :C] = np.where(valid, cols, -1)
+    q_last = q_last.reshape(B, nT, block_q).max(axis=-1)
+    # key blocks over [history | chunk | pad]: any valid history slot,
+    # and the first valid chunk key (Tp: none)
+    hist = np.zeros((B, Sp), bool)
+    hist[:, :H] = hist_valid
+    hist = hist.reshape(B, nS, block_kv).any(axis=-1)
+    k_first = np.full((B, Sp), Tp)
+    k_first[:, H:H + C] = np.where(valid, cols, Tp)
+    k_first = k_first.reshape(B, nS, block_kv).min(axis=-1)
+    live = ((q_last[:, :, None] >= 0) & hist[:, None, :]) | (
+        q_last[:, :, None] >= k_first[:, None, :])
+    return int(live.sum()), B * nT * nS
+
+
 def _flash_kernel(
     q_ref, k_ref, v_ref, mask_ref, blk_any_ref, o_ref,
     m_scr, l_scr, acc_scr, *, scale, num_s_blocks,
@@ -99,17 +160,21 @@ def _flash_kernel(
 
     @pl.when(blk_any_ref[b, t, s] != 0)
     def _compute():
-        q = q_ref[0, 0]                      # [Tblk, Dh]
+        # The group's heads are one [group * Tblk] block of rows against
+        # the K/V block they share; the mask is the same for every head.
+        group, block_q, dh = q_ref.shape[1:]
+        q = q_ref[0].reshape(group * block_q, dh)
         k = k_ref[0, 0]                      # [Sblk, Dh]
         v = v_ref[0, 0]                      # [Sblk, Dh]
-        mask = mask_ref[0]                   # [Tblk, Sblk] bool
+        mask = mask_ref[0][None]             # [1, Tblk, Sblk] bool
 
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                            # [Tblk, Sblk]
+        ) * scale
+        scores = scores.reshape(group, block_q, -1)   # [G, Tblk, Sblk]
         scores = jnp.where(mask, scores, _NEG_INF)
 
-        m_prev = m_scr[...]                  # [Tblk, 1]
+        m_prev = m_scr[...]                  # [G, Tblk, 1]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # Multiply by the mask: with the finite -1e30 sentinel, a fully-
@@ -119,21 +184,24 @@ def _flash_kernel(
 
         m_scr[...] = m_new
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        pv = jax.lax.dot_general(
+            p.reshape(group * block_q, -1).astype(v.dtype), v,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
+        acc_scr[...] = alpha * acc_scr[...] + pv.reshape(group, block_q, dh)
 
     @pl.when(s == num_s_blocks - 1)
     def _finish():
         l = l_scr[...]
-        o_ref[0, 0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def _pallas_flash(q, k, v, mask, scale, block_q: int, block_kv: int,
                   interpret: bool = False):
     """q [B,H,T,Dh], k/v [B,Hkv,S,Dh], mask [B,T,S] — pre-padded so that
-    T % block_q == 0, S % block_kv == 0, Dh % 128 == 0."""
+    T % block_q == 0, S % block_kv == 0, Dh % 128 == 0.  The grid is
+    (B, Hkv, T/block_q, S/block_kv): a step holds the whole GQA group's
+    query block, so each K/V block is fetched once per KV head."""
     B, H, T, Dh = q.shape
     Hkv, S = k.shape[1], k.shape[2]
     group = H // Hkv
@@ -149,24 +217,21 @@ def _pallas_flash(q, k, v, mask, scale, block_q: int, block_kv: int,
     kernel = functools.partial(_flash_kernel, scale=scale, num_s_blocks=nS)
     return pl.pallas_call(
         kernel,
-        grid=(B, H, nT, nS),
+        grid=(B, Hkv, nT, nS),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, t, s: (b, h, t, 0)),
-            pl.BlockSpec(
-                (1, 1, block_kv, Dh), lambda b, h, t, s, g=group: (b, h // g, s, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, Dh), lambda b, h, t, s, g=group: (b, h // g, s, 0)
-            ),
+            pl.BlockSpec((1, group, block_q, Dh), lambda b, h, t, s: (b, h, t, 0)),
+            pl.BlockSpec((1, 1, block_kv, Dh), lambda b, h, t, s: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, block_kv, Dh), lambda b, h, t, s: (b, h, s, 0)),
             pl.BlockSpec((1, block_q, block_kv), lambda b, h, t, s: (b, t, s)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, t, s: (b, h, t, 0)),
+        out_specs=pl.BlockSpec(
+            (1, group, block_q, Dh), lambda b, h, t, s: (b, h, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, Dh), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
-            pltpu.VMEM((block_q, Dh), jnp.float32),  # output accumulator
+            pltpu.VMEM((group, block_q, 1), jnp.float32),   # running max m
+            pltpu.VMEM((group, block_q, 1), jnp.float32),   # running sum l
+            pltpu.VMEM((group, block_q, Dh), jnp.float32),  # output accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -185,11 +250,11 @@ def _pad_to(x, axis: int, multiple: int, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-def flash_attention(q, k, v, mask, scale, block_q: int = 128,
-                    block_kv: int = 256, mesh: Optional[Mesh] = None,
+def flash_attention(q, k, v, mask, scale, mesh: Optional[Mesh] = None,
                     interpret: bool = False):
-    """Pallas flash attention.  ``mesh``: run per ``tp`` head shard
-    (:func:`shard_heads`).  Raises on a head dim the kernel cannot tile —
+    """Pallas flash attention at the blocks :func:`flash_blocks` picks.
+    ``mesh``: run per ``tp`` head shard (:func:`shard_heads`; a shard
+    keeps the group).  Raises on a head dim the kernel cannot tile —
     the caller picks :func:`blockwise_attention` for those, by name."""
     B, T, H, Dh = q.shape
     if Dh % 128 != 0:
@@ -197,6 +262,7 @@ def flash_attention(q, k, v, mask, scale, block_q: int = 128,
             f"flash_attention needs head_dim % 128 == 0, got {Dh}; use "
             "blockwise_attention (impl='blockwise') for this geometry"
         )
+    block_q, block_kv = flash_blocks(H // k.shape[2], T, Dh)
     qt = _pad_to(q.transpose(0, 2, 1, 3), 2, block_q)
     kt = _pad_to(k.transpose(0, 2, 1, 3), 2, block_kv)
     vt = _pad_to(v.transpose(0, 2, 1, 3), 2, block_kv)

@@ -16,6 +16,11 @@ Fails off-TPU (nothing would be validated).  Prints
 
 from __future__ import annotations
 
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 import numpy as np
 
 import jax

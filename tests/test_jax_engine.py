@@ -570,6 +570,60 @@ class TestChunkedPrefillSkipsDeadChunks:
         # Fewer calls of the SAME program: nothing new compiles.
         assert chunk_at._cache_size() <= 2
 
+    @pytest.mark.parametrize("cell", ["qwen3-8b-int8", "olmo-hybrid-7b-int8"])
+    def test_flash_step_counters_are_the_kernels_guard(
+            self, cell, cell_engine_options):
+        """``engine.prefill.attn_steps_live`` / ``_grid``: over the chunk
+        programs a call sends, the flash kernel's own ``blk_any`` table
+        from each program's whole mask, times KV heads and attention
+        layers.  The count is the host's: the CPU programs keep their
+        XLA attention and the engine is told its prefill runs the
+        kernel (a CPU boot resolves "xla")."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from bcg_tpu.obs import counters as obs_counters
+        from bcg_tpu.ops.attention import _pad_to, flash_blocks
+
+        engine = JaxEngine(EngineConfig(
+            backend="jax", max_model_len=2048, **cell_engine_options(cell)))
+        engine.attention_impl = "pallas"
+        seen, chunk_at = [], engine._prefill_chunk_at
+
+        def spy_chunk_at(*args, **kw):
+            seen.append((np.asarray(kw["hist_valid"]), np.asarray(kw["valid"])))
+            return chunk_at(*args, **kw)
+
+        engine._prefill_chunk_at = spy_chunk_at
+        before = obs_counters.snapshot()
+        try:
+            out = engine.batch_generate_json(
+                [self.LONG_ROW, self.SHORT_ROW], temperature=0.0, max_tokens=24)
+        finally:
+            engine.shutdown()
+        moved = obs_counters.delta(before)
+        assert all("error" not in r for r in out)
+        spec = engine.spec
+        group, live, grid = spec.num_heads // spec.num_kv_heads, 0, 0
+        assert len(seen) > 1
+        for hist, valid in seen:
+            B, C = valid.shape
+            causal = np.tril(np.ones((C, C), bool))
+            mask = np.concatenate(
+                [hist[:, None, :] & valid[:, :, None],
+                 causal[None] & valid[:, None, :] & valid[:, :, None]], axis=2)
+            block_q, block_kv = flash_blocks(group, C, spec.head_dim)
+            mp = np.asarray(_pad_to(_pad_to(jnp.asarray(mask), 1, block_q),
+                                    2, block_kv))
+            nT, nS = mp.shape[1] // block_q, mp.shape[2] // block_kv
+            live += int(mp.reshape(B, nT, block_q, nS, block_kv)
+                        .any(axis=(2, 4)).sum())
+            grid += B * nT * nS
+        heads = spec.num_kv_heads * engine._kv_layers
+        assert moved["engine.prefill.attn_steps_live"] == live * heads
+        assert moved["engine.prefill.attn_steps_grid"] == grid * heads
+        assert 0 < live < grid
+
     @pytest.mark.parametrize("rows, overrides", [
         ([LONG_ROW], {}),
         # The suffix window's leading chunk goes (and the entry build's).
