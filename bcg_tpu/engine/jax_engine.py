@@ -59,7 +59,6 @@ from bcg_tpu.obs import (
 from bcg_tpu.models.configs import (
     FULL_ATTENTION,
     LARGE_MODEL_PARAMS,
-    LINEAR_ATTENTION,
     ModelSpec,
     spec_for_model,
 )
@@ -846,15 +845,17 @@ class JaxEngine(InferenceEngine):
         # jit entry points (shape-polymorphic via jax.jit's trace cache).
         self._prefill_impl = self._kernel_impl(self.attention_impl)
         if self.spec.hybrid:
-            from bcg_tpu.ops import HybridImpl, gated_delta
+            from bcg_tpu.ops import HybridImpl, gated_delta, moe
 
             # One switch for the Pallas kernels: where flash prefill
-            # runs, the chunkwise delta-rule kernel runs; elsewhere its
-            # XLA twin (the same chunk mathematics as a scan).
+            # runs, the chunkwise delta-rule kernel and the grouped
+            # expert matmul run; elsewhere their XLA twins (the same
+            # chunk mathematics as a scan; every held expert, weighted).
+            kernels = self.attention_impl == "pallas"
             self._prefill_impl = HybridImpl(
                 self._prefill_impl,
-                gated_delta.PALLAS if self.attention_impl == "pallas"
-                else gated_delta.XLA,
+                gated_delta.PALLAS if kernels else gated_delta.XLA,
+                moe.PALLAS if kernels else moe.XLA,
             )
         # Named after their obs_hlo census entries: every program of
         # the prefill family carries "prefill" in the profiler's trace.
@@ -909,6 +910,10 @@ class JaxEngine(InferenceEngine):
                 # sp it must shard, not bypass (transformer.prefill_chunk_at
                 # ring branch — the chunk attends the whole sharded cache).
                 ring=((mesh, "sp") if self._sp_devices > 1 else None),
+                # a spec with routed experts: each chunk also counts its
+                # (token, held expert) assignments, read back with the
+                # decode loop's steps (no sync of their own)
+                **({"tally": True} if self.spec.moe else {}),
             ),
             donate_argnames=("cache",),
         )
@@ -988,6 +993,9 @@ class JaxEngine(InferenceEngine):
         self._mesh_devices = mesh.size if mesh is not None else 1
         self._kv_bytes_memo: Dict[Tuple[int, int], int] = {}
         self._cache_bytes_memo: Dict[Tuple[int, int], Dict[str, int]] = {}
+        # a spec with routed experts: the chunk programs' counts of (token,
+        # held expert) assignments, on the device until the call's readback
+        self._moe_prefill_counts: list = []
         self._param_bytes = sum(
             getattr(p, "nbytes", 0) for p in jax.tree.leaves(self.params)
         )
@@ -2106,6 +2114,10 @@ class JaxEngine(InferenceEngine):
         spec = self.spec
         eos_id = self.tokenizer.eos_id
         sampler = self._make_masked_sampler(eos_id, top_p, impl=sampler_impl)
+        # A spec with routed experts counts, in the carry, the held
+        # experts each step's rows hit (summed over layers and steps):
+        # read back with the steps, so no further sync.
+        tally = spec.moe
 
         def loop(params, cache, first_logits, valid_mask, prompt_lens, L,
                  tables, accepting, min_budget, dfa_ids, init_states,
@@ -2126,30 +2138,31 @@ class JaxEngine(InferenceEngine):
                 return (i < max_new - 1) & ~done.all()
 
             def body(carry):
-                i, done, cur_tok, states, cache, valid_mask, out, rng = carry
+                i, done, cur_tok, states, cache, valid_mask, out, rng, *hits = carry
                 # Open cache slot L+i, run the step, sample token i+1.
                 valid_mask = jax.lax.dynamic_update_slice(
                     valid_mask, jnp.ones((B, 1), bool), (0, L + i)
                 )
-                logits, cache = decode_step(
+                logits, cache, *hit = decode_step(
                     params, spec,
                     jnp.where(done, eos_id, cur_tok),
                     L + i, prompt_lens + i, cache, valid_mask, impl,
-                    ring=ring,
+                    ring=ring, tally=tally,
                 )
                 tok, states, rng = masked_sample(logits, states, rng, i + 1)
                 tok = jnp.where(done, eos_id, tok)
                 out = jax.lax.dynamic_update_slice(out, tok[:, None], (0, i + 1))
                 done = done | (tok == eos_id)
                 cur_tok = jnp.where(done, cur_tok, tok)
-                return (i + 1, done, cur_tok, states, cache, valid_mask, out, rng)
+                return (i + 1, done, cur_tok, states, cache, valid_mask, out, rng,
+                        *[n + m for n, m in zip(hits, hit)])
 
             tok0, states0, rng = masked_sample(first_logits, init_states, rng, 0)
             out = jnp.full((B, max_new), eos_id, dtype=jnp.int32)
             out = out.at[:, 0].set(tok0)
             carry = (jnp.int32(0), tok0 == eos_id, tok0, states0,
-                     cache, valid_mask, out, rng)
-            i, done, cur_tok, states, cache, valid_mask, out, rng = jax.lax.while_loop(
+                     cache, valid_mask, out, rng, *([jnp.int32(0)] if tally else []))
+            i, done, cur_tok, states, cache, valid_mask, out, rng, *hits = jax.lax.while_loop(
                 cond, body, carry
             )
             # Early-exit rows are already EOS-filled (out initialized to
@@ -2159,7 +2172,7 @@ class JaxEngine(InferenceEngine):
             # alias the loop carry — without a matching output the
             # donation is unusable and the program holds TWO full caches
             # (measured: pushed an 8B compile 8 GB past HBM capacity).
-            return out, (rng, i), cache
+            return out, (rng, i, *hits), cache
 
         return jax.jit(loop, static_argnames=("L",), donate_argnums=(1,))
 
@@ -2522,8 +2535,7 @@ class JaxEngine(InferenceEngine):
             obs_counters.inc(
                 "engine.cache.linear_state_bytes", by_kind["linear_state"])
             obs_counters.inc(
-                "engine.linear.state_rows",
-                B * self.spec.layers_of(LINEAR_ATTENTION))
+                "engine.linear.state_rows", B * self.spec.recurrent_layers)
         if self.mesh is None or self._mesh_devices <= 1:
             return init_kv_cache(self.spec, B, S, **kw)
         key = (B, S)
@@ -2594,7 +2606,7 @@ class JaxEngine(InferenceEngine):
             # prefill (the chunkwise kernel, or its XLA twin)
             obs_counters.inc(
                 "engine.linear.prefill_positions",
-                B * (L - starts.start) * self.spec.layers_of(LINEAR_ATTENTION),
+                B * (L - starts.start) * self.spec.recurrent_layers,
             )
         if not C or L <= C:
             if has_prefix:
@@ -2677,7 +2689,7 @@ class JaxEngine(InferenceEngine):
                 heads = self.spec.num_kv_heads * self._kv_layers
                 obs_counters.inc("engine.prefill.attn_steps_live", live * heads)
                 obs_counters.inc("engine.prefill.attn_steps_grid", grid * heads)
-            first_logits, cache = obs_hlo.wrap(
+            first_logits, cache, *count = obs_hlo.wrap(
                 "prefill_chunk", self._prefill_chunk_at
             )(
                 self.params,
@@ -2688,6 +2700,10 @@ class JaxEngine(InferenceEngine):
                 pos_offset=self._put_batch(pos_off.astype(np.int32)),
                 write_pos=jnp.int32(P + start),
             )
+            self._moe_prefill_counts += count
+        if self.spec.moe:
+            obs_counters.inc("engine.moe.prefill.tokens",
+                             int(valid.sum()) * self.spec.num_layers)
         return first_logits, cache
 
     def _prefill_paged_possibly_chunked(self, tokens, valid, Ls: int, cache,
@@ -3161,7 +3177,7 @@ class JaxEngine(InferenceEngine):
                         loop_args,
                     )
                 with obs_compile.time_block("decode_loop"):
-                    out, (_, steps), _cache_out = loop(*loop_args)
+                    out, (_, steps, *hits), _cache_out = loop(*loop_args)
             if paged:
                 # The loop wrote decode KV into private pool blocks
                 # through the donated carry: retain the returned pool
@@ -3174,7 +3190,22 @@ class JaxEngine(InferenceEngine):
             # Decode-loop iterations of this call (each is one weight
             # pass — the wall-clock unit of the decode phase).
             obs_hostsync.note("steps_readback", entry=loop_entry)
-            steps = int(steps)
+            if self.spec.moe:
+                # One transfer: the steps, the loop's expert hits and the
+                # chunk programs' (token, held expert) assignments,
+                # stacked on the device.  The hits are counted over steps
+                # and expert layers beside the slots: every held expert a
+                # step and layer.
+                prefilled, self._moe_prefill_counts = self._moe_prefill_counts, []
+                steps, hit, assigned = (int(n) for n in np.asarray(
+                    jnp.stack([steps, *hits, sum(prefilled)])))
+                obs_counters.inc("engine.moe.decode.experts_hit", hit)
+                obs_counters.inc(
+                    "engine.moe.decode.expert_slots",
+                    steps * self.spec.num_layers * self.spec.experts_held)
+                obs_counters.inc("engine.moe.prefill.assignments", assigned)
+            else:
+                steps = int(steps)
             obs_tracer.annotate(steps=steps)
         t2 = time.perf_counter()
         if not self._first_call_recorded:

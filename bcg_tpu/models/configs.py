@@ -14,7 +14,17 @@ delta-rule layer, ``ops/gated_delta.py``), in a repeating period
 also says where its norms sit (``norm_placement="post"``: ``x +
 norm(sublayer(x))``), what its q/k norm spans (``qk_norm="full"``: the
 whole projection before the split into heads) and may have no rotary
-embedding at all (``rope_theta=None``).
+embedding at all (``rope_theta=None``, or ``use_rope=False`` where the
+published config names a theta it does not use).
+
+A hybrid's layers may also be ``"kda"``: the CHANNEL-gated delta rule
+(Kimi Delta Attention, a decay per key channel from a low-rank
+projection; ``ops/gated_delta.py``), and its MLP half may be routed
+experts (``num_experts``): a sigmoid router over every published
+expert, the top ``experts_per_token`` of them renormalised, a shared
+expert, and only the experts this chip HOLDS (``experts_held``, experts
+``0 .. experts_held - 1``) computed here: the chip's share of an
+expert-parallel layer, with no exchange for the rest.
 """
 
 from __future__ import annotations
@@ -24,7 +34,10 @@ from typing import Dict, Optional, Tuple, Union
 
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
-LAYER_TYPES = (FULL_ATTENTION, LINEAR_ATTENTION)
+KDA = "kda"
+LAYER_TYPES = (FULL_ATTENTION, LINEAR_ATTENTION, KDA)
+# layer types whose per-row state is a recurrent state and a conv tail
+RECURRENT_TYPES = (LINEAR_ATTENTION, KDA)
 
 
 # Parameter count at/above which single-chip serving needs the memory
@@ -84,8 +97,33 @@ class ModelSpec:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
     linear_allow_neg_eigval: bool = False
+    # False: no rotary embedding whatever ``rope_theta`` says
+    use_rope: bool = True
+    # full-attention output gated elementwise: ``o * sigmoid(x W_g)``
+    attn_output_gate: bool = False
+    # width of a KDA layer's low-rank decay and output-gate projections
+    kda_gate_rank: int = 0
+    # Routed experts (0: the SwiGLU MLP of ``intermediate_size``).  The
+    # router spans ``num_experts``; this chip holds the first
+    # ``experts_held`` of them.
+    num_experts: int = 0
+    experts_held: int = 0
+    experts_per_token: int = 0
+    moe_intermediate_size: int = 0
+    shared_experts: int = 0
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
+        if self.num_experts and not (
+                self.layer_types is not None
+                and 0 < self.experts_held <= self.num_experts
+                and 0 < self.experts_per_token <= self.num_experts
+                and self.shared_experts in (0, 1)):
+            raise ValueError(
+                f"{self.name}: routed experts are built for a hybrid stack of "
+                "expert layers only, at most one shared expert, and 0 < "
+                "experts_held, experts_per_token <= num_experts")
         if self.layer_types is None:
             return
         if len(self.layer_types) != self.num_layers or \
@@ -111,6 +149,21 @@ class ModelSpec:
             if len(types) % n == 0 and types == types[:n] * (len(types) // n):
                 return types[:n]
         return types
+
+    @property
+    def moe(self) -> bool:
+        return self.num_experts > 0
+
+    @property
+    def first_k_dense_replace(self) -> int:
+        """Leading layers with a dense MLP before the expert layers: none
+        (with experts, every layer of the stack is an expert layer)."""
+        return 0
+
+    @property
+    def recurrent_layers(self) -> int:
+        """Layers that keep a recurrent state and a conv tail per row."""
+        return sum(self.layers_of(kind) for kind in RECURRENT_TYPES)
 
     def layers_of(self, kind: str) -> int:
         if self.layer_types is None:
@@ -140,18 +193,48 @@ class ModelSpec:
 
     def matmul_shapes(self, kind: str = FULL_ATTENTION) -> Dict[str, Tuple[int, int]]:
         """``leaf -> (in, out)`` of the dense matmuls of one layer of
-        ``kind``, the SwiGLU half included: the one table the parameter
-        plan, the size-class gate and the byte estimates read."""
+        ``kind``, the MLP half included (the SwiGLU, or an expert layer's
+        router and shared expert; the held experts' stacks are
+        :meth:`expert_shapes`): the one table the parameter plan, the
+        size-class gate and the byte estimates read."""
         D, F = self.hidden_size, self.intermediate_size
-        mlp = {"w_gate": (D, F), "w_up": (D, F), "w_down": (F, D)}
+        if self.moe:
+            Fm = self.moe_intermediate_size
+            mlp = {"moe_router": (D, self.num_experts)}
+            if self.shared_experts:
+                mlp.update(w_gate=(D, Fm), w_up=(D, Fm), w_down=(Fm, D))
+        else:
+            mlp = {"w_gate": (D, F), "w_up": (D, F), "w_down": (F, D)}
+        K, V = self.linear_key_size, self.linear_value_size
+        if kind == KDA:
+            r = self.kda_gate_rank
+            return {"lin_wq": (D, K), "lin_wk": (D, K), "lin_wv": (D, V),
+                    "lin_wb": (D, self.linear_num_value_heads),
+                    "kda_fa": (D, r), "kda_fb": (r, K),
+                    "kda_ga": (D, r), "kda_gb": (r, V),
+                    "lin_wo": (V, D), **mlp}
         if kind == LINEAR_ATTENTION:
-            K, V = self.linear_key_size, self.linear_value_size
             return {"lin_wq": (D, K), "lin_wk": (D, K), "lin_wv": (D, V),
                     "lin_wa": (D, self.linear_num_value_heads),
                     "lin_wb": (D, self.linear_num_value_heads),
                     "lin_wg": (D, V), "lin_wo": (V, D), **mlp}
+        gate = {"wg": (D, self.q_size)} if self.attn_output_gate else {}
         return {"wq": (D, self.q_size), "wk": (D, self.kv_size),
-                "wv": (D, self.kv_size), "wo": (self.q_size, D), **mlp}
+                "wv": (D, self.kv_size), **gate, "wo": (self.q_size, D), **mlp}
+
+    def expert_shapes(self) -> Dict[str, Tuple[int, int]]:
+        """``leaf -> (in, out)`` of ONE held expert; a layer holds
+        ``experts_held`` of each, stacked ``[experts_held, in, out]``."""
+        if not self.moe:
+            return {}
+        D, Fm = self.hidden_size, self.moe_intermediate_size
+        return {"moe_w_gate": (D, Fm), "moe_w_up": (D, Fm), "moe_w_down": (Fm, D)}
+
+    def _layer_sum(self, kind: str, of) -> int:
+        """``of((in, out))`` summed over one layer's matmuls, held experts
+        included."""
+        return (sum(of(s) for s in self.matmul_shapes(kind).values())
+                + self.experts_held * sum(of(s) for s in self.expert_shapes().values()))
 
     @property
     def matmul_params_per_layer(self) -> int:
@@ -163,9 +246,10 @@ class ModelSpec:
 
     @property
     def block_matmul_params(self) -> int:
-        """Dense matmul parameters of all blocks, counted by layer type."""
+        """Dense matmul parameters of all blocks, counted by layer type
+        (an expert layer's: the experts HELD here)."""
         return sum(
-            self.layers_of(kind) * sum(i * o for i, o in self.matmul_shapes(kind).values())
+            self.layers_of(kind) * self._layer_sum(kind, lambda s: s[0] * s[1])
             for kind in LAYER_TYPES
         )
 
@@ -199,7 +283,7 @@ class ModelSpec:
         # (a hybrid's two per-head gate projections stay bf16 and are
         # counted as if quantized: 0.1% of a layer):
         out_total = self.vocab_size + sum(
-            self.layers_of(kind) * sum(o for _, o in self.matmul_shapes(kind).values())
+            self.layers_of(kind) * self._layer_sum(kind, lambda s: s[1])
             for kind in LAYER_TYPES
         )
         if quantization is None:
@@ -233,6 +317,29 @@ def _olmo_hybrid(name: str, max_position: int) -> ModelSpec:
         linear_num_key_heads=30, linear_num_value_heads=30,
         linear_key_head_dim=96, linear_value_head_dim=192,
         linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+    )
+
+
+def _solar_open2(name: str, num_layers: int, experts_held: int,
+                 vocab_size: int, max_position: int) -> ModelSpec:
+    """Solar-Open2-250B's public ``config.json``: (gated NoPE GQA 64Q/8KV,
+    KDA x 3) periods (``gqa_layers`` every fourth from 0), KDA heads of
+    64 x 128 with a short conv of 4, 320 routed experts of width 1280,
+    top 8, renormalised, and a shared one, in every layer.  Pre-norm,
+    the sigmoid router, the elementwise gate and the low-rank width of
+    128 are assumed (benchmark/configs/solar-open2-250b-int8.json,
+    ``assumed``)."""
+    return ModelSpec(
+        name=name, vocab_size=vocab_size, hidden_size=4096, num_layers=num_layers,
+        num_heads=64, num_kv_heads=8, head_dim=128, intermediate_size=10240,
+        rope_theta=10000.0, use_rope=False, rms_eps=1e-5,
+        attn_output_gate=True, max_position=max_position,
+        layer_types=((FULL_ATTENTION,) + (KDA,) * 3) * (num_layers // 4),
+        linear_num_key_heads=64, linear_num_value_heads=64,
+        linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_conv_kernel_dim=4, linear_allow_neg_eigval=True, kda_gate_rank=128,
+        num_experts=320, experts_held=experts_held, experts_per_token=8,
+        moe_intermediate_size=1280, shared_experts=1, norm_topk_prob=True,
     )
 
 
@@ -284,6 +391,8 @@ MODEL_SPECS: Dict[str, ModelSpec] = {
     # name is here for its shapes, the bcg-tpu/ twin serves random
     # weights behind the byte tokenizer.
     "allenai/Olmo-Hybrid-7B": _olmo_hybrid("allenai/Olmo-Hybrid-7B", 65536),
+    "upstage/Solar-Open2-250B": _solar_open2(
+        "upstage/Solar-Open2-250B", 48, 320, 196608, 1048576),
     # Hermetic HF-artifact specs (models/hf_fixture.py): loaded through
     # the REAL checkpoint pipeline — AutoTokenizer + safetensors shards +
     # config.json on local disk — with random weights.  `tiny` proves the
@@ -367,6 +476,27 @@ MODEL_SPECS: Dict[str, ModelSpec] = {
         linear_num_key_heads=4, linear_num_value_heads=4,
         linear_key_head_dim=8, linear_value_head_dim=16,
         linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+    ),
+    # One chip's share of Solar-Open2-250B served 8-way expert parallel:
+    # the first 12 layers (stage 0 of 4), experts 0-39 of each layer's
+    # 320, vocabulary rows 0-24,575 (the byte tokenizer's ids lie in
+    # them); int8: 9.6 GB of weights.
+    "bcg-tpu/bench-solar-open2-250b": _solar_open2(
+        "bcg-tpu/bench-solar-open2-250b", 12, 40, 24576, 8192),
+    # One period of that block at tiny widths, 8 of 16 experts held: the
+    # CPU tests' stand-in for the spec above.
+    "bcg-tpu/tiny-solar": ModelSpec(
+        name="bcg-tpu/tiny-solar",
+        vocab_size=512, hidden_size=64, num_layers=4,
+        num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=160,
+        rope_theta=10000.0, use_rope=False, rms_eps=1e-5,
+        attn_output_gate=True, max_position=2048,
+        layer_types=(FULL_ATTENTION,) + (KDA,) * 3,
+        linear_num_key_heads=4, linear_num_value_heads=4,
+        linear_key_head_dim=16, linear_value_head_dim=16,
+        linear_conv_kernel_dim=4, linear_allow_neg_eigval=True, kda_gate_rank=8,
+        num_experts=16, experts_held=8, experts_per_token=4,
+        moe_intermediate_size=32, shared_experts=1, norm_topk_prob=True,
     ),
     # Qwen3-14B / 32B dims with random weights: the reference's larger
     # presets (config.py:20-25) as hermetic multi-chip TP targets —

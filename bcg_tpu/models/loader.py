@@ -240,7 +240,7 @@ def init_random_params_sharded(
     output; no golden-value contract exists for random weights.
     """
     from bcg_tpu.models.transformer import (
-        RANDOM_KINDS, assemble_param_tree, init_leaf, param_plan, plan_keys,
+        MATRIX_KINDS, RANDOM_KINDS, assemble_param_tree, init_leaf, param_plan, plan_keys,
     )
 
     sharding_for = None
@@ -275,7 +275,7 @@ def init_random_params_sharded(
                     # Dense leaves only, like init_params and
                     # boot_peak_report — the three param_plan
                     # consumers must agree on what transforms.
-                    if _kind == "dense" and leaf_transform is not None:
+                    if _kind in MATRIX_KINDS and leaf_transform is not None:
                         w = leaf_transform(_logical, w)
                     return w
 
@@ -337,7 +337,7 @@ def boot_peak_report(
     ``tests/test_born_sharded.py`` and ``scripts/boot_smoke.py`` against
     the components reported here.
     """
-    from bcg_tpu.models.transformer import RANDOM_KINDS, param_plan
+    from bcg_tpu.models.transformer import MATRIX_KINDS, RANDOM_KINDS, param_plan
 
     transform = None
     if quantization is not None:
@@ -362,7 +362,7 @@ def boot_peak_report(
 
         def _make(w, _logical=logical, _kind=kind):
             w = w.astype(dtype)
-            if transform is not None and _kind == "dense":
+            if transform is not None and _kind in MATRIX_KINDS:
                 return transform(_logical, w)
             return w
 

@@ -55,21 +55,24 @@ from bcg_tpu.obs import tracer as obs_tracer
 QuantizedDense = Dict[str, jax.Array]
 DenseWeight = Union[jax.Array, QuantizedDense]
 
-# The block matmuls that quantize: the dense family's seven, and the five
-# projections of a hybrid's delta-rule layer (its two per-head gate
-# projections, 30 columns wide, its conv taps and its vectors stay as
-# they are).
-_QUANT_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-                 "lin_wq", "lin_wk", "lin_wv", "lin_wg", "lin_wo")
+# The block matmuls that quantize: the dense family's seven, a gated full
+# attention's output gate, the five projections of a hybrid's delta-rule
+# layer (its per-head gate projections, a KDA layer's low-rank decay and
+# output-gate pairs, its conv taps and its vectors stay as they are), and
+# the held experts' stacks ([E, in, out]: a scale per expert and output
+# channel).  An expert layer's router stays bfloat16.
+_QUANT_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "wg",
+                 "lin_wq", "lin_wk", "lin_wv", "lin_wg", "lin_wo",
+                 "moe_w_gate", "moe_w_up", "moe_w_down")
 
 INT4_GROUP = 128
 
 
 def _quantize_impl(w: jax.Array) -> QuantizedDense:
     w32 = w.astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(w32), axis=0)
+    absmax = jnp.max(jnp.abs(w32), axis=-2)     # over the contraction axis
     scale = jnp.maximum(absmax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(w32 / scale), -127, 127).astype(jnp.int8)
+    q = jnp.clip(jnp.round(w32 / scale[..., None, :]), -127, 127).astype(jnp.int8)
     return {"q": q, "scale": scale}
 
 
@@ -78,7 +81,8 @@ _quantize_preserving = jax.jit(_quantize_impl)
 
 
 def quantize_weight(w, consume: bool = False) -> QuantizedDense:
-    """[in, out] bf16/f32 -> int8 + per-output-channel f32 absmax scale.
+    """[in, out] bf16/f32 -> int8 + per-output-channel f32 absmax scale
+    (a stack [E, in, out]: a scale per expert and output channel).
 
     Jitted so the op chain fuses: run eagerly it materializes a full f32
     copy of the weight (2x bf16) — quantizing an 8B model's [D, V] head
@@ -105,19 +109,19 @@ def int4_group_for(in_dim: int, group: int = INT4_GROUP) -> int:
 
 def _quantize4_impl(w: jax.Array, group: int) -> QuantizedDense:
     w32 = w.astype(jnp.float32)
-    in_dim, out_dim = w32.shape
-    grouped = w32.reshape(in_dim // group, group, out_dim)
-    absmax = jnp.max(jnp.abs(grouped), axis=1)
+    *lead, in_dim, out_dim = w32.shape          # a stack [E, in, out]: per expert
+    grouped = w32.reshape(*lead, in_dim // group, group, out_dim)
+    absmax = jnp.max(jnp.abs(grouped), axis=-2)
     scale = jnp.maximum(absmax, 1e-12) / 7.0                  # [in/group, out]
     # Quantize against the bf16-ROUNDED scale (what dequant will read),
     # so the half-step error bound holds exactly.
     scale = scale.astype(jnp.bfloat16).astype(jnp.float32)
-    q = jnp.clip(jnp.round(grouped / scale[:, None, :]), -8, 7)
-    q = q.astype(jnp.int8).reshape(in_dim, out_dim)
+    q = jnp.clip(jnp.round(grouped / scale[..., None, :]), -8, 7)
+    q = q.astype(jnp.int8).reshape(*lead, in_dim, out_dim)
     half = in_dim // 2
     packed = jnp.bitwise_or(
-        jnp.bitwise_and(q[:half], jnp.int8(0x0F)),
-        jnp.left_shift(q[half:], 4),
+        jnp.bitwise_and(q[..., :half, :], jnp.int8(0x0F)),
+        jnp.left_shift(q[..., half:, :], 4),
     ).astype(jnp.int8)
     return {"q4": packed, "gscale": scale.astype(jnp.bfloat16)}
 
@@ -132,7 +136,7 @@ def quantize_weight_int4(w, consume: bool = False, group: int = INT4_GROUP) -> Q
     Same jit/donate discipline as :func:`quantize_weight` (eager absmax
     would materialize a full f32 copy during a 14B load)."""
     w = jnp.asarray(w)
-    g = int4_group_for(w.shape[0], group)
+    g = int4_group_for(w.shape[-2], group)
     fn = _quantize4_consuming if consume else _quantize4_preserving
     return fn(w, g)
 
@@ -336,7 +340,7 @@ def _sharded_quantizer(mode: str, spec: ModelSpec, mesh):
             if mode == "int8":
                 impl = _quantize_impl
             else:
-                impl = partial(_quantize4_impl, group=int4_group_for(w.shape[0]))
+                impl = partial(_quantize4_impl, group=int4_group_for(w.shape[-2]))
             out_struct = jax.eval_shape(impl, jax.ShapeDtypeStruct(w.shape, w.dtype))
             outs = {
                 sub: param_sharding(f"{logical}.{sub}", spec, mesh)

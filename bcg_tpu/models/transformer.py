@@ -21,7 +21,11 @@ states ``layer_types`` — a block per layer type from ``_HYBRID_BLOCKS``
 (full attention reusing ``_attend``; the gated delta rule of
 ``ops/gated_delta.py``), two kinds of per-row state in the cache (K/V
 for the full layers, recurrent state and conv tail for the linear
-ones), and a layer scan over the PERIODS of the pattern.
+ones), and a layer scan over the PERIODS of the pattern.  A hybrid's
+mixers are the full attention (with an output gate where the spec has
+one), the scalar-gated delta rule and the channel-gated one (KDA); its
+MLP half is the SwiGLU or routed experts with a shared one
+(``ops/moe.py``: the chip computes the experts it holds).
 
 Replaces the CUDA side of the reference's engine (vLLM internals behind
 ``vllm_agent.py:100-157``); no reference code exists at this layer.
@@ -36,7 +40,9 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from bcg_tpu.models.configs import FULL_ATTENTION, LINEAR_ATTENTION, ModelSpec
+from bcg_tpu.models.configs import (
+    FULL_ATTENTION, KDA, LINEAR_ATTENTION, RECURRENT_TYPES, ModelSpec,
+)
 from bcg_tpu.models.quantize import dense
 from bcg_tpu.obs import tracer as obs_tracer
 from bcg_tpu.ops import HybridImpl, impl_mesh, is_pallas
@@ -47,7 +53,9 @@ TransformerParams = Dict  # pytree: see init_params for the layout
 # ----------------------------------------------------------------- building
 
 # Leaf kinds that draw from a key (see init_leaf); the rest are constants.
-RANDOM_KINDS = ("dense", "gate", "conv", "a_log", "dt_bias")
+RANDOM_KINDS = ("dense", "attn_out", "gate", "conv", "a_log", "dt_bias")
+# The matrices a leaf transform (the int8 or int4 quantiser) takes.
+MATRIX_KINDS = ("dense", "attn_out")
 
 # A post-norm stack adds a unit-RMS vector to the residual stream per
 # sublayer and feeds it to the next layer un-normalised: at 32 layers its
@@ -70,6 +78,15 @@ _GATE_DAMP = 8.0
 # section 6, PR 29).
 _FULL_MIXER_NORM = 4.0
 _FINAL_NORM = 2.0
+# The same lesson in a pre-norm hybrid, whose sublayers add to the
+# residual stream un-normalised: softmax attention's answer, the
+# prompt's mean, is some twentieth of a delta-rule or expert sublayer's,
+# and carried a ninth of the logits' variance over a string's positions
+# (PERF.md section 6, Solar-Open2: probed at width 512, three seeds; nine
+# tenths in the dense family, 0.85 in the post-norm hybrid as set above).
+# A full-attention layer's output projection drawn 16 times larger brings
+# that share to 0.87; with the final norm vector at 2, as above.
+_ATTN_OUT_GAIN = 16.0
 _CONSTANT_KINDS = {"ones": 1.0, "zeros": 0.0, "full_mixer_norm": _FULL_MIXER_NORM,
                    "hybrid_final_norm": _FINAL_NORM}
 
@@ -138,10 +155,14 @@ def param_plan(spec: ModelSpec):
 
 def _hybrid_param_plan(spec: ModelSpec):
     """The plan of a spec with ``layer_types``: each layer's leaves by
-    its type.  Both kinds carry the two sublayer norms and the SwiGLU
-    half; a linear layer's mixer is the gated delta rule's (five
-    projections, the two per-head gates, the depthwise conv's taps,
-    ``A_log``, ``dt_bias`` and the per-head output norm)."""
+    its type.  Every kind carries the two sublayer norms and the MLP
+    half (the SwiGLU, or the router, the shared expert and the held
+    experts' stacks); a linear layer's mixer is the gated delta rule's
+    (five projections, the two per-head gates, the depthwise conv's
+    taps, ``A_log``, ``dt_bias`` and the per-head output norm), a KDA
+    layer's the channel-gated rule's (q, k, v, beta, the low-rank decay
+    and output-gate pairs, conv, ``A_log`` per head, ``dt_bias`` per
+    channel, the output norm and projection)."""
     D = spec.hidden_size
     plan = [
         ("embed", "dense", (spec.vocab_size, D)),
@@ -165,8 +186,22 @@ def _hybrid_param_plan(spec: ModelSpec):
                 (pre + "lin_out_norm", "ones", (spec.linear_value_head_dim,)),
                 dense_leaf("lin_wo"),
             ]
+        elif kind == KDA:
+            plan += [
+                dense_leaf("lin_wq"), dense_leaf("lin_wk"), dense_leaf("lin_wv"),
+                (pre + "lin_conv", "conv",
+                 (spec.linear_conv_kernel_dim, spec.linear_conv_size)),
+                dense_leaf("lin_wb"), dense_leaf("kda_fa"), dense_leaf("kda_fb"),
+                (pre + "lin_a_log", "a_log", (H,)),
+                (pre + "lin_dt_bias", "dt_bias", (spec.linear_key_size,)),
+                dense_leaf("kda_ga"), dense_leaf("kda_gb"),
+                (pre + "lin_out_norm", "ones", (spec.linear_value_head_dim,)),
+                dense_leaf("lin_wo"),
+            ]
         else:
-            plan += [dense_leaf(n) for n in ("wq", "wk", "wv", "wo")]
+            plan += [dense_leaf(n) for n in ("wq", "wk", "wv", "wg") if n in shapes]
+            plan.append((pre + "wo", "attn_out" if spec.norm_placement == "pre" else "dense",
+                         shapes["wo"]))
             if spec.qk_norm:
                 full = spec.qk_norm == "full"
                 plan += [
@@ -175,10 +210,14 @@ def _hybrid_param_plan(spec: ModelSpec):
                 ]
         plan += [
             (pre + "attn_norm",
-             "ones" if kind == LINEAR_ATTENTION else "full_mixer_norm", (D,)),
+             "full_mixer_norm" if kind == FULL_ATTENTION and spec.norm_placement == "post"
+             else "ones", (D,)),
             (pre + "mlp_norm", "ones", (D,)),
-            dense_leaf("w_gate"), dense_leaf("w_up"), dense_leaf("w_down"),
         ]
+        plan += [dense_leaf(n) for n in ("moe_router", "w_gate", "w_up", "w_down")
+                 if n in shapes]
+        plan += [(pre + n, "dense", (spec.experts_held, *io))
+                 for n, io in spec.expert_shapes().items()]
     if not spec.tie_embeddings:
         plan.append(("lm_head", "dense", (D, spec.vocab_size)))
     return plan
@@ -196,7 +235,10 @@ def plan_keys(spec: ModelSpec, key: jax.Array, plan=None) -> jax.Array:
 def init_leaf(kind: str, shape, key: jax.Array, dtype) -> jax.Array:
     """One leaf of the plan from its key (constants ignore it).
 
-    ``dense``: normal / sqrt(fan_in).  The delta-rule layer's own:
+    ``dense``: normal / sqrt(fan_in) (a stack of held experts ``[E, in,
+    out]``: each expert's own); ``attn_out`` (a pre-norm hybrid's
+    full-attention output projection) 16 times that.  The delta-rule
+    layer's own:
     ``gate`` (the two per-head gate projections) normal / (8
     sqrt(fan_in)); ``conv`` taps normal / 2; ``a_log`` = log U(1, 16) and
     ``dt_bias`` the inverse softplus of U(0.001, 0.1).  With the gates'
@@ -211,7 +253,11 @@ def init_leaf(kind: str, shape, key: jax.Array, dtype) -> jax.Array:
     reference's best, against 4.4 for int4 weights)."""
     if kind == "dense":
         return (
-            jax.random.normal(key, shape, jnp.float32) / math.sqrt(shape[0])
+            jax.random.normal(key, shape, jnp.float32) / math.sqrt(shape[-2])
+        ).astype(dtype)
+    if kind == "attn_out":
+        return (
+            jax.random.normal(key, shape, jnp.float32) * _ATTN_OUT_GAIN / math.sqrt(shape[-2])
         ).astype(dtype)
     if kind == "gate":
         return (
@@ -288,7 +334,7 @@ def init_params(
     def build(logical, kind, shape):
         w = init_leaf(
             kind, shape, next(keys) if kind in RANDOM_KINDS else None, dtype)
-        if kind == "dense" and leaf_transform:
+        if kind in MATRIX_KINDS and leaf_transform:
             return leaf_transform(logical, w)
         return w
 
@@ -403,7 +449,7 @@ def stack_layer_params(
 
     out = dict(params)
     kinds = [layer_kind(l) for l in layers]
-    if LINEAR_ATTENTION in kinds:
+    if set(kinds) & set(RECURRENT_TYPES):
         # A hybrid stacks BY TYPE, each type's layers in model order:
         # the scan walks periods and indexes each type's stack.
         if mesh is not None:
@@ -420,6 +466,8 @@ def stack_layer_params(
 
 def layer_kind(layer: Dict) -> str:
     """A layer's type, told from its leaves."""
+    if "kda_fa" in layer:
+        return KDA
     return LINEAR_ATTENTION if "lin_wq" in layer else FULL_ATTENTION
 
 
@@ -478,9 +526,9 @@ def rope_table(
 
 def _rope_for(spec: ModelSpec, positions: jax.Array):
     """cos/sin for a spec's rotary embedding, or ``(None, None)`` where
-    it has none (``rope_theta=None``): the blocks then skip
-    :func:`apply_rope`; nothing is fed a stand-in theta."""
-    if spec.rope_theta is None:
+    it has none (``rope_theta=None`` or ``use_rope=False``): the blocks
+    then skip :func:`apply_rope`; nothing is fed a stand-in theta."""
+    if spec.rope_theta is None or not spec.use_rope:
         return None, None
     return rope_table(positions, spec.head_dim, spec.rope_theta, spec.rope_scaling)
 
@@ -966,16 +1014,53 @@ def _norm_out(spec: ModelSpec, y, weight):
     return rms_norm(y, weight, spec.rms_eps) if spec.norm_placement == "post" else y
 
 
-def _mlp_sublayer(layer: Dict, spec: ModelSpec, x: jax.Array) -> jax.Array:
+def _mlp_sublayer(layer: Dict, spec: ModelSpec, x: jax.Array, ctx: _Ctx):
+    """The SwiGLU half of a hybrid layer.  Returns the new residual and
+    the held experts the step hit (none: it has no experts)."""
     y = _swiglu(layer, _norm_in(spec, x, layer["mlp_norm"]))
-    return x + _norm_out(spec, y, layer["mlp_norm"])
+    return x + _norm_out(spec, y, layer["mlp_norm"]), jnp.int32(0)
+
+
+def _moe_sublayer(layer: Dict, spec: ModelSpec, x: jax.Array, ctx: _Ctx):
+    """Routed experts with a shared one (``ops/moe.py``): the router
+    spans all ``num_experts``, the chip adds the part of the experts it
+    holds (``0 .. experts_held - 1``) and the shared expert's.  Pad
+    positions take no expert's work.  Returns the new residual and the
+    expert work's count: in a decode step the distinct held experts its
+    rows hit (what the decode kernel streams), in a prefill the (token,
+    held expert) assignments of its real tokens (what the grouped matmul
+    computes)."""
+    from bcg_tpu.ops import moe
+
+    B, T, D = x.shape
+    h = _norm_in(spec, x, layer["mlp_norm"])
+    flat = h.reshape(B * T, D)
+    routing = moe.route(flat, layer["moe_router"], spec.experts_per_token,
+                        spec.norm_topk_prob, spec.routed_scaling_factor)
+    decode = ctx.valid is None
+    experts = [layer[n] for n in ("moe_w_gate", "moe_w_up", "moe_w_down")]
+    li = experts[0].li if isinstance(experts[0], _Layer) else None
+    y = moe.routed_experts(
+        flat, routing, *(e.stack if isinstance(e, _Layer) else e for e in experts),
+        valid=None if decode else ctx.valid.reshape(-1), decode=decode,
+        impl=ctx.impl.moe, layer=li)
+    if spec.shared_experts:
+        y = y + _swiglu(layer, flat).astype(jnp.float32)
+    y = y.astype(x.dtype).reshape(B, T, D)
+    if decode:
+        count = moe.experts_hit(routing, 0, spec.experts_held)
+    else:
+        chosen = moe.held_weights(routing, 0, spec.experts_held)[1]
+        count = jnp.sum(chosen & ctx.valid.reshape(-1, 1)).astype(jnp.int32)
+    return x + _norm_out(spec, y, layer["mlp_norm"]), count
 
 
 def _block_full(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
                 ctx: _Ctx) -> Tuple[jax.Array, Dict]:
-    """A hybrid's full-attention layer: the dense family's attention
+    """A hybrid's full-attention mixer: the dense family's attention
     (:func:`_attend`: same cache entry, same kernels) under the spec's
-    norm placement, q/k norm form and rotary setting."""
+    norm placement, q/k norm form and rotary setting, its output gated
+    elementwise by ``sigmoid(x W_g)`` where the layer has ``wg``."""
     B, T, _ = x.shape
     h = _norm_in(spec, x, layer["attn_norm"])
     q, k, v = dense(h, layer["wq"]), dense(h, layer["wk"]), dense(h, layer["wv"])
@@ -995,32 +1080,24 @@ def _block_full(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
         spec, q, k, v, ctx.write_pos, entry, ctx.attn_mask, ctx.impl.attention,
         hist_len=ctx.hist_len,
     )
-    y = dense(attn_out.reshape(B, T, spec.q_size), layer["wo"])
-    x = x + _norm_out(spec, y, layer["attn_norm"])
-    return _mlp_sublayer(layer, spec, x), new_entry
+    attn_out = attn_out.reshape(B, T, spec.q_size)
+    if "wg" in layer:
+        attn_out = attn_out * jax.nn.sigmoid(dense(h, layer["wg"]))
+    y = dense(attn_out, layer["wo"])
+    return x + _norm_out(spec, y, layer["attn_norm"]), new_entry
 
 
-def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
-                       ctx: _Ctx) -> Tuple[jax.Array, Dict]:
-    """A hybrid's linear-attention layer: the gated delta rule
-    (``ops/gated_delta.py``).  Its cache entry is the recurrent state
-    ``S`` [B, H, dv, dk] float32 and the conv tail ``conv`` [B, K-1,
-    channels] (the last K-1 inputs of the depthwise causal conv).
-
-    A pad position (``ctx.valid`` False) leaves both as they were: its
-    conv input is zero (what the conv sees before a sequence starts,
-    and pads stand only before a row's tokens), its decay is 0 and its
-    write strength 0.  So a row's state is that of the row alone, and
-    a chunk of pads maps zeros to zeros."""
-    from bcg_tpu.ops.gated_delta import gated_delta_prefill, gated_delta_step
-
-    B, T, _ = x.shape
+def _short_conv(layer: Dict, spec: ModelSpec, h: jax.Array, entry: Dict, ctx: _Ctx):
+    """A delta-rule mixer's q, k, v: the three projections through the
+    depthwise causal conv of ``K`` taps and silu, q and k L2-normalised
+    per head and q scaled by ``dk^-1/2``.  A pad position's conv input
+    is zero.  Returns q, k [B, T, H, dk], v [B, T, H, dv] (float32) and
+    the conv's window (its last K - 1 inputs are the new tail)."""
+    B, T, _ = h.shape
     H = spec.linear_num_value_heads
     dk, dv = spec.linear_key_head_dim, spec.linear_value_head_dim
     K = spec.linear_conv_kernel_dim
     f32 = jnp.float32
-    h = _norm_in(spec, x, layer["attn_norm"])
-
     u = jnp.concatenate(
         [dense(h, layer["lin_wq"]), dense(h, layer["lin_wk"]),
          dense(h, layer["lin_wv"])], axis=-1)                     # [B, T, C]
@@ -1034,7 +1111,28 @@ def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
     q, k = q.reshape(B, T, H, dk), k.reshape(B, T, H, dk)
     v = v.reshape(B, T, H, dv)
     unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)  # noqa: E731
-    q, k = unit(q) * dk ** -0.5, unit(k)
+    return unit(q) * dk ** -0.5, unit(k), v, window
+
+
+def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
+                       ctx: _Ctx) -> Tuple[jax.Array, Dict]:
+    """A hybrid's linear-attention mixer: the gated delta rule
+    (``ops/gated_delta.py``).  Its cache entry is the recurrent state
+    ``S`` [B, H, dv, dk] float32 and the conv tail ``conv`` [B, K-1,
+    channels] (the last K-1 inputs of the depthwise causal conv).
+
+    A pad position (``ctx.valid`` False) leaves both as they were: its
+    conv input is zero (what the conv sees before a sequence starts,
+    and pads stand only before a row's tokens), its decay is 0 and its
+    write strength 0.  So a row's state is that of the row alone, and
+    a chunk of pads maps zeros to zeros."""
+    from bcg_tpu.ops.gated_delta import gated_delta_prefill, gated_delta_step
+
+    B, T, _ = x.shape
+    H, dv = spec.linear_num_value_heads, spec.linear_value_head_dim
+    f32 = jnp.float32
+    h = _norm_in(spec, x, layer["attn_norm"])
+    q, k, v, window = _short_conv(layer, spec, h, entry, ctx)
 
     gate = lambda w: jnp.dot(h, w, preferred_element_type=f32)    # noqa: E731  [B, T, H]
     beta = jax.nn.sigmoid(gate(layer["lin_wb"]))
@@ -1057,15 +1155,70 @@ def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
     o = rms_norm(o.astype(x.dtype), layer["lin_out_norm"], spec.rms_eps)
     o = o * jax.nn.silu(dense(h, layer["lin_wg"])).reshape(B, T, H, dv)
     y = dense(o.reshape(B, T, H * dv), layer["lin_wo"])
-    x = x + _norm_out(spec, y, layer["attn_norm"])
-    return _mlp_sublayer(layer, spec, x), new_entry
+    return x + _norm_out(spec, y, layer["attn_norm"]), new_entry
 
 
-# Layer type -> block.  A further mechanism adds a function and an entry.
+def _block_kda(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
+               ctx: _Ctx) -> Tuple[jax.Array, Dict]:
+    """A hybrid's KDA mixer: the channel-gated delta rule
+    (``ops/gated_delta.kda_prefill`` / ``kda_step``), its cache entry
+    and its pad handling as :func:`_block_gated_delta`'s.  The decay is a
+    vector over the key channels, ``g = -exp(A_log) softplus(f_b(f_a(x))
+    + dt_bias)``, the write strength ``beta = 2 sigmoid(x W_b)``, and the
+    output ``W_o(rmsnorm_head(o) * sigmoid(g_b(g_a(x))))``."""
+    from bcg_tpu.ops.gated_delta import kda_prefill, kda_step
+
+    B, T, _ = x.shape
+    H, dk, dv = (spec.linear_num_value_heads, spec.linear_key_head_dim,
+                 spec.linear_value_head_dim)
+    f32 = jnp.float32
+    h = _norm_in(spec, x, layer["attn_norm"])
+    q, k, v, window = _short_conv(layer, spec, h, entry, ctx)
+
+    def low_rank(a, b):       # [B, T, out] float32 through the rank-r pair
+        return jnp.dot(jnp.dot(h, a, preferred_element_type=f32), b.astype(f32))
+
+    beta = jax.nn.sigmoid(jnp.dot(h, layer["lin_wb"], preferred_element_type=f32))
+    if spec.linear_allow_neg_eigval:
+        beta = 2.0 * beta
+    g = jax.nn.softplus(low_rank(layer["kda_fa"], layer["kda_fb"])
+                        + layer["lin_dt_bias"].astype(f32)).reshape(B, T, H, dk)
+    g = -jnp.exp(layer["lin_a_log"].astype(f32))[:, None] * g
+    if ctx.valid is None:       # one decoded token: the recurrence itself
+        o, S = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], entry["S"])
+        o = o[:, None]
+    else:
+        g = jnp.where(ctx.valid[..., None, None], g, 0.0)
+        beta = jnp.where(ctx.valid[..., None], beta, 0.0)
+        o, S = kda_prefill(
+            q.astype(x.dtype), k.astype(x.dtype), v.astype(x.dtype), g, beta,
+            entry["S"], impl=ctx.impl.delta)
+    new_entry = {"S": S, "conv": window[:, T:]}
+
+    o = rms_norm(o.astype(x.dtype), layer["lin_out_norm"], spec.rms_eps)
+    gate = jax.nn.sigmoid(low_rank(layer["kda_ga"], layer["kda_gb"]))
+    o = o * gate.astype(x.dtype).reshape(B, T, H, dv)
+    y = dense(o.reshape(B, T, H * dv), layer["lin_wo"])
+    return x + _norm_out(spec, y, layer["attn_norm"]), new_entry
+
+
+# Layer type -> its mixer, and the MLP half -> its sublayer.  A further
+# mechanism adds a function and an entry.
 _HYBRID_BLOCKS = {
     FULL_ATTENTION: _block_full,
     LINEAR_ATTENTION: _block_gated_delta,
+    KDA: _block_kda,
+    "swiglu": _mlp_sublayer,
+    "moe": _moe_sublayer,
 }
+
+
+def _hybrid_layer(kind: str, layer: Dict, spec: ModelSpec, x, entry, ctx: _Ctx):
+    """One hybrid layer: its mixer, then its MLP half.  Returns the
+    residual, the mixer's new entry and the experts the step hit."""
+    x, entry = _HYBRID_BLOCKS[kind](layer, spec, x, entry, ctx)
+    x, hits = _HYBRID_BLOCKS["moe" if spec.moe else "swiglu"](layer, spec, x, ctx)
+    return x, entry, hits
 
 
 def _carried_entry(stack: Dict, li):
@@ -1090,6 +1243,18 @@ def _carry_with(stack: Dict, li, entry) -> Dict:
         stack, entry)
 
 
+def _layer_weights(stack: Dict, li) -> Dict:
+    """Layer ``li``'s weights from one type's stacks, for the scan body:
+    each leaf sliced where it is used (XLA fuses the slice into its
+    consumer), but a held-expert stack handed on whole with its index
+    (:class:`_Layer`): the expert kernels read their layer through their
+    index maps, where a slice would be copied out for them (630 MB a
+    layer and decode step at published widths)."""
+    take = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)  # noqa: E731
+    return {name: _Layer(leaf, li) if name.startswith("moe_w_") else jax.tree.map(take, leaf)
+            for name, leaf in stack.items()}
+
+
 def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
                        x: jax.Array, cache, ctx: _Ctx):
     """:func:`_run_layers` for a spec with ``layer_types``.  List form:
@@ -1098,20 +1263,25 @@ def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
     ``lax.scan`` over the PERIODS of the pattern, whose body applies the
     period's layers in order, so the program is O(1) in depth; both
     kinds of state ride the carry, indexed per type
-    (:func:`_carried_entry`: K/V in place, recurrent state whole)."""
+    (:func:`_carried_entry`: K/V in place, recurrent state whole).
+    Returns the residual, the new cache and the expert work's count
+    (:func:`_moe_sublayer`), summed over layers (0 without experts; only
+    a spec with experts carries it through the scan)."""
     layers = params["layers"]
+    hits = jnp.int32(0)
     if not isinstance(layers, dict):
         new_cache = []
         for layer, kind, entry in zip(layers, spec.layer_types, cache):
-            x, entry = _HYBRID_BLOCKS[kind](layer, spec, x, entry, ctx)
+            x, entry, hit = _hybrid_layer(kind, layer, spec, x, entry, ctx)
+            hits = hits + hit
             new_cache.append(entry)
-        return x, new_cache
+        return x, new_cache, hits
 
     period = spec.layer_period
     per = {kind: period.count(kind) for kind in dict.fromkeys(period)}
 
     def body(carry, p):
-        h, c = carry
+        h, c, *hits = carry
         seen = dict.fromkeys(per, 0)
         for kind in period:
             # the period's j-th layer of this kind is the type's stack's
@@ -1120,16 +1290,17 @@ def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
             # whole every iteration: 5 GB a decode step at 7B, measured)
             li = p * per[kind] + seen[kind]
             seen[kind] += 1
-            take = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)  # noqa: E731
-            h, entry = _HYBRID_BLOCKS[kind](
-                jax.tree.map(take, layers[kind]), spec, h,
+            h, entry, hit = _hybrid_layer(
+                kind, _layer_weights(layers[kind], li), spec, h,
                 _carried_entry(c[kind], li), ctx)
             c = {**c, kind: _carry_with(c[kind], li, entry)}
-        return (h, c), None
+            hits = [n + hit for n in hits]
+        return (h, c, *hits), None
 
-    (x, new_cache), _ = jax.lax.scan(
-        body, (x, cache), jnp.arange(spec.num_layers // len(period)))
-    return x, new_cache
+    (x, new_cache, *counted), _ = jax.lax.scan(
+        body, (x, cache, *([hits] if spec.moe else [])),
+        jnp.arange(spec.num_layers // len(period)))
+    return x, new_cache, (counted or [hits])[0]
 
 
 def _run_layers(
@@ -1146,6 +1317,7 @@ def _run_layers(
     ring=None,
     kv_valid=None,
     valid=None,
+    tally: bool = False,
 ):
     """Apply every decoder block: a Python loop for list-form params
     (each layer unrolled into the HLO — best when the program already
@@ -1160,17 +1332,22 @@ def _run_layers(
     A spec with ``layer_types`` goes through :func:`_run_layers_hybrid`
     (a block per layer type, two kinds of state); ``valid`` ([B, T],
     False on pads) is what its recurrent layers need of a prefill, and
-    the call forms it is not built for raise here, at trace time."""
+    the call forms it is not built for raise here, at trace time.  With
+    ``tally`` a hybrid's call returns a third value, the count of its
+    expert work (:func:`_run_layers_hybrid`)."""
     if spec.hybrid:
         if chunk or ring is not None or (valid is None and x.shape[1] > 1):
             raise NotImplementedError(
                 f"{spec.name}: a spec with layer_types runs prefill, "
                 "prefill_chunk_at and decode_step only (no decode chunk, "
                 "ring, cached-prefix or paged form carries recurrent state)")
-        if not isinstance(impl, HybridImpl):   # a plain name: the XLA twin
-            impl = HybridImpl(impl, "xla")
-        return _run_layers_hybrid(params, spec, x, cache, _Ctx(
+        if not isinstance(impl, HybridImpl):
+            # a plain name (a decode loop's): the delta rule's one-token
+            # step is XLA's, the expert kernels follow the attention's
+            impl = HybridImpl(impl, "xla", "pallas" if is_pallas(impl) else "xla")
+        x, cache, hits = _run_layers_hybrid(params, spec, x, cache, _Ctx(
             cos, sin, write_pos, attn_mask, hist_len, valid, impl))
+        return (x, cache, hits) if tally else (x, cache)
     layers = params["layers"]
     if isinstance(layers, dict):
         # The cache rides the scan CARRY, not xs/ys: ys would be a second
@@ -1301,7 +1478,7 @@ def init_kv_cache(
                             spec.linear_conv_size), dtype),
             }
 
-        make = {FULL_ATTENTION: entry, LINEAR_ATTENTION: linear}
+        make = {FULL_ATTENTION: entry, LINEAR_ATTENTION: linear, KDA: linear}
         if stacked:
             return {kind: make[kind](lead=(spec.layers_of(kind),))
                     for kind in dict.fromkeys(spec.layer_period)}
@@ -1556,7 +1733,9 @@ def prefill_chunk_at(
     impl: str = "xla",
     ring=None,                 # static (Mesh, axis_name): sp-sharded-cache
                                # chunked prefill (sp_chunk_decode_attention)
-) -> Tuple[jax.Array, Dict]:
+    tally: bool = False,       # static: also return the chunk's (token,
+                               # held expert) assignments over the layers
+):
     """One chunk of a chunked prefill with a DYNAMIC write position.
 
     Unlike :func:`prefill_with_prefix` (whose history width — and hence
@@ -1596,18 +1775,18 @@ def prefill_chunk_at(
         full_mask = jax.lax.dynamic_update_slice(
             full_mask, chunk_mask, (0, 0, write_pos)
         )
-        x, new_cache = _run_layers(
+        x, new_cache, *count = _run_layers(
             params, spec, x, cos, sin, write_pos, cache, full_mask, impl,
             chunk=True, ring=ring,
         )
     else:
         attn_mask = jnp.concatenate([hist_mask, chunk_mask], axis=2)
-        x, new_cache = _run_layers(
+        x, new_cache, *count = _run_layers(
             params, spec, x, cos, sin, write_pos, cache, attn_mask, impl,
-            hist_len=H, valid=valid,
+            hist_len=H, valid=valid, tally=tally,
         )
     logits = _logits(params, spec, x[:, -1:, :])[:, 0, :]
-    return logits, new_cache
+    return (logits, new_cache, *count)
 
 
 def decode_step(
@@ -1621,18 +1800,22 @@ def decode_step(
     impl: str = "xla",
     ring=None,                 # static (Mesh, axis_name): sp-sharded-cache
                                # decode (ops/ring_attention.sp_decode_attention)
-) -> Tuple[jax.Array, Dict]:
-    """One autoregressive step for the whole batch."""
+    tally: bool = False,       # static: also return the distinct held
+                               # experts the step's rows hit, over the layers
+):
+    """One autoregressive step for the whole batch: ``(logits, cache)``,
+    and with ``tally`` (a spec with routed experts) the step's count of
+    held experts hit as a third value."""
     B = token.shape[0]
     cos, sin = _rope_for(spec, seq_positions[:, None])
     x = params["embed"][token][:, None, :]  # [B, 1, D]
 
-    x, new_cache = _run_layers(
+    x, new_cache, *hits = _run_layers(
         params, spec, x, cos, sin, write_pos, cache,
-        _live_mask(valid_mask, cache, impl, ring), impl, ring=ring,
+        _live_mask(valid_mask, cache, impl, ring), impl, ring=ring, tally=tally,
     )
     logits = _logits(params, spec, x)[:, 0, :]
-    return logits, new_cache
+    return (logits, new_cache, *hits)
 
 
 def decode_chunk(

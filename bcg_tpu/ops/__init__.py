@@ -23,12 +23,14 @@ class PallasTP(NamedTuple):
 
 class HybridImpl(NamedTuple):
     """``impl`` marker of a model with two kinds of layer: what its
-    full-attention layers run (any marker above, or a plain name) and
-    what its gated delta-rule layers run (an ``ops.gated_delta``
-    marker).  Resolved by the engine at boot, like the rest."""
+    full-attention layers run (any marker above, or a plain name), what
+    its delta-rule layers run (an ``ops.gated_delta`` marker) and what
+    its routed experts run (an ``ops.moe`` marker).  Resolved by the
+    engine at boot, like the rest."""
 
     attention: object
     delta: str
+    moe: str = "xla"
 
 
 def is_pallas(impl) -> bool:

@@ -26,6 +26,25 @@ Two entry points, one per phase of serving:
   token, plain XLA (two passes over ``S``; see PERF.md for why it is not
   a kernel of its own).
 
+The CHANNEL-gated form (Kimi Delta Attention, Kimi Linear, arXiv
+2510.26692) decays each key channel by its own ``a_t = exp(g_t)``, a
+vector in R^dk per head and token::
+
+    S_t = S_{t-1} Diag(a_t) + beta_t (v_t - S_{t-1} Diag(a_t) k_t) k_t^T
+    o_t = S_t q_t
+
+(``S_t^T = (I - beta k k^T) Diag(a_t) S_{t-1}^T + beta k v^T`` in the
+paper's orientation).  :func:`kda_prefill` and :func:`kda_step` are its
+two entry points; the chunkwise form shares the triangular inverse and
+the walk over recurrence chunks (:func:`_pallas_walk`,
+:func:`_xla_walk`) with the scalar form, whose mathematics is untouched.
+Inside a chunk a channel's decay products ``exp(G_i - G_j)`` (``G`` the
+running sum of ``g``) are formed with exponents that are never positive:
+across sub-blocks of :data:`SUB` tokens through the first position of
+the later sub-block, inside one sub-block element by element.  A per-
+channel decay of ``g = -20`` a token thus loses nothing to overflow,
+where the scalar form's single running sum would need ``exp(+1280)``.
+
 A position with ``g = 0`` and ``beta = 0`` leaves the state as it was
 (``S_t = S_{t-1}``): that is how pad positions of a left-padded batch,
 and the positions the wrapper appends to fill the last recurrence chunk,
@@ -115,18 +134,22 @@ def _chunk_math(q, k, v, gcol, grow, bcol, S):
     return o, S_new
 
 
-def _kernel(q_ref, k_ref, v_ref, gcol_ref, grow_ref, b_ref, s0_ref,
-            o_ref, s_out_ref, s_scr, *, chunks):
+def _walk_kernel(*refs, math, chunks, n_seq):
+    """One grid step (row, head, chunk): the chunk's operands as float32
+    (a 4-D operand's block is ``[1, 1, CHUNK, w]``, a 5-D one's ``[1, 1,
+    1, 1, w]`` read as ``[1, w]``), the state from VMEM scratch."""
+    seq, s0_ref = refs[:n_seq], refs[n_seq]
+    o_ref, s_out_ref, s_scr = refs[n_seq + 1:]
     c = pl.program_id(2)
 
     @pl.when(c == 0)
     def _load():
         s_scr[...] = s0_ref[0, 0]
 
-    o, S_new = _chunk_math(
-        q_ref[0, 0].astype(jnp.float32), k_ref[0, 0].astype(jnp.float32),
-        v_ref[0, 0].astype(jnp.float32), gcol_ref[0, 0], grow_ref[0, 0, 0],
-        b_ref[0, 0], s_scr[...],
+    o, S_new = math(
+        *(r[0, 0, 0] if len(r.shape) == 5 else r[0, 0].astype(jnp.float32)
+          for r in seq),
+        s_scr[...],
     )
     o_ref[0, 0] = o.astype(o_ref.dtype)
     s_scr[...] = S_new
@@ -136,25 +159,29 @@ def _kernel(q_ref, k_ref, v_ref, gcol_ref, grow_ref, b_ref, s0_ref,
         s_out_ref[0, 0] = S_new
 
 
-def _pallas_prefill(q, k, v, gcol, grow, bcol, S0, interpret: bool):
-    """Head-major operands: q, k [B, H, T, dk]; v [B, H, T, dv]; gcol,
-    bcol [B, H, T, 1]; grow [B, H, T/C, 1, C]; S0 [B, H, dv, dk]."""
-    B, H, T, dk = q.shape
-    dv = v.shape[-1]
+def _pallas_walk(math, seq, S0, out_dtype, name: str, interpret: bool):
+    """One Pallas program per (row, head) that walks the recurrence
+    chunks with ``S`` in VMEM.  ``seq``: head-major per-position
+    operands ``[B, H, T, w]`` (a chunk's ``CHUNK`` rows each) or per-
+    chunk ones ``[B, H, T/C, 1, w]``; the first and third are q and v.
+    Returns o ``[B, H, T, dv]`` and the state after the last chunk."""
+    B, H, T, _ = seq[0].shape
+    dv, dk = S0.shape[-2:]
     n = T // CHUNK
-    seq = lambda d: pl.BlockSpec((1, 1, CHUNK, d), lambda b, h, c: (b, h, c, 0))  # noqa: E731
+
+    def spec(x):
+        if x.ndim == 5:
+            return pl.BlockSpec((1, 1, 1, 1, x.shape[-1]), lambda b, h, c: (b, h, c, 0, 0))
+        return pl.BlockSpec((1, 1, CHUNK, x.shape[-1]), lambda b, h, c: (b, h, c, 0))
+
     state = pl.BlockSpec((1, 1, dv, dk), lambda b, h, c: (b, h, 0, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, chunks=n),
+        functools.partial(_walk_kernel, math=math, chunks=n, n_seq=len(seq)),
         grid=(B, H, n),
-        in_specs=[
-            seq(dk), seq(dk), seq(dv), seq(1),
-            pl.BlockSpec((1, 1, 1, 1, CHUNK), lambda b, h, c: (b, h, c, 0, 0)),
-            seq(1), state,
-        ],
-        out_specs=[seq(dv), state],
+        in_specs=[*map(spec, seq), state],
+        out_specs=[pl.BlockSpec((1, 1, CHUNK, dv), lambda b, h, c: (b, h, c, 0)), state],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, T, dv), v.dtype),
+            jax.ShapeDtypeStruct((B, H, T, dv), out_dtype),
             jax.ShapeDtypeStruct((B, H, dv, dk), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
@@ -163,28 +190,37 @@ def _pallas_prefill(q, k, v, gcol, grow, bcol, S0, interpret: bool):
         ),
         interpret=interpret,
         # findable in the device trace beside the attention kernels
-        name="gated_delta_prefill",
-    )(q, k, v, gcol, grow, bcol, S0)
+        name=name,
+    )(*seq, S0)
 
 
-def _xla_prefill(q, k, v, gcol, grow, bcol, S0):
+def _xla_walk(math, seq, S0, out_dtype):
     """The same chunk mathematics with no kernel: a scan over the
     recurrence chunks, vmapped over rows and heads."""
-    B, H, T, _ = q.shape
+    B, H, T, _ = seq[0].shape
     n = T // CHUNK
 
-    def head(q, k, v, gcol, grow, bcol, S0):
-        cut = lambda x: x.reshape(n, CHUNK, x.shape[-1]).astype(jnp.float32)  # noqa: E731
+    def head(*xs):
+        *seq, S0 = xs
+        cut = lambda x: (x if x.ndim == 3 else  # noqa: E731
+                         x.reshape(n, CHUNK, x.shape[-1]).astype(jnp.float32))
 
         def step(S, xs):
-            o, S = _chunk_math(*xs, S)
+            o, S = math(*xs, S)
             return S, o
 
-        S, o = jax.lax.scan(
-            step, S0, (cut(q), cut(k), cut(v), cut(gcol), grow, cut(bcol)))
-        return o.reshape(T, -1).astype(v.dtype), S
+        S, o = jax.lax.scan(step, S0, tuple(map(cut, seq)))
+        return o.reshape(T, -1).astype(out_dtype), S
 
-    return jax.vmap(jax.vmap(head))(q, k, v, gcol, grow, bcol, S0)
+    return jax.vmap(jax.vmap(head))(*seq, S0)
+
+
+def _walk(math, seq, S0, out_dtype, impl: str, name: str):
+    if impl == XLA:
+        return _xla_walk(math, seq, S0, out_dtype)
+    if impl in (PALLAS, PALLAS_INTERPRET):
+        return _pallas_walk(math, seq, S0, out_dtype, name, impl == PALLAS_INTERPRET)
+    raise ValueError(f"{name}: unknown impl {impl!r}")
 
 
 def gated_delta_prefill(q, k, v, g, beta, S0, impl: str = XLA):
@@ -206,17 +242,12 @@ def gated_delta_prefill(q, k, v, g, beta, S0, impl: str = XLA):
     gsum = jnp.cumsum(
         g.astype(jnp.float32).transpose(0, 2, 1).reshape(B, H, Tp // CHUNK, CHUNK),
         axis=-1)
-    operands = (
+    seq = (
         heads(q), heads(k), heads(v), gsum.reshape(B, H, Tp, 1),
         gsum[:, :, :, None, :],
-        beta.astype(jnp.float32).transpose(0, 2, 1)[..., None], S0,
+        beta.astype(jnp.float32).transpose(0, 2, 1)[..., None],
     )
-    if impl == XLA:
-        o, S = _xla_prefill(*operands)
-    elif impl in (PALLAS, PALLAS_INTERPRET):
-        o, S = _pallas_prefill(*operands, interpret=impl == PALLAS_INTERPRET)
-    else:
-        raise ValueError(f"gated_delta_prefill: unknown impl {impl!r}")
+    o, S = _walk(_chunk_math, seq, S0, v.dtype, impl, "gated_delta_prefill")
     return o[:, :, :T].transpose(0, 2, 1, 3), S
 
 
@@ -226,6 +257,116 @@ def gated_delta_step(q, k, v, g, beta, S):
     and the new state."""
     q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     decayed = jnp.exp(g.astype(jnp.float32))[..., None, None] * S
+    u = beta.astype(jnp.float32)[..., None] * (
+        v - jnp.einsum("bhvk,bhk->bhv", decayed, k, precision=_PRECISION))
+    S = decayed + u[..., :, None] * k[..., None, :]
+    return jnp.einsum("bhvk,bhk->bhv", S, q, precision=_PRECISION), S
+
+
+# ------------------------------------------------ the channel-gated form
+
+# Tokens per sub-block of a recurrence chunk (KDA's secondary chunking):
+# pairs of positions in two sub-blocks meet through a matmul, pairs in
+# one sub-block element by element.
+SUB = 16
+
+
+def _row(x, i: int):
+    """Row ``i`` of ``x`` [n, w] as ``[1, w]``, by a masked sum (exact:
+    the other rows add zeros), which a kernel lowers at any offset."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.sum(jnp.where(rows == i, x, 0.0), axis=0, keepdims=True)
+
+
+def _kda_pairs(q, k, G, bcol):
+    """The chunk's two matrices of channel-decayed products, ``[C, C]``:
+    ``a[i, j] = beta_i sum_c k_i k_j exp(G_i - G_j)`` for ``j < i`` and
+    ``qk[i, j] = sum_c q_i k_j exp(G_i - G_j)`` for ``j <= i``; zero
+    elsewhere.  Every exponent is ``<= 0``."""
+    C = q.shape[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (SUB, C), 1)
+    a_rows, qk_rows = [], []
+    for lo in range(0, C, SUB):
+        qI, kI, GI = q[lo:lo + SUB], k[lo:lo + SUB], G[lo:lo + SUB]
+        row = lo + jax.lax.broadcasted_iota(jnp.int32, (SUB, C), 0)
+        if lo:
+            # earlier sub-blocks, through this sub-block's first position
+            ref = _row(GI, 0)
+            before = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) < lo
+            right = jnp.where(before, k * jnp.exp(jnp.minimum(ref - G, 0.0)), 0.0)
+            decay = jnp.exp(GI - ref)
+            a = _mm(kI * decay, right, ((1,), (1,)))
+            qk = _mm(qI * decay, right, ((1,), (1,)))
+        else:
+            a = qk = jnp.zeros((SUB, C), jnp.float32)
+        def own(j, mats, lo=lo, qI=qI, kI=kI, GI=GI, row=row):
+            # this sub-block's own position lo + j, element by element
+            a, qk = mats
+            kj = _row(kI, j) * jnp.exp(jnp.minimum(GI - _row(GI, j), 0.0))
+            at = col == lo + j
+            a = jnp.where(at & (row > lo + j), jnp.sum(kI * kj, axis=1, keepdims=True), a)
+            qk = jnp.where(at & (row >= lo + j), jnp.sum(qI * kj, axis=1, keepdims=True), qk)
+            return a, qk
+
+        a, qk = jax.lax.fori_loop(0, SUB, own, (a, qk))
+        a_rows.append(a)
+        qk_rows.append(qk)
+    return bcol * jnp.concatenate(a_rows, axis=0), jnp.concatenate(qk_rows, axis=0)
+
+
+def _kda_chunk_math(q, k, v, G, bcol, S):
+    """One recurrence chunk of one head of the channel-gated rule.  q, k
+    [C, dk]; v [C, dv]; G [C, dk] the INCLUSIVE running sum of g inside
+    the chunk; bcol [C, 1] beta; S [dv, dk] the state before the chunk.
+    With ``Gam_i = exp(G_i)`` (the decay from the chunk's start) the
+    pseudo-values ``u = (I + a)^-1 diag(beta) (v - (Gam * k) S^T)`` give
+    ``o_i = S (Gam_i * q_i) + sum_{j<=i} qk[i, j] u_j`` and the state
+    after the chunk ``S Diag(Gam_C) + sum_j u_j (k_j exp(G_C - G_j))^T``."""
+    C = q.shape[0]
+    a, qk = _kda_pairs(q, k, G, bcol)
+    t = _unit_lower_inverse(a)
+    gam = jnp.exp(G)                                      # [C, dk], <= 1
+    w = _mm(t, bcol * gam * k, ((1,), (0,)))              # [C, dk]
+    u = _mm(t, bcol * v, ((1,), (0,))) - _mm(w, S, ((1,), (1,)))      # [C, dv]
+    o = _mm(gam * q, S, ((1,), (1,))) + _mm(qk, u, ((1,), (0,)))
+    g_end = _row(G, C - 1)                                # [1, dk]
+    S_new = jnp.exp(g_end) * S + _mm(u, jnp.exp(g_end - G) * k, ((0,), (0,)))
+    return o, S_new
+
+
+def kda_prefill(q, k, v, g, beta, S0, impl: str = XLA):
+    """The channel-gated delta rule over T positions from the state
+    ``S0``.  q, k [B, T, H, dk] and v [B, T, H, dv] (q already scaled);
+    g [B, T, H, dk] float32, each channel's log decay (``<= 0``); beta [B,
+    T, H] float32; S0 [B, H, dv, dk] float32.  Returns o [B, T, H, dv] in
+    v's dtype and the state after position T - 1.  A position with ``g =
+    0`` and ``beta = 0`` leaves the state as it was.  The kernel is named
+    ``kda_prefill`` in the device trace."""
+    B, T, H, dk = q.shape
+    pad = (-T) % CHUNK
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    Tp = T + pad
+    heads = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    G = jnp.cumsum(
+        heads(g.astype(jnp.float32)).reshape(B, H, Tp // CHUNK, CHUNK, dk), axis=-2)
+    seq = (heads(q), heads(k), heads(v), G.reshape(B, H, Tp, dk),
+           beta.astype(jnp.float32).transpose(0, 2, 1)[..., None])
+    # float32 out of the kernel: its device-trace name (``kda_prefill.N
+    # custom-call f32[...]``) then takes no attention pattern of a
+    # configuration's file, whose outputs are bfloat16
+    o, S = _walk(_kda_chunk_math, seq, S0, jnp.float32, impl, "kda_prefill")
+    return o[:, :, :T].transpose(0, 2, 1, 3).astype(v.dtype), S
+
+
+def kda_step(q, k, v, g, beta, S):
+    """The channel-gated recurrence for one token.  q, k [B, H, dk]; v
+    [B, H, dv]; g [B, H, dk]; beta [B, H]; S [B, H, dv, dk] float32.
+    Returns o [B, H, dv] float32 and the new state."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    decayed = S * jnp.exp(g.astype(jnp.float32))[..., None, :]
     u = beta.astype(jnp.float32)[..., None] * (
         v - jnp.einsum("bhvk,bhk->bhv", decayed, k, precision=_PRECISION))
     S = decayed + u[..., :, None] * k[..., None, :]

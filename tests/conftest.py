@@ -40,12 +40,13 @@ def cell_engine_options():
     def options(name: str) -> dict:
         with open(os.path.join(configs, f"{name}.json")) as f:
             program = json.load(f)["program"]
-        hybrid = spec_for_model(program["model_name"]).hybrid
+        spec = spec_for_model(program["model_name"])
         return {
             **program["engine"],
             "prefill_chunk": 64,
             "model_name": (
-                "bcg-tpu/tiny-hybrid" if hybrid else "bcg-tpu/tiny-test"
+                "bcg-tpu/tiny-solar" if spec.moe
+                else "bcg-tpu/tiny-hybrid" if spec.hybrid else "bcg-tpu/tiny-test"
             ),
         }
 

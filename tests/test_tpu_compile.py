@@ -199,6 +199,49 @@ class TestOneChip:
             ((B, T, heads), jnp.float32), ((B, heads, dv, dk), jnp.float32),
         )
 
+    def test_kda_prefill_chunk(self, one):
+        # One 512-token prefill chunk of a KDA layer at Solar-Open2's
+        # widths: 64 heads of 128, a decay per key channel, float32 state.
+        from bcg_tpu.ops import gated_delta
+
+        heads, d, T = 64, 128, 512
+
+        def chunk(q, k, v, g, beta, S):
+            return gated_delta.kda_prefill(q, k, v, g, beta, S, impl=gated_delta.PALLAS)
+
+        text = _compile(
+            chunk, one,
+            ((B, T, heads, d), jnp.bfloat16), ((B, T, heads, d), jnp.bfloat16),
+            ((B, T, heads, d), jnp.bfloat16), ((B, T, heads, d), jnp.float32),
+            ((B, T, heads), jnp.float32), ((B, heads, d, d), jnp.float32),
+        ).as_text()
+        assert f"f32[{B},{heads},{T},{d}]" in text   # how trace_names finds it
+
+    @pytest.mark.parametrize("decode", [False, True], ids=["grouped", "decode"])
+    def test_expert_kernels(self, one, decode):
+        # The held experts of Solar-Open2's share, 40 of 320 (top 8),
+        # SwiGLU 4096 -> 1280 -> 4096 int8, read out of a 9-layer stack by
+        # the layer index: a 512-token chunk of 10 rows through the
+        # grouped matmul, or a decode step's 10 rows.
+        from bcg_tpu.ops import moe
+
+        layers, held, D, F, E, n = 9, 40, 4096, 1280, 320, B if decode else B * 512
+
+        def experts(h, router, gq, gs, uq, us, dq, ds, layer):
+            routing = moe.route(h, router, 8, True, 1.0)
+            return moe.routed_experts(
+                h, routing, {"q": gq, "scale": gs}, {"q": uq, "scale": us},
+                {"q": dq, "scale": ds}, decode=decode, impl=moe.PALLAS, layer=layer)
+
+        up = ((layers, held, D, F), jnp.int8), ((layers, held, F), jnp.float32)
+        text = _compile(
+            experts, one, ((n, D), jnp.bfloat16), ((D, E), jnp.bfloat16), *up, *up,
+            ((layers, held, F, D), jnp.int8), ((layers, held, D), jnp.float32),
+            ((), jnp.int32),
+        ).as_text()
+        # nothing the size of a layer's experts is produced on the way
+        assert f"s8[{held},{D},{F}]" not in text
+
     @pytest.mark.parametrize("top_p", [1.0, 0.9])
     def test_fused_sampler_qwen_vocab(self, one, top_p):
         from bcg_tpu.ops import guided_sampler as gs
