@@ -39,11 +39,13 @@ two entry points; the chunkwise form shares the triangular inverse and
 the walk over recurrence chunks (:func:`_pallas_walk`,
 :func:`_xla_walk`) with the scalar form, whose mathematics is untouched.
 Inside a chunk a channel's decay products ``exp(G_i - G_j)`` (``G`` the
-running sum of ``g``) are formed with exponents that are never positive:
-across sub-blocks of :data:`SUB` tokens through the first position of
-the later sub-block, inside one sub-block element by element.  A per-
-channel decay of ``g = -20`` a token thus loses nothing to overflow,
-where the scalar form's single running sum would need ``exp(+1280)``.
+running sum of ``g``) are formed with exponents that are never positive,
+by recursive halving of the chunk: a pair whose positions lie in the
+two halves of a block meets through the first position of the later
+half, one masked matmul a matrix and level, no step per position
+(:func:`_kda_pairs`).  A per-channel decay of ``g = -20`` a token (or
+-60) thus loses nothing to overflow, where the scalar form's single
+running sum would need ``exp(+1280)``.
 
 A position with ``g = 0`` and ``beta = 0`` leaves the state as it was
 (``S_t = S_{t-1}``): that is how pad positions of a left-padded batch,
@@ -265,11 +267,6 @@ def gated_delta_step(q, k, v, g, beta, S):
 
 # ------------------------------------------------ the channel-gated form
 
-# Tokens per sub-block of a recurrence chunk (KDA's secondary chunking):
-# pairs of positions in two sub-blocks meet through a matmul, pairs in
-# one sub-block element by element.
-SUB = 16
-
 
 def _row(x, i: int):
     """Row ``i`` of ``x`` [n, w] as ``[1, w]``, by a masked sum (exact:
@@ -282,36 +279,32 @@ def _kda_pairs(q, k, G, bcol):
     """The chunk's two matrices of channel-decayed products, ``[C, C]``:
     ``a[i, j] = beta_i sum_c k_i k_j exp(G_i - G_j)`` for ``j < i`` and
     ``qk[i, j] = sum_c q_i k_j exp(G_i - G_j)`` for ``j <= i``; zero
-    elsewhere.  Every exponent is ``<= 0``."""
-    C = q.shape[0]
-    col = jax.lax.broadcasted_iota(jnp.int32, (SUB, C), 1)
-    a_rows, qk_rows = [], []
-    for lo in range(0, C, SUB):
-        qI, kI, GI = q[lo:lo + SUB], k[lo:lo + SUB], G[lo:lo + SUB]
-        row = lo + jax.lax.broadcasted_iota(jnp.int32, (SUB, C), 0)
-        if lo:
-            # earlier sub-blocks, through this sub-block's first position
-            ref = _row(GI, 0)
-            before = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) < lo
-            right = jnp.where(before, k * jnp.exp(jnp.minimum(ref - G, 0.0)), 0.0)
-            decay = jnp.exp(GI - ref)
-            a = _mm(kI * decay, right, ((1,), (1,)))
-            qk = _mm(qI * decay, right, ((1,), (1,)))
-        else:
-            a = qk = jnp.zeros((SUB, C), jnp.float32)
-        def own(j, mats, lo=lo, qI=qI, kI=kI, GI=GI, row=row):
-            # this sub-block's own position lo + j, element by element
-            a, qk = mats
-            kj = _row(kI, j) * jnp.exp(jnp.minimum(GI - _row(GI, j), 0.0))
-            at = col == lo + j
-            a = jnp.where(at & (row > lo + j), jnp.sum(kI * kj, axis=1, keepdims=True), a)
-            qk = jnp.where(at & (row >= lo + j), jnp.sum(qI * kj, axis=1, keepdims=True), qk)
-            return a, qk
+    elsewhere.
 
-        a, qk = jax.lax.fori_loop(0, SUB, own, (a, qk))
-        a_rows.append(a)
-        qk_rows.append(qk)
-    return bcol * jnp.concatenate(a_rows, axis=0), jnp.concatenate(qk_rows, axis=0)
+    By recursive halving of the chunk, C a power of two: at level s (C/2,
+    ..., 2, 1) the pairs with ``i`` in the later half of a 2s-block and
+    ``j`` in its earlier half meet through the first position ``m`` of
+    that later half, in one matmul a matrix, ``(x_i exp(G_i - G_m)) (k_j
+    exp(G_m - G_j))^T`` with ``x = k`` for ``a`` and ``q`` for ``qk``;
+    every pair ``j < i`` is met at exactly one level.  ``G`` falls along
+    the chunk, so both exponents are ``-|G_p - G_m|``, never positive;
+    the products outside the level's pairs are finite and masked out."""
+    C = q.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    a = jnp.zeros((C, C), jnp.float32)
+    qk = jnp.where(row == col, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    s = C // 2
+    while s:
+        # every position's m at this level, gathered by a one-hot matmul
+        at_m = col == row // (2 * s) * (2 * s) + s
+        e = jnp.exp(-jnp.abs(G - _mm(jnp.where(at_m, 1.0, 0.0), G, ((1,), (0,)))))
+        ke = k * e
+        m = (row // (2 * s) == col // (2 * s)) & (row // s % 2 == 1) & (col // s % 2 == 0)
+        a = jnp.where(m, _mm(ke, ke, ((1,), (1,))), a)
+        qk = jnp.where(m, _mm(q * e, ke, ((1,), (1,))), qk)
+        s //= 2
+    return bcol * a, qk
 
 
 def _kda_chunk_math(q, k, v, G, bcol, S):

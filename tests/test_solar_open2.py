@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from bcg_tpu.models import transformer as T
 from bcg_tpu.models.configs import FULL_ATTENTION, KDA, MODEL_SPECS
@@ -193,6 +194,88 @@ class TestKernel:
         _, S = gated_delta.kda_prefill(q, k, v, jnp.zeros_like(g), jnp.zeros_like(beta),
                                        S0, gated_delta.XLA)
         np.testing.assert_array_equal(S, S0)
+
+    @pytest.mark.parametrize("impl", [gated_delta.PALLAS_INTERPRET, gated_delta.XLA])
+    def test_pairs_match_float64(self, impl):
+        """``_kda_pairs``' two matrices at the cell's chunk and width (C
+        64, dk 128) against the elementwise products in float64, from the
+        same float32 inputs: decays down to -20 a channel and token, write
+        strengths near 2, a stretch of pads."""
+        C, dk = gated_delta.CHUNK, 128
+        rng = np.random.default_rng(5)
+        q = rng.standard_normal((C, dk)) * dk ** -0.5
+        k = rng.standard_normal((C, dk)) + 1.0
+        k /= np.linalg.norm(k, axis=1, keepdims=True)
+        g = -np.exp(rng.uniform(-8.0, 3.0, (C, dk)))
+        beta = 2 / (1 + np.exp(-2 * rng.standard_normal((C, 1)) - 1))
+        g[20:30], beta[20:30] = 0.0, 0.0
+        assert g.min() < -19.0 and beta.max() > 1.99
+        q, k, G, beta = (np.float32(x) for x in (q, k, np.cumsum(g, axis=0), beta))
+
+        i, j = np.tril_indices(C)
+        decay = np.exp(G[i].astype(np.float64) - G[j])            # [pairs, dk]
+        want_a, want_qk = np.zeros((C, C)), np.zeros((C, C))
+        want_a[i, j] = beta[i, 0] * np.sum(k[i] * k[j].astype(np.float64) * decay, axis=1)
+        want_a[np.arange(C), np.arange(C)] = 0.0
+        want_qk[i, j] = np.sum(q[i] * k[j].astype(np.float64) * decay, axis=1)
+
+        if impl == gated_delta.XLA:
+            a, qk = jax.jit(gated_delta._kda_pairs)(q, k, G, beta)
+        else:
+            def kernel(q_ref, k_ref, G_ref, b_ref, a_ref, qk_ref):
+                a_ref[...], qk_ref[...] = gated_delta._kda_pairs(
+                    q_ref[...], k_ref[...], G_ref[...], b_ref[...])
+
+            a, qk = pl.pallas_call(
+                kernel, out_shape=[jax.ShapeDtypeStruct((C, C), jnp.float32)] * 2,
+                interpret=True)(q, k, G, beta)
+        # a few float32 units at the entries' size (|a| < 2, |qk| < 1)
+        np.testing.assert_allclose(a, want_a, rtol=0, atol=1e-6)
+        np.testing.assert_allclose(qk, want_qk, rtol=0, atol=1e-7)
+
+    @pytest.mark.parametrize("impl", [gated_delta.PALLAS_INTERPRET, gated_delta.XLA])
+    def test_extreme_decay_stays_finite(self, impl):
+        """Decays near -60 a token in three channels of four: across two
+        positions any positive exponent is exp(+120), past float32.  The
+        chunkwise form stays finite and still is the recurrence."""
+        Tn, H, dk, dv = 150, 2, 128, 128
+        ks = jax.random.split(jax.random.PRNGKey(1), 7)
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+        q = unit(jax.random.normal(ks[0], (1, Tn, H, dk))) * dk ** -0.5
+        k = unit(jax.random.normal(ks[1], (1, Tn, H, dk)) + 1.0)
+        v = jax.random.normal(ks[2], (1, Tn, H, dv))
+        steep = jax.random.uniform(ks[3], (1, Tn, H, dk), minval=-60.0, maxval=-50.0)
+        mild = -jnp.exp(jax.random.uniform(ks[6], (1, Tn, H, dk), minval=-8.0, maxval=0.0))
+        g = jnp.where(jnp.arange(dk) % 4 == 0, mild, steep)
+        beta = 2 * jax.nn.sigmoid(2 * jax.random.normal(ks[4], (1, Tn, H)) + 1)
+        pad = (jnp.arange(Tn) >= 70) & (jnp.arange(Tn) < 80)
+        g = jnp.where(pad[None, :, None, None], 0.0, g)
+        beta = jnp.where(pad[None, :, None], 0.0, beta)
+        S0 = jax.random.normal(ks[5], (1, H, dv, dk))
+
+        o, S = gated_delta.kda_prefill(q, k, v, g, beta, S0, impl=impl)
+        assert bool(jnp.isfinite(o).all() & jnp.isfinite(S).all())
+        o_ref, S_ref = reference.recurrence(q[0], k[0], v[0], g[0], beta[0], S0[0])
+        np.testing.assert_allclose(o[0], o_ref, atol=5e-5)
+        np.testing.assert_allclose(S[0], S_ref, atol=5e-5)
+
+    def test_chunk_math_walks_no_positions(self):
+        """The chunk's mathematics at the cell's [64, 128] is straight-line
+        code: no ``scan`` or ``while`` anywhere in its jaxpr, sub-jaxprs
+        included, so no step of it runs once per position."""
+        C, d = gated_delta.CHUNK, 128
+        shapes = [jax.ShapeDtypeStruct(s, jnp.float32)
+                  for s in ((C, d), (C, d), (C, d), (C, d), (C, 1), (d, d))]
+
+        def primitives(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn.primitive.name
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from primitives(sub)
+
+        found = set(primitives(jax.make_jaxpr(gated_delta._kda_chunk_math)(*shapes).jaxpr))
+        assert "dot_general" in found
+        assert not found & {"scan", "while"}
 
 
 class TestAgainstReference:
