@@ -54,6 +54,7 @@ from bcg_tpu.obs import (
     hlo as obs_hlo,
     hostsync as obs_hostsync,
     ledger as obs_ledger,
+    scopes as obs_scopes,
     tracer as obs_tracer,
 )
 from bcg_tpu.models.configs import (
@@ -2119,6 +2120,7 @@ class JaxEngine(InferenceEngine):
         # read back with the steps, so no further sync.
         tally = spec.moe
 
+        @jax.named_scope(obs_scopes.SAMPLE)
         def loop(params, cache, first_logits, valid_mask, prompt_lens, L,
                  tables, accepting, min_budget, dfa_ids, init_states,
                  row_temp, row_budget, rng):
@@ -2271,6 +2273,7 @@ class JaxEngine(InferenceEngine):
         eos_id = self.tokenizer.eos_id
         sampler = self._make_masked_sampler(eos_id, top_p, impl=sampler_impl)
 
+        @jax.named_scope(obs_scopes.SAMPLE)
         def loop(params, cache, first_logits, valid_mask, prompt_lens, L,
                  tables, accepting, min_budget, dfa_ids, init_states,
                  chain_tok, chain_len, chain_next,
@@ -2425,7 +2428,8 @@ class JaxEngine(InferenceEngine):
         # device memory (min of the two wins).  Off by default on TPU —
         # see EngineConfig.
         cap = self.config.max_num_seqs
-        derived = self._provisioned_row_cap(parts, budgets)
+        with obs_tracer.span("engine.provision"):
+            derived = self._provisioned_row_cap(parts, budgets)
         if derived is not None:
             cap = min(cap, derived) if cap else derived
         mult = self._dp_mult(cap)
@@ -2788,42 +2792,45 @@ class JaxEngine(InferenceEngine):
                 parts, batch, sig_prefix, real_B, temps, budgets, top_p
             )
         finally:
-            if self._paged is not None:
-                if self._paged_dirty:
-                    # A jit call raised AFTER donating the pool: the old
-                    # buffers are dead and the radix's resident blocks
-                    # with them — reallocate a zeroed pool so the engine
-                    # stays serviceable (working set re-prefills).
-                    self._paged_dirty = False
-                    self._paged_call_private = []
-                    self._paged.invalidate()
-                else:
-                    # Release this call's private (suffix/decode) blocks
-                    # and the refcount pins on its radix paths — shared
-                    # prefix blocks stay resident for the next round.
-                    self._paged.free(self._paged_call_private)
-                    self._paged_call_private = []
-                    self._paged.unpin_all()
-                # Publish the post-call pool snapshot (incl. the active
-                # impl) for consumers without an engine handle — the
-                # bench error path's forensics (runtime/metrics idiom,
-                # same as LAST_SERVE_STATS).
-                from bcg_tpu.runtime import metrics as _metrics
+            # Host work after the call's last device program: a span of
+            # its own, so the device's idle time here has an owner.
+            with obs_tracer.span("engine.release"):
+                if self._paged is not None:
+                    if self._paged_dirty:
+                        # A jit call raised AFTER donating the pool: the old
+                        # buffers are dead and the radix's resident blocks
+                        # with them — reallocate a zeroed pool so the engine
+                        # stays serviceable (working set re-prefills).
+                        self._paged_dirty = False
+                        self._paged_call_private = []
+                        self._paged.invalidate()
+                    else:
+                        # Release this call's private (suffix/decode) blocks
+                        # and the refcount pins on its radix paths — shared
+                        # prefix blocks stay resident for the next round.
+                        self._paged.free(self._paged_call_private)
+                        self._paged_call_private = []
+                        self._paged.unpin_all()
+                    # Publish the post-call pool snapshot (incl. the active
+                    # impl) for consumers without an engine handle — the
+                    # bench error path's forensics (runtime/metrics idiom,
+                    # same as LAST_SERVE_STATS).
+                    from bcg_tpu.runtime import metrics as _metrics
 
-                _metrics.publish_kv_pool(self.kv_pool_stats())
-            # Sampler self-description (impl, interpret, fused-kernel
-            # invocation count) — published per call like kv_pool so
-            # the bench ERROR path keeps the forensics of completed
-            # calls.
-            from bcg_tpu.runtime import metrics as _metrics2
+                    _metrics.publish_kv_pool(self.kv_pool_stats())
+                # Sampler self-description (impl, interpret, fused-kernel
+                # invocation count) — published per call like kv_pool so
+                # the bench ERROR path keeps the forensics of completed
+                # calls.
+                from bcg_tpu.runtime import metrics as _metrics2
 
-            _metrics2.publish_sampler(self.sampler_stats())
-            obs_ledger.credit("kv_cache", id(self))
-            obs_ledger.credit("spec_slots", id(self))
-            if self._mem_limit is not None:
-                # Real allocator present: publish the drift gauge
-                # (ledger vs bytes_in_use) each call — the leak alarm.
-                obs_ledger.reconcile()
+                _metrics2.publish_sampler(self.sampler_stats())
+                obs_ledger.credit("kv_cache", id(self))
+                obs_ledger.credit("spec_slots", id(self))
+                if self._mem_limit is not None:
+                    # Real allocator present: publish the drift gauge
+                    # (ledger vs bytes_in_use) each call — the leak alarm.
+                    obs_ledger.reconcile()
 
     def _decode_batch_impl(
         self, parts, batch, sig_prefix, real_B, temps, budgets,
@@ -3038,38 +3045,40 @@ class JaxEngine(InferenceEngine):
             )
         t1 = time.perf_counter()
 
-        self._key, sub = jax.random.split(self._key)
-        drafted = accepted = None
-        # HLO-census entry names: the paged loops lower different
-        # programs (block gather/scatter), so they pin under their own
-        # names instead of drifting the dense entries — and the fused
-        # Pallas loops under theirs, so the census can assert the
-        # kernel's step counts BELOW the gather baseline.  A fused-
-        # sampler engine likewise tags its EXECUTED loops "fused_" (on
-        # CPU that is the interpret-mode emulation — the hardware claim
-        # is carried by the tpu_fused_* cross-lowering twins below), so
-        # the dense xla-sampler baseline entries never drift.
-        if paged:
-            census_prefix = (
-                "paged_" if self._paged_loop_impl == "xla"
-                else "paged_pallas_"
-            )
-        else:
-            census_prefix = ""
-        if self._sampler_loop_impl != "xla":
-            census_prefix += "fused_"
-        # Host-sync attribution entry: the census name of the decode
-        # loop this call executes — what the auditor attributes the
-        # post-loop readbacks to when tracing is off.
-        loop_entry = census_prefix + (
-            "spec_decode_loop" if use_spec
-            else "ff_decode_loop" if use_ff
-            else "decode_loop"
-        )
-        if paged:
-            self._paged_dirty = True  # pool rides the donated loop call
+        # The span opens with the decode clock (t1): the key split and
+        # the loop's choice are host work the device waits on.
         with obs_tracer.span("engine.decode",
                              args={"rows": B, "max_new": max_new}):
+            self._key, sub = jax.random.split(self._key)
+            drafted = accepted = None
+            # HLO-census entry names: the paged loops lower different
+            # programs (block gather/scatter), so they pin under their own
+            # names instead of drifting the dense entries — and the fused
+            # Pallas loops under theirs, so the census can assert the
+            # kernel's step counts BELOW the gather baseline.  A fused-
+            # sampler engine likewise tags its EXECUTED loops "fused_" (on
+            # CPU that is the interpret-mode emulation — the hardware claim
+            # is carried by the tpu_fused_* cross-lowering twins below), so
+            # the dense xla-sampler baseline entries never drift.
+            if paged:
+                census_prefix = (
+                    "paged_" if self._paged_loop_impl == "xla"
+                    else "paged_pallas_"
+                )
+            else:
+                census_prefix = ""
+            if self._sampler_loop_impl != "xla":
+                census_prefix += "fused_"
+            # Host-sync attribution entry: the census name of the decode
+            # loop this call executes — what the auditor attributes the
+            # post-loop readbacks to when tracing is off.
+            loop_entry = census_prefix + (
+                "spec_decode_loop" if use_spec
+                else "ff_decode_loop" if use_ff
+                else "decode_loop"
+            )
+            if paged:
+                self._paged_dirty = True  # pool rides the donated loop call
             ring = (self.mesh, "sp") if self._sp_devices > 1 else None
             if use_spec:
                 loop = obs_hlo.wrap(
@@ -3207,54 +3216,58 @@ class JaxEngine(InferenceEngine):
             else:
                 steps = int(steps)
             obs_tracer.annotate(steps=steps)
+            # The loop's device arguments and output are spent: freed
+            # here, where a span holds the host's time, not when the
+            # frame returns.
+            del loop_args, out, first_logits, cache
         t2 = time.perf_counter()
-        if not self._first_call_recorded:
-            # Boot breakdown's final phase: the first serving call pays
-            # the first prefill + decode-loop compiles (plus one
-            # execute) — recorded so a compile-time OOM names itself.
-            self._boot.note("first_compile", t2 - t0)
-            self._first_call_recorded = True
-        self.last_decode_steps = steps
-        self.total_decode_steps += steps
-        if self._sampler_loop_impl != "xla":
-            # Fused-kernel invocations: one sampler program per loop
-            # iteration.  Keys created only when the kernel actually
-            # ran, so an xla-sampler engine's counter namespace stays
-            # byte-identical to HEAD's.
-            self._sampler_fused_calls += steps
-            obs_counters.inc("engine.sampler.fused_calls", steps)
-        if use_spec:
-            # Draft acceptance over REAL rows only (padding rows repeat
-            # row 0 and would inflate the rate).  Counted even when 0 —
-            # but keys are only created once something drafted, so a
-            # spec-off engine's counter namespace stays byte-identical
-            # to HEAD's.
-            obs_hostsync.note("spec_readback", n=2, entry=loop_entry)
-            spec_drafted = int(np.asarray(drafted)[:real_B].sum())
-            spec_accepted = int(np.asarray(accepted)[:real_B].sum())
-            if spec_drafted:
-                obs_counters.inc("engine.spec.drafted", spec_drafted)
-                obs_counters.inc("engine.spec.accepted", spec_accepted)
-                obs_counters.inc(
-                    "engine.spec.rejected", spec_drafted - spec_accepted
-                )
-        # Refresh LAST_HOSTSYNC once per generation call (no-op when
-        # the auditor is off) — a crash after this call keeps the sync
-        # profile in the bench error JSON.
-        obs_hostsync.publish()
-        # Perf accounting.  A decode step streams the cache slots of
-        # each row's LIVE blocks where the int8 kernel attends
-        # (_decode_kv_slots) and the whole ALLOCATED window elsewhere
-        # (the einsum paths read all S slots, masked), plus one full
-        # weight pass per loop iteration.
-        self.prefill_tokens += B * (L if (prepped is None and not paged) else Ls)
-        self.prefill_seconds += t1 - t0
-        self.decode_seconds += t2 - t1
-        self.decode_kv_bytes += (
-            self._decode_kv_slots(valid_mask, L, steps, use_spec or use_ff)
-            * self._kv_slot_bytes * self._kv_layers
-        )
-        self.decode_weight_passes += steps
+        # The call's accounting, between the decode loop's readback and
+        # the detokenizer: host work the device waits on.
+        with obs_tracer.span("engine.account"):
+            if not self._first_call_recorded:
+                # Boot breakdown's final phase: the first serving call pays
+                # the first prefill + decode-loop compiles (plus one
+                # execute) — recorded so a compile-time OOM names itself.
+                self._boot.note("first_compile", t2 - t0)
+                self._first_call_recorded = True
+            self.last_decode_steps = steps
+            self.total_decode_steps += steps
+            if self._sampler_loop_impl != "xla":
+                # Fused-kernel invocations (``sampler_stats``): one sampler
+                # program per loop iteration.
+                self._sampler_fused_calls += steps
+            if use_spec:
+                # Draft acceptance over REAL rows only (padding rows repeat
+                # row 0 and would inflate the rate).  Counted even when 0 —
+                # but keys are only created once something drafted, so a
+                # spec-off engine's counter namespace stays byte-identical
+                # to HEAD's.
+                obs_hostsync.note("spec_readback", n=2, entry=loop_entry)
+                spec_drafted = int(np.asarray(drafted)[:real_B].sum())
+                spec_accepted = int(np.asarray(accepted)[:real_B].sum())
+                if spec_drafted:
+                    obs_counters.inc("engine.spec.drafted", spec_drafted)
+                    obs_counters.inc("engine.spec.accepted", spec_accepted)
+                    obs_counters.inc(
+                        "engine.spec.rejected", spec_drafted - spec_accepted
+                    )
+            # Refresh LAST_HOSTSYNC once per generation call (no-op when
+            # the auditor is off) — a crash after this call keeps the sync
+            # profile in the bench error JSON.
+            obs_hostsync.publish()
+            # Perf accounting.  A decode step streams the cache slots of
+            # each row's LIVE blocks where the int8 kernel attends
+            # (_decode_kv_slots) and the whole ALLOCATED window elsewhere
+            # (the einsum paths read all S slots, masked), plus one full
+            # weight pass per loop iteration.
+            self.prefill_tokens += B * (L if (prepped is None and not paged) else Ls)
+            self.prefill_seconds += t1 - t0
+            self.decode_seconds += t2 - t1
+            self.decode_kv_bytes += (
+                self._decode_kv_slots(valid_mask, L, steps, use_spec or use_ff)
+                * self._kv_slot_bytes * self._kv_layers
+            )
+            self.decode_weight_passes += steps
         texts = []
         served = 0
         with obs_tracer.span("engine.detokenize", args={"rows": real_B}):
@@ -3625,19 +3638,20 @@ class JaxEngine(InferenceEngine):
         # ladder (serve dispatch recovery, orchestrator fallback).
         resilience.inject("engine.generate")
         parts = []
-        for system_prompt, user_prompt, _ in prompts:
-            if isinstance(user_prompt, tuple):
-                core, tail = user_prompt
-                parts.append(format_chat_parts3(
-                    self.config.model_name, system_prompt, core, tail,
-                    self.config.disable_qwen3_thinking,
-                ))
-            else:
-                prefix, suffix = format_chat_parts(
-                    self.config.model_name, system_prompt, user_prompt,
-                    self.config.disable_qwen3_thinking,
-                )
-                parts.append((prefix, "", suffix))
+        with obs_tracer.span("engine.format"):
+            for system_prompt, user_prompt, _ in prompts:
+                if isinstance(user_prompt, tuple):
+                    core, tail = user_prompt
+                    parts.append(format_chat_parts3(
+                        self.config.model_name, system_prompt, core, tail,
+                        self.config.disable_qwen3_thinking,
+                    ))
+                else:
+                    prefix, suffix = format_chat_parts(
+                        self.config.model_name, system_prompt, user_prompt,
+                        self.config.disable_qwen3_thinking,
+                    )
+                    parts.append((prefix, "", suffix))
         schemas = [schema for _, _, schema in prompts]
         try:
             texts = self._run_guided(parts, schemas, temperature, max_tokens)
@@ -3653,16 +3667,17 @@ class JaxEngine(InferenceEngine):
             self.failed_rows += len(prompts)
             return [{"error": "generation_failed", "message": str(e)} for _ in prompts]
         results = []
-        for text in texts:
-            try:
-                results.append(json.loads(text))
-            except json.JSONDecodeError:
-                salvaged = self.extract_json(text)
-                results.append(
-                    salvaged
-                    if salvaged is not None
-                    else {"error": "json_parse_failed", "raw": text[:200]}
-                )
+        with obs_tracer.span("engine.parse"):
+            for text in texts:
+                try:
+                    results.append(json.loads(text))
+                except json.JSONDecodeError:
+                    salvaged = self.extract_json(text)
+                    results.append(
+                        salvaged
+                        if salvaged is not None
+                        else {"error": "json_parse_failed", "raw": text[:200]}
+                    )
         self.total_rows += len(results)
         self.failed_rows += sum(
             1 for r in results if isinstance(r, dict) and "error" in r
@@ -3696,7 +3711,8 @@ class JaxEngine(InferenceEngine):
         temps = _per_row(temperature, n, float)
         budgets = _per_row(max_tokens, n, int)
         cap = self.config.max_num_seqs
-        derived = self._provisioned_row_cap(parts, budgets)
+        with obs_tracer.span("engine.provision"):
+            derived = self._provisioned_row_cap(parts, budgets)
         if derived is not None:
             cap = min(cap, derived) if cap else derived
         mult = self._dp_mult(cap)

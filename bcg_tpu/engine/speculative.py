@@ -47,6 +47,8 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from bcg_tpu.obs import scopes
+
 # Defaults for the registered env flags / EngineConfig fields.  K = 4
 # mirrors the forced-chain FF_CHUNK rationale (chunk MXU overhead vs
 # saved weight passes); N = 3 trigrams are specific enough that most
@@ -344,6 +346,7 @@ def build_spec_loop(
         sampler = make_masked_sampler(eos_id, top_p)
     K1 = k + 1
 
+    @jax.named_scope(scopes.SAMPLE)
     def loop(params, cache, first_logits, valid_mask, prompt_lens, L,
              tables, accepting, min_budget, dfa_ids, init_states,
              chain_tok, chain_len, hist,

@@ -14,6 +14,10 @@ TPU-first design choices:
 * The attention inner op is pluggable (``attention_impl``): the stock
   XLA path (einsum softmax einsum — XLA fuses it well on MXU) or the
   Pallas flash kernel in :mod:`bcg_tpu.ops.attention`.
+* Every operation is traced under the ``jax.named_scope`` of its part
+  (:mod:`bcg_tpu.obs.scopes`): a model call is ``bcg.head``, its layer
+  scan ``bcg.layers``, each sublayer ``bcg.attn``, ``bcg.linear_mixer``
+  or ``bcg.ffn``; the device trace reads time per part by them.
 
 Two block families share this file (``models/configs.py``): the dense
 one above (``_block``; every layer the same) and hybrids whose spec
@@ -44,6 +48,7 @@ from bcg_tpu.models.configs import (
     FULL_ATTENTION, KDA, LINEAR_ATTENTION, RECURRENT_TYPES, ModelSpec,
 )
 from bcg_tpu.models.quantize import dense
+from bcg_tpu.obs import scopes
 from bcg_tpu.obs import tracer as obs_tracer
 from bcg_tpu.ops import HybridImpl, impl_mesh, is_pallas
 
@@ -885,27 +890,29 @@ def _block(
     kv_valid=None,             # [B, T] bool, ring mode only (pads False)
 ) -> Tuple[jax.Array, Dict]:
     B, T, D = x.shape
-    h = rms_norm(x, layer["attn_norm"], spec.rms_eps)
-    q, k, v = dense(h, layer["wq"]), dense(h, layer["wk"]), dense(h, layer["wv"])
-    if "bq" in layer:  # Qwen2-style projection biases
-        q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-    q = q.reshape(B, T, spec.num_heads, spec.head_dim)
-    k = k.reshape(B, T, spec.num_kv_heads, spec.head_dim)
-    v = v.reshape(B, T, spec.num_kv_heads, spec.head_dim)
-    if spec.qk_norm:
-        q = rms_norm(q, layer["q_norm"], spec.rms_eps)
-        k = rms_norm(k, layer["k_norm"], spec.rms_eps)
-    if cos is not None:
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+    with jax.named_scope(scopes.ATTN):
+        h = rms_norm(x, layer["attn_norm"], spec.rms_eps)
+        q, k, v = dense(h, layer["wq"]), dense(h, layer["wk"]), dense(h, layer["wv"])
+        if "bq" in layer:  # Qwen2-style projection biases
+            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+        q = q.reshape(B, T, spec.num_heads, spec.head_dim)
+        k = k.reshape(B, T, spec.num_kv_heads, spec.head_dim)
+        v = v.reshape(B, T, spec.num_kv_heads, spec.head_dim)
+        if spec.qk_norm:
+            q = rms_norm(q, layer["q_norm"], spec.rms_eps)
+            k = rms_norm(k, layer["k_norm"], spec.rms_eps)
+        if cos is not None:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
-    attn_out, new_entry = _attend(
-        spec, q, k, v, kv_write_pos, cache_entry, attn_mask, impl,
-        hist_len=hist_len, ring=ring, kv_valid=kv_valid,
-    )
-    x = x + dense(attn_out.reshape(B, T, spec.q_size), layer["wo"])
+        attn_out, new_entry = _attend(
+            spec, q, k, v, kv_write_pos, cache_entry, attn_mask, impl,
+            hist_len=hist_len, ring=ring, kv_valid=kv_valid,
+        )
+        x = x + dense(attn_out.reshape(B, T, spec.q_size), layer["wo"])
 
-    x = x + _swiglu(layer, rms_norm(x, layer["mlp_norm"], spec.rms_eps))
+    with jax.named_scope(scopes.FFN):
+        x = x + _swiglu(layer, rms_norm(x, layer["mlp_norm"], spec.rms_eps))
     return x, new_entry
 
 
@@ -1014,6 +1021,7 @@ def _norm_out(spec: ModelSpec, y, weight):
     return rms_norm(y, weight, spec.rms_eps) if spec.norm_placement == "post" else y
 
 
+@jax.named_scope(scopes.FFN)
 def _mlp_sublayer(layer: Dict, spec: ModelSpec, x: jax.Array, ctx: _Ctx):
     """The SwiGLU half of a hybrid layer.  Returns the new residual and
     the held experts the step hit (none: it has no experts)."""
@@ -1021,6 +1029,7 @@ def _mlp_sublayer(layer: Dict, spec: ModelSpec, x: jax.Array, ctx: _Ctx):
     return x + _norm_out(spec, y, layer["mlp_norm"]), jnp.int32(0)
 
 
+@jax.named_scope(scopes.FFN)
 def _moe_sublayer(layer: Dict, spec: ModelSpec, x: jax.Array, ctx: _Ctx):
     """Routed experts with a shared one (``ops/moe.py``): the router
     spans all ``num_experts``, the chip adds the part of the experts it
@@ -1055,6 +1064,7 @@ def _moe_sublayer(layer: Dict, spec: ModelSpec, x: jax.Array, ctx: _Ctx):
     return x + _norm_out(spec, y, layer["mlp_norm"]), count
 
 
+@jax.named_scope(scopes.ATTN)
 def _block_full(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
                 ctx: _Ctx) -> Tuple[jax.Array, Dict]:
     """A hybrid's full-attention mixer: the dense family's attention
@@ -1114,6 +1124,7 @@ def _short_conv(layer: Dict, spec: ModelSpec, h: jax.Array, entry: Dict, ctx: _C
     return unit(q) * dk ** -0.5, unit(k), v, window
 
 
+@jax.named_scope(scopes.LINEAR_MIXER)
 def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
                        ctx: _Ctx) -> Tuple[jax.Array, Dict]:
     """A hybrid's linear-attention mixer: the gated delta rule
@@ -1158,6 +1169,7 @@ def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
     return x + _norm_out(spec, y, layer["attn_norm"]), new_entry
 
 
+@jax.named_scope(scopes.LINEAR_MIXER)
 def _block_kda(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
                ctx: _Ctx) -> Tuple[jax.Array, Dict]:
     """A hybrid's KDA mixer: the channel-gated delta rule
@@ -1290,10 +1302,16 @@ def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
             # whole every iteration: 5 GB a decode step at 7B, measured)
             li = p * per[kind] + seen[kind]
             seen[kind] += 1
+            # A recurrent layer's state leaves the carry and goes back
+            # whole: its mixer's traffic (the one-token update fuses into
+            # the write back), so its mixer's part.
+            part = scopes.LINEAR_MIXER if kind in RECURRENT_TYPES else scopes.LAYERS
+            with jax.named_scope(part):
+                entry = _carried_entry(c[kind], li)
             h, entry, hit = _hybrid_layer(
-                kind, _layer_weights(layers[kind], li), spec, h,
-                _carried_entry(c[kind], li), ctx)
-            c = {**c, kind: _carry_with(c[kind], li, entry)}
+                kind, _layer_weights(layers[kind], li), spec, h, entry, ctx)
+            with jax.named_scope(part):
+                c = {**c, kind: _carry_with(c[kind], li, entry)}
             hits = [n + hit for n in hits]
         return (h, c, *hits), None
 
@@ -1303,6 +1321,7 @@ def _run_layers_hybrid(params: TransformerParams, spec: ModelSpec,
     return x, new_cache, (counted or [hits])[0]
 
 
+@jax.named_scope(scopes.LAYERS)
 def _run_layers(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1503,6 +1522,7 @@ def cache_bytes(spec: ModelSpec, batch: int, max_len: int, **kw) -> Dict[str, in
     return out
 
 
+@jax.named_scope(scopes.HEAD)
 def prefill(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1535,6 +1555,7 @@ def prefill(
     return logits, new_cache
 
 
+@jax.named_scope(scopes.HEAD)
 def prefill_sp(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1581,6 +1602,7 @@ def prefill_sp(
     return logits, new_cache
 
 
+@jax.named_scope(scopes.HEAD)
 def prefill_with_prefix(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1618,6 +1640,7 @@ def prefill_with_prefix(
     return logits, new_cache
 
 
+@jax.named_scope(scopes.HEAD)
 def prefill_paged(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1664,6 +1687,7 @@ def prefill_paged(
     return logits, new_cache
 
 
+@jax.named_scope(scopes.HEAD)
 def prefill_paged_chunk_at(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1719,6 +1743,7 @@ def prefill_paged_chunk_at(
     return logits, new_cache
 
 
+@jax.named_scope(scopes.HEAD)
 def prefill_chunk_at(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1789,6 +1814,7 @@ def prefill_chunk_at(
     return (logits, new_cache, *count)
 
 
+@jax.named_scope(scopes.HEAD)
 def decode_step(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1818,6 +1844,7 @@ def decode_step(
     return (logits, new_cache, *hits)
 
 
+@jax.named_scope(scopes.HEAD)
 def decode_chunk(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1864,6 +1891,7 @@ def decode_chunk(
     return logits, new_cache
 
 
+@jax.named_scope(scopes.HEAD)
 def decode_chunk_spec(
     params: TransformerParams,
     spec: ModelSpec,
@@ -1929,6 +1957,18 @@ def _block_chunk(
     """Chunk decode block: write the fresh K positions into the cache,
     then attend over the WHOLE cache (prior context + the chunk itself,
     all selected by ``attn_mask``)."""
+    x, new_entry = _chunk_attention(layer, spec, x, cos, sin, write_pos,
+                                    cache_entry, attn_mask, impl, ring)
+    with jax.named_scope(scopes.FFN):
+        x = x + _swiglu(layer, rms_norm(x, layer["mlp_norm"], spec.rms_eps))
+    return x, new_entry
+
+
+@jax.named_scope(scopes.ATTN)
+def _chunk_attention(layer, spec, x, cos, sin, write_pos, cache_entry,
+                     attn_mask, impl, ring):
+    """:func:`_block_chunk`'s attention half (arguments as there):
+    the new residual and the updated entry."""
     B, K, D = x.shape
     h = rms_norm(x, layer["attn_norm"], spec.rms_eps)
     q, k, v = dense(h, layer["wq"]), dense(h, layer["wk"]), dense(h, layer["wv"])
@@ -1994,10 +2034,7 @@ def _block_chunk(
         attn_out = attention(
             q, ck, cv, attn_mask, scale, "xla" if quantized else impl
         )
-    x = x + dense(attn_out.reshape(B, K, spec.q_size), layer["wo"])
-
-    x = x + _swiglu(layer, rms_norm(x, layer["mlp_norm"], spec.rms_eps))
-    return x, new_entry
+    return x + dense(attn_out.reshape(B, K, spec.q_size), layer["wo"]), new_entry
 
 
 def param_count(params: TransformerParams) -> int:

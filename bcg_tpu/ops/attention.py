@@ -237,6 +237,7 @@ def _pallas_flash(q, k, v, mask, scale, block_q: int, block_kv: int,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_prefill",
     )(q, k, v, mask, blk_any)
 
 

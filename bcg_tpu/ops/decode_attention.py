@@ -335,6 +335,7 @@ def _quantized_attention(qg, live, layer, kp, vp, ksp, vsp, mp, scale,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_attention",
     )(live, layer, qg, kp, vp, ksp, vsp, mp)
 
 

@@ -228,6 +228,7 @@ def _sampler_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, _LANES), jnp.int32),
         interpret=interpret,
+        name="guided_sampler",
     )(dfa_ids, states, logits3, minb4, meta_i, meta_f)
 
 

@@ -92,6 +92,9 @@ class BCGSimulation:
     # one file (see run_round).
     _uid_counter = itertools.count(1)
 
+    # A span: a caller that builds a new game for each round it replays
+    # holds the device idle meanwhile.
+    @obs_tracer.spanned_once("game.setup")
     def __init__(
         self,
         config: Optional[BCGConfig] = None,
@@ -720,14 +723,15 @@ class BCGSimulation:
                     self.logger.log(f"  {aid}: received {len(proposals)} proposals, updated state")
 
         # 3.5 Round summaries + Q3 reasoning capture
-        self._update_round_summaries(round_num)
-        self.game.store_round_reasoning(
-            {
-                aid: agent.last_reasoning
-                for aid, agent in self.agents.items()
-                if agent.last_reasoning
-            }
-        )
+        with obs_tracer.span("round.summaries"):
+            self._update_round_summaries(round_num)
+            self.game.store_round_reasoning(
+                {
+                    aid: agent.last_reasoning
+                    for aid, agent in self.agents.items()
+                    if agent.last_reasoning
+                }
+            )
 
         # 4. Vote
         self.logger.log("[Voting Phase]")
@@ -740,33 +744,33 @@ class BCGSimulation:
                     vote = agent.vote_to_terminate(game_state)
                     agent_votes[aid] = vote
 
-        if self._recorder:
-            for aid, vote in agent_votes.items():
-                self._recorder.vote(
-                    round_num, aid, self.agents[aid].is_byzantine, vote
-                )
-
-        vote_info = self.game.get_all_termination_votes(agent_votes)
-        self.logger.log(
-            f"  All agents voting to stop: {vote_info['total_stop_votes']}/{vote_info['total_agents']}"
-        )
-
         # 5. Advance
-        self.game.advance_round(agent_votes)
-        self.network.advance_round()
-        self.network.end_round_gc(round_num)
-        self.profiler.count_round(num_decisions=2 * len(self.agents))
-        # Fleet liveness: each completed round advances this rank's
-        # progress watermark (no-op when fleet stamping is off).
-        obs_fleet.note_round()
-        if self._recorder:
-            # round_end reads the round advance_round just recorded;
-            # game_end here (not only in run()) covers external drivers
-            # (serve.run_serving_simulations, resume) that call
-            # run_round directly — it is idempotent.
-            self._recorder.round_end(round_num, self.game)
-            if self.game.game_over:
-                self._recorder.game_end(self.game)
+        with obs_tracer.span("round.advance"):
+            if self._recorder:
+                for aid, vote in agent_votes.items():
+                    self._recorder.vote(
+                        round_num, aid, self.agents[aid].is_byzantine, vote
+                    )
+
+            vote_info = self.game.get_all_termination_votes(agent_votes)
+            self.logger.log(
+                f"  All agents voting to stop: {vote_info['total_stop_votes']}/{vote_info['total_agents']}"
+            )
+            self.game.advance_round(agent_votes)
+            self.network.advance_round()
+            self.network.end_round_gc(round_num)
+            self.profiler.count_round(num_decisions=2 * len(self.agents))
+            # Fleet liveness: each completed round advances this rank's
+            # progress watermark (no-op when fleet stamping is off).
+            obs_fleet.note_round()
+            if self._recorder:
+                # round_end reads the round advance_round just recorded;
+                # game_end here (not only in run()) covers external callers
+                # (serve.run_serving_simulations, resume) that call
+                # run_round directly — it is idempotent.
+                self._recorder.round_end(round_num, self.game)
+                if self.game.game_over:
+                    self._recorder.game_end(self.game)
 
         # Per-round checkpoints (--checkpoint-every-round) ride the
         # save_results sinks; BCG_TPU_SERVE_CHECKPOINT_EVERY=N

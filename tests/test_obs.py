@@ -424,7 +424,8 @@ class TestAcceptanceTrace:
     REQUIRED = {
         "round", "decide", "vote", "serve.request", "serve.queue_wait",
         "serve.batch_form", "serve.device", "serve.scatter",
-        "engine.prefill", "engine.decode",
+        "engine.prefill", "engine.decode", "round.summaries", "round.advance",
+        "game.setup",
     }
 
     def _run_games(self):
@@ -458,7 +459,7 @@ class TestAcceptanceTrace:
             return by_id[pid]["name"] if pid in by_id else None
 
         for ev in spans.values():
-            if ev["name"] == "decide":
+            if ev["name"] in ("decide", "round.summaries", "round.advance"):
                 assert parent_name(ev) == "round"
             if ev["name"] == "serve.queue_wait":
                 # Cross-thread handoff: the X event on the scheduler
@@ -480,6 +481,7 @@ class TestAcceptanceTrace:
         # summarize(): per-name latency table over the run.
         table = traced.summarize()
         assert table["round"]["count"] == 4  # 2 games x 2 rounds
+        assert table["game.setup"]["count"] == 2  # once a game, before its rounds
         assert {"count", "total_ms", "mean_ms", "p50_ms", "p95_ms"} == set(
             table["round"]
         )
@@ -745,14 +747,17 @@ class TestEngineSpans:
         events = traced.events()[first:]
         begins = [e for e in events if e[0] == "B"]
         assert [e[1] for e in begins] == [
-            "engine.call", "engine.guides", "engine.prefill",
-            "engine.tokenize", "engine.decode", "engine.detokenize",
+            "engine.call", "engine.format", "engine.provision",
+            "engine.guides", "engine.prefill", "engine.tokenize",
+            "engine.decode", "engine.account", "engine.detokenize",
+            "engine.release", "engine.parse",
         ]
         ids = {e[1]: e[4] for e in begins}
         parents = {e[1]: e[5] for e in begins}
         assert parents["engine.tokenize"] == ids["engine.prefill"]
-        for child in ("engine.guides", "engine.prefill", "engine.decode",
-                      "engine.detokenize"):
+        for child in ("engine.format", "engine.provision", "engine.guides",
+                      "engine.prefill", "engine.decode", "engine.account",
+                      "engine.detokenize", "engine.release", "engine.parse"):
             assert parents[child] == ids["engine.call"], child
         assert begins[0][6] == {"rows": 3, "max_tokens": 48}
         ends = {e[1]: e[6] for e in events if e[0] == "E"}
@@ -769,6 +774,31 @@ class TestEngineSpans:
             assert ends["engine.prefill"]["chunks"] == 1
         assert ends["engine.prefill"]["prompt_window"] > 0
         assert ends["engine.prefill"]["cache_len"] > 0
+
+    def test_untraced_call_opens_only_the_shared_noop(self, untraced,
+                                                      monkeypatch):
+        """Tracing off, every span of an engine call, the ones that own
+        the call's host gaps included, is the shared no-op: no event,
+        no annotation, no per-site branch."""
+        opened = []
+        span = obs_tracer.span
+
+        def counting(name, *args, **kwargs):
+            got = span(name, *args, **kwargs)
+            opened.append((name, got))
+            return got
+
+        engine = _tiny_engine(prefix_caching=False)
+        try:
+            monkeypatch.setattr(obs_tracer, "span", counting)
+            engine.batch_generate_json(_ROWS, temperature=0.0, max_tokens=8)
+        finally:
+            engine.shutdown()
+        assert {"engine.call", "engine.format", "engine.provision",
+                "engine.account", "engine.release", "engine.parse"} <= {
+                    n for n, _ in opened}
+        assert all(got is obs_tracer._NULL_SPAN for _, got in opened)
+        assert obs_tracer.get_tracer() is None
 
     @pytest.mark.parametrize("options", [
         {"decode_fast_forward": False}, {"decode_fast_forward": True},

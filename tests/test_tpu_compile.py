@@ -17,6 +17,7 @@ import os
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else compiler logs land in /tmp
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -286,6 +287,141 @@ class TestOneChip:
             ((B, nblk * bs), jnp.bool_),
             *[(pool[k].shape, pool[k].dtype) for k in sorted(pool)],
         )
+
+
+# A Pallas call's line of a compiled program: its instruction name.
+_CUSTOM_CALL = re.compile(
+    r'^\s*(?:ROOT\s+)?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"')
+
+
+def _kernels(text: str) -> dict:
+    """``{kernel name: [op_name, ...]}`` of a compiled program's Pallas
+    calls (the instruction name less its ``.N``)."""
+    out = {}
+    for line in text.splitlines():
+        m = _CUSTOM_CALL.match(line)
+        if m:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            out.setdefault(m.group(1).split(".")[0], []).append(
+                op_name and op_name.group(1))
+    return out
+
+
+class TestKernelParts:
+    """The device trace names each kernel by its HLO instruction, and
+    reads its model part from the op_name stack (bcg_tpu/obs/scopes.py):
+    every kernel on the cells' path has a name of its own, which the
+    configurations' shape-keyed ``trace_names`` still match, and sits
+    under its own part when the model calls it."""
+
+    @pytest.fixture
+    def one(self, topo):
+        return SingleDeviceSharding(topo.devices[0])
+
+    @pytest.mark.parametrize("kernel", ["flash_prefill", "decode_attention", "guided_sampler"])
+    def test_named_kernels_keep_their_trace_patterns(self, one, kernel):
+        import json
+        import sys
+
+        from bcg_tpu.ops import guided_sampler as gs
+
+        bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                             "benchmark")
+        sys.path.insert(0, bench)
+        from lib import trace
+
+        if kernel == "flash_prefill":
+            T, S = 512, 3072 + 512
+            text = _compile(
+                _flash, one,
+                ((B, T, H, DH), jnp.bfloat16), ((B, S, HKV, DH), jnp.bfloat16),
+                ((B, S, HKV, DH), jnp.bfloat16), ((B, T, S), jnp.bool_)).as_text()
+        elif kernel == "decode_attention":
+            text = _compile(
+                _decode_int8, one, *_int8_cache_shapes(((B, H, DH), jnp.bfloat16)),
+                ((B, S_CACHE), jnp.bool_)).as_text()
+        else:
+            _, vs = gs.vocab_rows(VOCAB)
+            text = _compile(
+                functools.partial(gs._sampler_call, eos_id=151_645, top_p=1.0,
+                                  vocab=VOCAB, interpret=False), one,
+                ((B, vs, 128), jnp.float32), ((2, 64, vs, 128), jnp.int16),
+                ((B, 1, 4), jnp.int32), ((B, 1, 2), jnp.float32),
+                ((B,), jnp.int32), ((B,), jnp.int32)).as_text()
+        assert list(_kernels(text)) == [kernel]
+        line = next(re.sub(r"^ROOT\s+", "", ln.strip()) for ln in text.splitlines()
+                    if re.match(rf"\s*(ROOT\s+)?%{kernel}(\.\d+)? = ", ln))
+        event = trace.op_name(line)
+        assert event.startswith(kernel)
+        for name in ("qwen3-8b-int8", "olmo-hybrid-7b-int8", "solar-open2-250b-int8"):
+            with open(os.path.join(bench, "configs", name + ".json")) as f:
+                patterns = json.load(f)["trace_names"]
+            found = [k for k in ("flash_prefill", "decode_attention")
+                     if re.search(patterns[k], event)]
+            assert found == ([] if kernel == "guided_sampler" else [kernel]), (name, event)
+
+    # (preset, layer index, phase) -> the kernels the layer calls
+    CASES = {
+        ("bcg-tpu/tiny-test", 0, "prefill"): {"flash_prefill"},
+        ("bcg-tpu/tiny-test", 0, "decode"): {"decode_attention"},
+        ("bcg-tpu/tiny-hybrid", 0, "prefill"): {"gated_delta_prefill"},
+        ("bcg-tpu/tiny-hybrid", 3, "decode"): {"decode_attention"},
+        ("bcg-tpu/tiny-solar", 0, "prefill"): {"flash_prefill", "moe_prefill"},
+        ("bcg-tpu/tiny-solar", 1, "prefill"): {"kda_prefill", "moe_prefill"},
+        ("bcg-tpu/tiny-solar", 0, "decode"): {"decode_attention", "moe_decode"},
+    }
+    PART = {"flash_prefill": "bcg.attn", "decode_attention": "bcg.attn",
+            "gated_delta_prefill": "bcg.linear_mixer", "kda_prefill": "bcg.linear_mixer",
+            "moe_prefill": "bcg.ffn", "moe_decode": "bcg.ffn"}
+
+    @pytest.mark.parametrize("case", list(CASES), ids=lambda c: f"{c[0][8:]}-{c[1]}-{c[2]}")
+    def test_each_kernel_sits_under_its_part(self, one, case):
+        """One layer of the tiny preset, its heads widened to the 128
+        lanes the kernels tile, W8A8 with an int8 cache, compiled as the
+        chunk program or the decode loop calls it with the Pallas
+        kernels."""
+        import dataclasses
+
+        from bcg_tpu.models import transformer as T
+        from bcg_tpu.models.configs import MODEL_SPECS
+        from bcg_tpu.models.quantize import quantize_leaf_transform
+        from bcg_tpu.obs import scopes
+        from bcg_tpu.ops import HybridImpl, gated_delta, moe
+
+        preset, li, phase = case
+        spec = dataclasses.replace(
+            MODEL_SPECS[preset], head_dim=128, use_rope=False,
+            **({"linear_key_head_dim": 128, "linear_value_head_dim": 128}
+               if MODEL_SPECS[preset].hybrid else {}))
+        rows, Tq, hist = 2, (1 if phase == "decode" else 128), 128
+        S = hist + 128
+        params = jax.eval_shape(lambda: T.init_params(
+            spec, jax.random.PRNGKey(0), leaf_transform=quantize_leaf_transform(spec, "int8")))
+        cache = jax.eval_shape(lambda: T.init_kv_cache(spec, rows, S, quantized="int8"))
+        layer, entry = params["layers"][li], cache[li]
+        mask_shape = (rows, S) if phase == "decode" else (rows, Tq, hist + Tq)
+        impl = (HybridImpl("pallas", "xla" if phase == "decode" else gated_delta.PALLAS,
+                           moe.PALLAS) if spec.hybrid else "pallas")
+
+        def run(layer, x, entry, mask, valid):
+            if not spec.hybrid:
+                return T._block(layer, spec, x, None, None, jnp.int32(hist), entry,
+                                mask, impl, hist_len=0 if phase == "decode" else hist)
+            ctx = T._Ctx(None, None, jnp.int32(hist), mask, 0 if phase == "decode" else hist,
+                         None if phase == "decode" else valid, impl)
+            return T._hybrid_layer(spec.layer_types[li], layer, spec, x, entry, ctx)
+
+        place = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+        text = jax.jit(run).lower(
+            place(layer), place(jax.ShapeDtypeStruct((rows, Tq, spec.hidden_size), jnp.bfloat16)),
+            place(entry), place(jax.ShapeDtypeStruct(mask_shape, jnp.bool_)),
+            place(jax.ShapeDtypeStruct((rows, Tq), jnp.bool_)),
+        ).compile().as_text()
+        kernels = _kernels(text)
+        assert set(kernels) == self.CASES[case]
+        for kernel, op_names in kernels.items():
+            assert {scopes.part(n or "") for n in op_names} == {self.PART[kernel]}, kernel
 
 
 class _Spec:
