@@ -16,9 +16,18 @@ FlashAttention/xFORMERS CUDA paged kernels for the same reason,
   the range (a fast-forward gap, the pad in the first live block) are
   masked in the body.  A wholly masked block leaves the online softmax
   as it was, so the output is that of the unbounded grid, bit for bit;
-* optionally reads **int8** K/V with per-(position, kv-head) scales and
-  dequantizes in VMEM — halving the dominant HBM traffic with no
-  full-precision cache copy ever materialized;
+* optionally reads **int8** K/V with per-(position, kv-head) scales —
+  halving the dominant HBM traffic with no full-precision cache copy
+  ever materialized.  The int8 blocks go to the matmul dtype unscaled
+  (exact: |x| <= 127) and a slot's scales are applied where they cost a
+  ``[rows, Sblk]`` multiply: the K scale to its score
+  (``q . s k = s (q . k)``), the V scale to its softmax weight before
+  the PV matmul (``sum p s v = sum (p s) v``), never to the
+  denominator.  Each scale is read as the ``[1, Sblk]`` lane row its
+  block already is.  Dequantising every element instead (int8 ->
+  float32, times the scale relaid from lanes to a ``[Sblk, 1]`` column,
+  -> bfloat16) cost the body several VPU passes over each K and V
+  block, some four times the block's DMA;
 * is GQA-native: grid over (batch, kv-head), each program computing all
   ``group`` query heads of that kv head at once (an [group, Dh] MXU tile
   instead of ``group`` separate vector products).
@@ -228,14 +237,16 @@ def _decode_kernel_allheads(
         maskf = mask.astype(jnp.float32)
         for h in range(hkv):
             q = q_ref[0, h]                  # [rows, Dh]
-            k = k_ref[0, h].astype(jnp.float32) * ks_ref[0, h][:, None]
-            v = v_ref[0, h].astype(jnp.float32) * vs_ref[0, h][:, None]
-            k = k.astype(q.dtype)
-            v = v.astype(q.dtype)
+            # Unscaled: |x| <= 127 is exact in bfloat16.  The scales ride
+            # the scores and the weights (module docstring).
+            k = k_ref[0, h].astype(q.dtype)  # [Sblk, Dh]
+            v = v_ref[0, h].astype(q.dtype)
+            ks = ks_ref[0, pl.ds(h, 1), :]   # [1, Sblk]
+            vs = vs_ref[0, pl.ds(h, 1), :]
             scores = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale                        # [rows, Sblk]
+            ) * (scale * ks)                 # [rows, Sblk]
             scores = jnp.where(mask, scores, _NEG_INF)
             m_prev = m_scr[h]                # [rows, 1]
             m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -244,7 +255,7 @@ def _decode_kernel_allheads(
             m_scr[h] = m_new
             l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
             acc_scr[h] = alpha * acc_scr[h] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                (p * vs).astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
 
@@ -496,7 +507,7 @@ def chunk_decode_attention(
     :class:`LiveMask` of it) -> [B, K, H, Dh]; k/v [B, S, Hkv, Dh] bf16
     or the int8 cache layout [B, Hkv, S, Dh],
     stacked with ``layer`` (see :func:`decode_attention`).  Same
-    streaming/online-softmax/in-VMEM-dequant design, with an
+    streaming/online-softmax/scale-folding design, with an
     [K*group, Dh] query tile per (batch, kv-head) program — K=4, group=2
     is an 8-row MXU tile, where the prefill flash kernel would pad the
     4 chunk rows to a 128-row query block (32x wasted work).

@@ -1,164 +1,136 @@
 #!/usr/bin/env python
-"""Microbenchmark decode-step components at game shapes, IN-LOOP.
+"""Time the int8 decode-attention kernel alone, IN-LOOP, at the
+benchmark cells' geometries.
 
 Every device call pays a host dispatch latency, so per-call timing is
-latency-floored and meaningless for ops that run inside the decode
-``lax.while_loop``.  Every measurement here runs the
-op N times inside ONE jitted ``fori_loop`` with a serializing data
-dependency, so the reported per-iteration cost is the in-loop cost.
+latency-floored and meaningless for an op that runs inside the decode
+``lax.while_loop``.  Here ``decode_attention`` runs N times inside ONE
+jitted ``fori_loop`` with a serializing data dependency, so the
+reported per-iteration cost is the in-loop cost.
 
-Motivated by round-3: the int8-KV decode loop measured 9.0 ms/step vs
-bf16's 5.1 while carrying ~2/3 the traffic.  Suspects: the Pallas
-kernel's achieved bandwidth, and the quantize+scatter cache writes.
+Geometries: 10 rows, Dh 128, a stacked int8 cache of two layers at
+5,120 slots with the second attended (the layer scan's carry and its
+prefetched index); each row 1,536 slots of left pad, then 2,300 live
+(a decide call's rows on the 4096 prompt rung), handed over as a
+:class:`LiveMask` made outside the loop as the engine makes it once a
+step.
+
+* ``dense``   Hkv 8, group 4  (Qwen3-8B)
+* ``hybrid``  Hkv 30, group 1 (Olmo-Hybrid-7B's full-attention layers)
+* ``sparse``  Hkv 8, group 8  (Solar-Open2's GQA layers)
+
+For each: us a call with the rows live, us a call with no slot live (a
+grid of empty steps: what a step outside a row's range costs), the live
+blocks a call computes (``live_block_count``), us a live block with the
+empty steps taken out, and the live blocks' K/V and scale bytes over
+the call's time against the v5e's 819 GB/s.
 
 Usage (on the TPU):  python scripts/microbench_decode_attention.py
 """
 
 from __future__ import annotations
 
+import argparse
+import json
+import os
+import sys
 import time
-from functools import partial
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bcg_tpu.ops.decode_attention import (
-    chunk_decode_attention,
+    LiveMask,
     decode_attention,
-    quantize_kv,
+    kernel_block,
+    live_block_count,
+    live_slots,
 )
 
-ITERS = 100
+GEOMETRIES = {"dense": (8, 4), "hybrid": (30, 1), "sparse": (8, 8)}
+B, DH, S, LAYERS = 10, 128, 5120, 2
+PAD, LIVE = 1536, 2300
+HBM_BYTES_PER_S = 819e9           # TPU v5e, Google Cloud's published peak
 
 
-def loop_time(make_body, carry0, iters=ITERS):
-    """Time ``iters`` sequential in-loop applications of ``make_body``
-    inside one jit; returns seconds per iteration."""
+def loop_time(body, carry0, iters, repeats):
+    """Best of ``repeats`` timings of ``iters`` sequential in-loop
+    applications of ``body`` inside one jit; seconds per iteration."""
 
     @jax.jit
     def run(carry):
-        return jax.lax.fori_loop(0, iters, make_body, carry)
+        return jax.lax.fori_loop(0, iters, body, carry)
 
-    out = run(carry0)
-    jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    out = run(carry0)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / iters
+    jax.block_until_ready(run(carry0))
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(carry0))
+        best = min(best, time.perf_counter() - t0)
+    return best / iters
+
+
+def measure(name, hkv, group, iters, repeats, seed=0):
+    rng = np.random.default_rng(seed)
+    H = hkv * group
+    cache = (LAYERS, B, hkv, S)
+    k = jnp.asarray(rng.integers(-127, 128, cache + (DH,)), jnp.int8)
+    v = jnp.asarray(rng.integers(-127, 128, cache + (DH,)), jnp.int8)
+    ks = jnp.asarray(10.0 ** rng.uniform(-3, -1, cache), jnp.float32)
+    vs = jnp.asarray(10.0 ** rng.uniform(-3, -1, cache), jnp.float32)
+    q0 = jnp.asarray(rng.standard_normal((B, H, DH)), jnp.bfloat16)
+    slots = np.arange(S)
+    live = np.broadcast_to((slots >= PAD) & (slots < PAD + LIVE), (B, S))
+    layer = jnp.int32(LAYERS - 1)
+
+    def timed(mask):
+        lm = LiveMask(jnp.asarray(mask), live_slots(jnp.asarray(mask)))
+
+        def body(i, carry):
+            acc, q = carry
+            out = decode_attention(q, k, v, lm, DH ** -0.5, k_scale=ks,
+                                   v_scale=vs, layer=layer)
+            acc = acc + out.astype(jnp.float32).mean()
+            return acc, q + (acc * 1e-20).astype(q.dtype)
+
+        return loop_time(body, (jnp.float32(0), q0), iters, repeats)
+
+    t_live = timed(live)
+    t_dead = timed(np.zeros((B, S), bool))
+    block = kernel_block(hkv, DH)
+    grid = B * (S // block)
+    first, last = live_slots(live, xp=np)
+    blocks = live_block_count(first, last, block)
+    per_block = (t_live - t_dead * (grid - blocks) / grid) / blocks
+    nbytes = blocks * hkv * block * (2 * DH + 2 * 4)
+    return {
+        "geometry": name, "hkv": hkv, "group": group, "block": block,
+        "grid_steps": grid, "live_blocks": blocks,
+        "us_per_call": t_live * 1e6,
+        "us_per_call_no_slot_live": t_dead * 1e6,
+        "us_per_empty_step": t_dead / grid * 1e6,
+        "us_per_live_block": per_block * 1e6,
+        "us_per_live_block_and_head": per_block / hkv * 1e6,
+        "hbm_roof_pct": 100.0 * nbytes / HBM_BYTES_PER_S / t_live,
+    }
 
 
 def main():
-    B, H, Hkv, Dh, S = 10, 16, 8, 128, 4096
-    K = 8
-    scale = Dh ** -0.5
-    rng = np.random.default_rng(0)
-    q0 = jnp.asarray(rng.standard_normal((B, H, Dh)), jnp.bfloat16)
-    qk0 = jnp.asarray(rng.standard_normal((B, K, H, Dh)), jnp.bfloat16)
-    k_bf = jnp.asarray(rng.standard_normal((B, S, Hkv, Dh)), jnp.bfloat16)
-    v_bf = jnp.asarray(rng.standard_normal((B, S, Hkv, Dh)), jnp.bfloat16)
-    k_i8 = jnp.asarray(rng.integers(-127, 127, (B, Hkv, S, Dh)), jnp.int8)
-    v_i8 = jnp.asarray(rng.integers(-127, 127, (B, Hkv, S, Dh)), jnp.int8)
-    ks = jnp.asarray(rng.random((B, Hkv, S)) * 0.01 + 0.001, jnp.float32)
-    vs = jnp.asarray(rng.random((B, Hkv, S)) * 0.01 + 0.001, jnp.float32)
-    mask = jnp.asarray(np.ones((B, S), bool))
-    maskk = jnp.asarray(np.ones((B, K, S), bool))
-
-    i8_bytes = 2 * B * Hkv * S * Dh + 2 * B * Hkv * S * 4
-    bf_bytes = 2 * B * S * Hkv * Dh * 2
-    print(f"shapes: B={B} H={H} Hkv={Hkv} Dh={Dh} S={S}; per-step KV "
-          f"traffic int8 {i8_bytes/1e6:.0f} MB, bf16 {bf_bytes/1e6:.0f} MB; "
-          f"{ITERS} in-loop iterations")
-
-    def attn_body(attn_fn):
-        # carry = (acc, q); feed acc back into q so iterations serialize.
-        def body(i, carry):
-            acc, q = carry
-            out = attn_fn(q)
-            acc = acc + out.astype(jnp.float32).mean()
-            q = q + (acc * 1e-20).astype(q.dtype)
-            return (acc, q)
-        return body
-
-    # int8 Pallas kernel across block sizes.
-    for bs in (512, 1024, 2048, 4096):
-        t = loop_time(
-            attn_body(partial(
-                decode_attention, k=k_i8, v=v_i8, mask=mask, scale=scale,
-                k_scale=ks, v_scale=vs, block_s=bs,
-            )),
-            (jnp.float32(0), q0),
-        )
-        print(f"int8 pallas  block={bs:<4d}: {t*1e3:7.3f} ms/it  "
-              f"{i8_bytes/t/1e9:6.1f} GB/s")
-
-    # bf16 einsum reference (the stock decode path).
-    def einsum_path(q):
-        qg = q.reshape(B, Hkv, H // Hkv, Dh)
-        logits = jnp.einsum("bhgd,bshd->bhgs", qg, k_bf).astype(jnp.float32) * scale
-        logits = jnp.where(mask[:, None, None, :], logits, -1e30)
-        p = jax.nn.softmax(logits, axis=-1).astype(v_bf.dtype)
-        return jnp.einsum("bhgs,bshd->bhgd", p, v_bf).reshape(B, H, Dh)
-
-    t = loop_time(attn_body(einsum_path), (jnp.float32(0), q0))
-    print(f"bf16 einsum           : {t*1e3:7.3f} ms/it  {bf_bytes/t/1e9:6.1f} GB/s")
-
-    # int8 einsum-with-dequant (the non-Pallas int8 fallback shape).
-    def dequant_einsum(q):
-        kd = (k_i8.astype(jnp.float32) * ks[..., None]).transpose(0, 2, 1, 3).astype(q.dtype)
-        vd = (v_i8.astype(jnp.float32) * vs[..., None]).transpose(0, 2, 1, 3).astype(q.dtype)
-        qg = q.reshape(B, Hkv, H // Hkv, Dh)
-        logits = jnp.einsum("bhgd,bshd->bhgs", qg, kd).astype(jnp.float32) * scale
-        p = jax.nn.softmax(jnp.where(mask[:, None, None, :], logits, -1e30), axis=-1)
-        return jnp.einsum("bhgs,bshd->bhgd", p.astype(vd.dtype), vd).reshape(B, H, Dh)
-
-    t = loop_time(attn_body(dequant_einsum), (jnp.float32(0), q0))
-    print(f"int8 dequant einsum   : {t*1e3:7.3f} ms/it  {i8_bytes/t/1e9:6.1f} GB/s")
-
-    # int8 chunk kernel (the fast-forward path).
-    def chunk_body(bs):
-        def body(i, carry):
-            acc, qk = carry
-            out = chunk_decode_attention(
-                qk, k_i8, v_i8, maskk, scale, k_scale=ks, v_scale=vs,
-                block_s=bs,
-            )
-            acc = acc + out.astype(jnp.float32).mean()
-            qk = qk + (acc * 1e-20).astype(qk.dtype)
-            return (acc, qk)
-        return body
-
-    for bs in (512, 2048, 4096):
-        t = loop_time(chunk_body(bs), (jnp.float32(0), qk0))
-        print(f"int8 chunk{K} block={bs:<4d}: {t*1e3:7.3f} ms/it  "
-              f"{i8_bytes/t/1e9:6.1f} GB/s")
-
-    # Cache-write paths (per decode step): bf16 = 2 dynamic updates;
-    # int8 = quantize + transpose + 4 updates (k/v/scales).
-    kn = jnp.asarray(rng.standard_normal((B, K, Hkv, Dh)), jnp.bfloat16)
-
-    def bf16_write(i, carry):
-        acc, k_cache, v_cache = carry
-        fresh = kn + (acc * 1e-20).astype(kn.dtype)
-        k_cache = jax.lax.dynamic_update_slice(k_cache, fresh, (0, 0, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, fresh, (0, 0, 0, 0))
-        return (acc + k_cache[0, 0, 0, 0].astype(jnp.float32), k_cache, v_cache)
-
-    t = loop_time(bf16_write, (jnp.float32(0), k_bf, v_bf))
-    print(f"bf16 cache write (K={K}) : {t*1e3:7.3f} ms/it")
-
-    def int8_write(i, carry):
-        acc, kc, vc, ksc, vsc = carry
-        fresh = kn + (acc * 1e-20).astype(kn.dtype)
-        kq, s = quantize_kv(fresh)
-        kc = jax.lax.dynamic_update_slice(kc, kq.transpose(0, 2, 1, 3), (0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, kq.transpose(0, 2, 1, 3), (0, 0, 0, 0))
-        ksc = jax.lax.dynamic_update_slice(ksc, s.transpose(0, 2, 1), (0, 0, 0))
-        vsc = jax.lax.dynamic_update_slice(vsc, s.transpose(0, 2, 1), (0, 0, 0))
-        return (acc + kc[0, 0, 0, 0].astype(jnp.float32), kc, vc, ksc, vsc)
-
-    t = loop_time(int8_write, (jnp.float32(0), k_i8, v_i8, ks, vs))
-    print(f"int8 cache write (K={K}) : {t*1e3:7.3f} ms/it")
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--iters", type=int, default=200)
+    ap.add_argument("--repeats", type=int, default=3)
+    ap.add_argument("--geometry", choices=sorted(GEOMETRIES), action="append")
+    args = ap.parse_args()
+    dev = jax.devices()[0]
+    print(f"device {dev.platform} {dev.device_kind}; B={B} Dh={DH} S={S} "
+          f"pad={PAD} live={LIVE}; {args.iters} in-loop iterations, best "
+          f"of {args.repeats}")
+    for name in args.geometry or list(GEOMETRIES):
+        row = measure(name, *GEOMETRIES[name], args.iters, args.repeats)
+        print(json.dumps(row), flush=True)
 
 
 if __name__ == "__main__":

@@ -299,6 +299,115 @@ def test_blocks_outside_the_range_are_never_read(chunk, stacked, group):
     np.testing.assert_array_equal(dirty, clean)
 
 
+# ------------------------------------- scales on the scores and the weights
+
+
+def _spread_stack(key, layers, B, S, Hkv, Dh):
+    """A stacked int8 cache whose K and V scales are drawn apart per
+    slot and per KV head, log-uniform over 1e-3 to 1: a scale read on
+    the wrong axis or head, or carried into the softmax's denominator,
+    moves the output far past rounding."""
+    kk, kv, ks, vs = jax.random.split(key, 4)
+    shape = (layers, B, Hkv, S, Dh)
+    k, v = (jax.random.randint(x, shape, -127, 128).astype(jnp.int8)
+            for x in (kk, kv))
+    ks, vs = (10.0 ** jax.random.uniform(x, shape[:-1], minval=-3.0, maxval=0.0)
+              for x in (ks, vs))
+    return k, v, ks, vs
+
+
+def _spread_case(chunk, stacked, group, dtype=jnp.float32, seed=41):
+    """``(attend, q, mask, blocks, cache)`` as :func:`_bounded_case`,
+    over :func:`_spread_stack`'s cache and the masks of
+    :func:`_ranged_masks`, with ``q`` in ``dtype``."""
+    from bcg_tpu.ops.decode_attention import chunk_decode_attention
+
+    Hkv, Dh, B = 2, 128, 4
+    mask, blocks = _ranged_masks(chunk)
+    shape = (B, _K, Hkv * group, Dh) if chunk else (B, Hkv * group, Dh)
+    # small queries, so scores of order one and a softmax that is not
+    # one slot's alone
+    q = (0.02 * jax.random.normal(jax.random.PRNGKey(seed), shape)).astype(dtype)
+    k, v, ks, vs = _spread_stack(jax.random.PRNGKey(seed + 1), 2, B, _S, Hkv, Dh)
+    fn = chunk_decode_attention if chunk else decode_attention
+    kw = dict(block_s=_BLK, interpret=True)
+    if stacked:
+        kw["layer"] = jnp.int32(1)
+        cache = (k, v, ks, vs)
+    else:
+        cache = (k[1], v[1], ks[1], vs[1])
+
+    def attend(mask, cache):
+        k, v, ks, vs = cache
+        return np.asarray(fn(q, k, v, mask, 1.0 / np.sqrt(Dh), k_scale=ks,
+                             v_scale=vs, **kw).astype(jnp.float32))
+
+    return attend, q, mask, blocks, cache
+
+
+def _dequantized_reference(q, mask, cache, chunk, stacked):
+    """float32 attention over :func:`dequantize_kv`'s cache, every
+    matmul at ``highest``: the entry the kernel attends (layer 1 of a
+    stack), q [B, (K,) H, Dh] in float32."""
+    k, v, ks, vs = (a[1] if stacked else a for a in cache)
+    k, v = (dequantize_kv(x, sc).transpose(0, 2, 1, 3) for x, sc in ((k, ks), (v, vs)))
+    q = q.astype(jnp.float32)
+    m3 = mask if chunk else mask[:, None, :]
+    with jax.default_matmul_precision("highest"):
+        ref = _xla_attention(q if chunk else q[:, None], k, v, m3,
+                             1.0 / np.sqrt(q.shape[-1]))
+    return np.asarray(ref if chunk else ref[:, 0])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("group", [1, 4, 8, 5])     # 5: rows padded to 8
+@pytest.mark.parametrize("chunk, stacked", _FORMS)
+def test_scales_on_scores_and_weights_are_the_dequantized_attention(
+        chunk, stacked, group, dtype):
+    """The kernel multiplies a slot's K scale into its score and its V
+    scale into its softmax weight, never into the denominator: held to
+    the float32 attention over the dequantised cache at ``highest``,
+    with scales three orders of magnitude apart, slot by slot and head
+    by head.  float32 queries keep the matmuls in float32 (rounding
+    alone is left); bfloat16 ones round the queries, the weights and the
+    output to bfloat16, 2**-8 of a value each."""
+    attend, q, mask, blocks, cache = _spread_case(chunk, stacked, group, dtype)
+    out = attend(mask, cache)
+    ref = _dequantized_reference(q, mask, cache, chunk, stacked)
+    live = [b for b, r in enumerate(blocks) if r]
+    assert not out[2].any()                          # the row with no slot
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out[live], ref[live], rtol=tol,
+                               atol=tol * np.abs(ref[live]).max())
+
+
+@pytest.mark.parametrize("chunk, stacked", _FORMS)
+def test_a_masked_slots_scale_is_never_applied(chunk, stacked):
+    """A masked slot inside a live block (the left pad in row 0's first
+    block, the hole in row 3's range) whose K and V scales read 1e4: the
+    output is bit-equal to the same call with those scales at 1.  The
+    score's mask and the weight's zero hold them out."""
+    attend, _, mask, blocks, cache = _spread_case(chunk, stacked, group=4)
+    k, v, ks, vs = cache
+    rows = np.asarray(mask if not chunk else mask.any(axis=1))
+    inside = np.zeros_like(rows)
+    for b, r in enumerate(blocks):
+        if r:
+            inside[b, r[0] * _BLK: (r[1] + 1) * _BLK] = True
+    masked = jnp.array(inside & ~rows)[:, None, :]    # over the kv heads
+    assert masked[0].any() and masked[3].any()
+
+    def scaled(value):
+        def put(a):
+            return jnp.where(masked, jnp.asarray(value, a.dtype), a)
+        return (k, v, put(ks), put(vs))
+
+    big = attend(mask, scaled(1e4))
+    assert np.isfinite(big).all()
+    np.testing.assert_array_equal(big, attend(mask, scaled(1.0)))
+
+
 @pytest.mark.parametrize("block", [1024, 512])
 @pytest.mark.parametrize("step", [0, 22, 298])
 def test_live_block_range_at_the_cells_shape(step, block):
