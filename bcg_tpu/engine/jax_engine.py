@@ -60,6 +60,7 @@ from bcg_tpu.obs import (
 from bcg_tpu.models.configs import (
     FULL_ATTENTION,
     LARGE_MODEL_PARAMS,
+    RECURRENT_TYPES,
     ModelSpec,
     spec_for_model,
 )
@@ -242,12 +243,12 @@ class JaxEngine(InferenceEngine):
     def _refuse_unbuilt_for_hybrid(self, config, mesh) -> None:
         """A spec with ``layer_types`` keeps two kinds of per-row state
         (K/V in its full-attention layers, recurrent state and conv tail
-        in its delta-rule layers).  What is built for that is the plain
-        path: full or chunked prefill into a dense cache and the
-        one-token decode loop on one device.  Every option that would
-        need a state snapshot, a state rollback, a paged or a sharded
-        form of the second kind raises here, by name; nothing falls
-        back."""
+        in its delta-rule, KDA or state-space layers).  What is built
+        for that is the plain path: full or chunked prefill into a dense
+        cache and the one-token decode loop on one device.  Every option
+        that would need a state snapshot, a state rollback, a paged or a
+        sharded form of the second kind raises here, by name; nothing
+        falls back."""
         from bcg_tpu.runtime.envflags import get_str
 
         kv = (get_str("BCG_TPU_KV_DTYPE") or str(config.kv_cache_dtype)).strip().lower()
@@ -268,8 +269,10 @@ class JaxEngine(InferenceEngine):
              "recurrent state has no sharded form)"),
         ) if on]
         if unbuilt:
+            kinds = sorted({k for k in self.spec.layer_types if k in RECURRENT_TYPES})
             raise ValueError(
-                f"{self.spec.name} is a hybrid (layer_types) spec; not built "
+                f"{self.spec.name} is a hybrid (layer_types) spec with "
+                f"recurrent layers ({', '.join(kinds)}); not built "
                 "for it: " + "; ".join(unbuilt))
 
     def __init__(self, config, mesh=None, params=None, spec: Optional[ModelSpec] = None):
@@ -846,17 +849,19 @@ class JaxEngine(InferenceEngine):
         # jit entry points (shape-polymorphic via jax.jit's trace cache).
         self._prefill_impl = self._kernel_impl(self.attention_impl)
         if self.spec.hybrid:
-            from bcg_tpu.ops import HybridImpl, gated_delta, moe
+            from bcg_tpu.ops import HybridImpl, gated_delta, moe, selective_scan
 
             # One switch for the Pallas kernels: where flash prefill
-            # runs, the chunkwise delta-rule kernel and the grouped
-            # expert matmul run; elsewhere their XLA twins (the same
-            # chunk mathematics as a scan; every held expert, weighted).
+            # runs, the chunkwise delta-rule kernel, the grouped expert
+            # matmul and the selective-scan kernel run; elsewhere their
+            # XLA twins (the same chunk mathematics as a scan; every held
+            # expert, weighted; the scan over positions).
             kernels = self.attention_impl == "pallas"
             self._prefill_impl = HybridImpl(
                 self._prefill_impl,
                 gated_delta.PALLAS if kernels else gated_delta.XLA,
                 moe.PALLAS if kernels else moe.XLA,
+                selective_scan.PALLAS if kernels else selective_scan.XLA,
             )
         # Named after their obs_hlo census entries: every program of
         # the prefill family carries "prefill" in the profiler's trace.

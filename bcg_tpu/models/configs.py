@@ -25,19 +25,26 @@ expert, the top ``experts_per_token`` of them renormalised, a shared
 expert, and only the experts this chip HOLDS (``experts_held``, experts
 ``0 .. experts_held - 1``) computed here: the chip's share of an
 expert-parallel layer, with no exchange for the rest.
+
+A hybrid's layers may also be ``"mamba"``: a Mamba-1 state-space layer
+(``ops/selective_scan.py``): an inner stream of ``mamba_expand *
+hidden_size`` channels through a causal depthwise conv, a selective
+scan whose state is ``mamba_d_state`` numbers a channel, and a SiLU
+gate (the ``mamba_*`` fields).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Optional, Tuple, Union
+from typing import ClassVar, Dict, Optional, Tuple, Union
 
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
 KDA = "kda"
-LAYER_TYPES = (FULL_ATTENTION, LINEAR_ATTENTION, KDA)
+MAMBA = "mamba"
+LAYER_TYPES = (FULL_ATTENTION, LINEAR_ATTENTION, KDA, MAMBA)
 # layer types whose per-row state is a recurrent state and a conv tail
-RECURRENT_TYPES = (LINEAR_ATTENTION, KDA)
+RECURRENT_TYPES = (LINEAR_ATTENTION, KDA, MAMBA)
 
 
 # Parameter count at/above which single-chip serving needs the memory
@@ -113,6 +120,17 @@ class ModelSpec:
     shared_experts: int = 0
     norm_topk_prob: bool = False
     routed_scaling_factor: float = 1.0
+    # A Mamba layer's sizes: state numbers a channel, conv taps, inner
+    # width over the hidden size, rank of the step-size projection.
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
+    # What a Mamba layer is built with, not a choice: a bias on the conv
+    # and none on the in/out projections (a configuration's file that
+    # states otherwise is refused against these).
+    mamba_conv_bias: ClassVar[bool] = True
+    mamba_proj_bias: ClassVar[bool] = False
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -135,6 +153,12 @@ class ModelSpec:
             raise ValueError(
                 f"{self.name}: the delta-rule layer is built for as many key "
                 "heads as value heads")
+        if MAMBA in self.layer_types and not (
+                self.mamba_d_state > 0 and self.mamba_d_conv > 1
+                and self.mamba_expand > 0 and self.mamba_dt_rank > 0):
+            raise ValueError(
+                f"{self.name}: a mamba layer needs mamba_d_state, mamba_d_conv > 1, "
+                "mamba_expand and mamba_dt_rank")
 
     @property
     def hybrid(self) -> bool:
@@ -149,6 +173,16 @@ class ModelSpec:
             if len(types) % n == 0 and types == types[:n] * (len(types) // n):
                 return types[:n]
         return types
+
+    @property
+    def attn_layer_period(self) -> int:
+        """Layers from one full-attention layer to the next (a period's)."""
+        return len(self.layer_period) if self.hybrid else 1
+
+    @property
+    def attn_layer_offset(self) -> int:
+        """Index of a period's full-attention layer."""
+        return self.layer_period.index(FULL_ATTENTION) if self.hybrid else 0
 
     @property
     def moe(self) -> bool:
@@ -184,6 +218,11 @@ class ModelSpec:
         return 2 * self.linear_key_size + self.linear_value_size
 
     @property
+    def mamba_inner(self) -> int:
+        """Channels of a Mamba layer's inner stream."""
+        return self.mamba_expand * self.hidden_size
+
+    @property
     def q_size(self) -> int:
         return self.num_heads * self.head_dim
 
@@ -206,6 +245,10 @@ class ModelSpec:
         else:
             mlp = {"w_gate": (D, F), "w_up": (D, F), "w_down": (F, D)}
         K, V = self.linear_key_size, self.linear_value_size
+        if kind == MAMBA:
+            Di, R, N = self.mamba_inner, self.mamba_dt_rank, self.mamba_d_state
+            return {"ssm_in": (D, 2 * Di), "ssm_x": (Di, R + 2 * N),
+                    "ssm_dt": (R, Di), "ssm_out": (Di, D), **mlp}
         if kind == KDA:
             r = self.kda_gate_rank
             return {"lin_wq": (D, K), "lin_wk": (D, K), "lin_wv": (D, V),
@@ -343,6 +386,27 @@ def _solar_open2(name: str, num_layers: int, experts_held: int,
     )
 
 
+def _jamba2(name: str, num_layers: int, hidden_size: int, heads: int,
+            intermediate_size: int, vocab_size: int, max_position: int) -> ModelSpec:
+    """AI21-Jamba2-3B's public ``config.json`` (``model_type: "jamba"``):
+    Mamba-1 layers with full attention at every layer ``i % 14 == 7``
+    (``attn_layer_offset`` 7, ``attn_layer_period`` 14), no rotary
+    embedding, one KV head, a dense SwiGLU in every layer
+    (``num_experts`` 1), pre-norm, tied embeddings; a Mamba layer's
+    state 16 a channel, conv 4 with a bias, inner width twice the
+    hidden size, step-size rank ``hidden_size / 16``."""
+    return ModelSpec(
+        name=name, vocab_size=vocab_size, hidden_size=hidden_size,
+        num_layers=num_layers, num_heads=heads, num_kv_heads=1,
+        head_dim=hidden_size // heads, intermediate_size=intermediate_size,
+        rope_theta=None, tie_embeddings=True, max_position=max_position,
+        layer_types=tuple(FULL_ATTENTION if i % 14 == 7 else MAMBA
+                          for i in range(num_layers)),
+        mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
+        mamba_dt_rank=-(-hidden_size // 16),
+    )
+
+
 MODEL_SPECS: Dict[str, ModelSpec] = {
     # Qwen3 dense family (HF config.json values).
     "Qwen/Qwen3-8B": ModelSpec(
@@ -393,6 +457,8 @@ MODEL_SPECS: Dict[str, ModelSpec] = {
     "allenai/Olmo-Hybrid-7B": _olmo_hybrid("allenai/Olmo-Hybrid-7B", 65536),
     "upstage/Solar-Open2-250B": _solar_open2(
         "upstage/Solar-Open2-250B", 48, 320, 196608, 1048576),
+    "ai21labs/AI21-Jamba2-3B": _jamba2(
+        "ai21labs/AI21-Jamba2-3B", 28, 2560, 20, 8192, 65536, 262144),
     # Hermetic HF-artifact specs (models/hf_fixture.py): loaded through
     # the REAL checkpoint pipeline — AutoTokenizer + safetensors shards +
     # config.json on local disk — with random weights.  `tiny` proves the
@@ -498,6 +564,16 @@ MODEL_SPECS: Dict[str, ModelSpec] = {
         num_experts=16, experts_held=8, experts_per_token=4,
         moe_intermediate_size=32, shared_experts=1, norm_topk_prob=True,
     ),
+    # AI21-Jamba2-3B whole, with random weights and the 8,192-position
+    # limit: 26 Mamba layers and 2 of full attention; bfloat16: 6.1 GB
+    # of weights, per row 9.3 MB of float32 scan state and conv tail.
+    "bcg-tpu/bench-jamba2-3b": _jamba2(
+        "bcg-tpu/bench-jamba2-3b", 28, 2560, 20, 8192, 65536, 8192),
+    # One period of that pattern (13 Mamba layers, attention at 7) at
+    # tiny widths, the published state, conv and expansion: the CPU
+    # tests' stand-in for the spec above.
+    "bcg-tpu/tiny-jamba": _jamba2(
+        "bcg-tpu/tiny-jamba", 14, 64, 4, 128, 512, 2048),
     # Qwen3-14B / 32B dims with random weights: the reference's larger
     # presets (config.py:20-25) as hermetic multi-chip TP targets —
     # int8 14B (~15 GB) needs tp>=2 on 16 GB chips, 32B tp>=4.  Shard

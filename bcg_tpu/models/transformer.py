@@ -27,9 +27,10 @@ states ``layer_types`` — a block per layer type from ``_HYBRID_BLOCKS``
 for the full layers, recurrent state and conv tail for the linear
 ones), and a layer scan over the PERIODS of the pattern.  A hybrid's
 mixers are the full attention (with an output gate where the spec has
-one), the scalar-gated delta rule and the channel-gated one (KDA); its
-MLP half is the SwiGLU or routed experts with a shared one
-(``ops/moe.py``: the chip computes the experts it holds).
+one), the scalar-gated delta rule, the channel-gated one (KDA) and the
+Mamba-1 state-space mixer (``ops/selective_scan.py``); its MLP half is
+the SwiGLU or routed experts with a shared one (``ops/moe.py``: the chip
+computes the experts it holds).
 
 Replaces the CUDA side of the reference's engine (vLLM internals behind
 ``vllm_agent.py:100-157``); no reference code exists at this layer.
@@ -45,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from bcg_tpu.models.configs import (
-    FULL_ATTENTION, KDA, LINEAR_ATTENTION, RECURRENT_TYPES, ModelSpec,
+    FULL_ATTENTION, KDA, LINEAR_ATTENTION, MAMBA, RECURRENT_TYPES, ModelSpec,
 )
 from bcg_tpu.models.quantize import dense
 from bcg_tpu.obs import scopes
@@ -58,7 +59,8 @@ TransformerParams = Dict  # pytree: see init_params for the layout
 # ----------------------------------------------------------------- building
 
 # Leaf kinds that draw from a key (see init_leaf); the rest are constants.
-RANDOM_KINDS = ("dense", "attn_out", "gate", "conv", "a_log", "dt_bias")
+RANDOM_KINDS = ("dense", "attn_out", "gate", "conv", "a_log", "dt_bias",
+                "tied_embed", "ssm_dt_bias")
 # The matrices a leaf transform (the int8 or int4 quantiser) takes.
 MATRIX_KINDS = ("dense", "attn_out")
 
@@ -167,10 +169,15 @@ def _hybrid_param_plan(spec: ModelSpec):
     taps, ``A_log``, ``dt_bias`` and the per-head output norm), a KDA
     layer's the channel-gated rule's (q, k, v, beta, the low-rank decay
     and output-gate pairs, conv, ``A_log`` per head, ``dt_bias`` per
-    channel, the output norm and projection)."""
+    channel, the output norm and projection), a Mamba layer's the
+    selective scan's (in-projection, conv taps and bias, x-projection,
+    the norms on the step size, B and C, the step-size projection and
+    its bias, ``A_log`` [channels, state], ``D``, out-projection).  A
+    spec with tied embeddings draws its embedding at the head's scale
+    (``tied_embed``)."""
     D = spec.hidden_size
     plan = [
-        ("embed", "dense", (spec.vocab_size, D)),
+        ("embed", "tied_embed" if spec.tie_embeddings else "dense", (spec.vocab_size, D)),
         ("final_norm", "hybrid_final_norm", (D,)),
     ]
     H = spec.linear_num_value_heads
@@ -202,6 +209,22 @@ def _hybrid_param_plan(spec: ModelSpec):
                 dense_leaf("kda_ga"), dense_leaf("kda_gb"),
                 (pre + "lin_out_norm", "ones", (spec.linear_value_head_dim,)),
                 dense_leaf("lin_wo"),
+            ]
+        elif kind == MAMBA:
+            Di, N, R = spec.mamba_inner, spec.mamba_d_state, spec.mamba_dt_rank
+            plan += [
+                dense_leaf("ssm_in"),
+                (pre + "ssm_conv", "conv", (spec.mamba_d_conv, Di)),
+                (pre + "ssm_conv_bias", "conv", (Di,)),
+                dense_leaf("ssm_x"),
+                (pre + "ssm_dt_norm", "ones", (R,)),
+                (pre + "ssm_b_norm", "ones", (N,)),
+                (pre + "ssm_c_norm", "ones", (N,)),
+                dense_leaf("ssm_dt"),
+                (pre + "ssm_dt_bias", "ssm_dt_bias", (Di,)),
+                (pre + "ssm_a_log", "ssm_a_log", (Di, N)),
+                (pre + "ssm_d", "ones", (Di,)),
+                dense_leaf("ssm_out"),
             ]
         else:
             plan += [dense_leaf(n) for n in ("wq", "wk", "wv", "wg") if n in shapes]
@@ -255,7 +278,14 @@ def init_leaf(kind: str, shape, key: jax.Array, dtype) -> jax.Array:
     RMS 8 nearly every decay is 0, and a rounding error of 0.02 in the
     pre-activation moves ``g`` by 16 times that (measured: the W8A8
     model's greedy tokens then lie up to 2.3 below the float32
-    reference's best, against 4.4 for int4 weights)."""
+    reference's best, against 4.4 for int4 weights).
+
+    A Mamba layer's own, Mamba-1's initialisation: ``ssm_a_log`` = log 1,
+    ..., N along the state axis in every channel (float32), and
+    ``ssm_dt_bias`` the inverse softplus of a step size log-uniform in
+    [0.001, 0.1].  ``tied_embed`` (an embedding that is also the head):
+    normal / sqrt(hidden size), so that the logits have the untied
+    head's scale."""
     if kind == "dense":
         return (
             jax.random.normal(key, shape, jnp.float32) / math.sqrt(shape[-2])
@@ -277,6 +307,15 @@ def init_leaf(kind: str, shape, key: jax.Array, dtype) -> jax.Array:
     if kind == "dt_bias":
         dt = jax.random.uniform(key, shape, jnp.float32, 0.001, 0.1)
         return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    if kind == "tied_embed":
+        return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(shape[-1])).astype(dtype)
+    if kind == "ssm_dt_bias":
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(0.001), math.log(0.1)))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    if kind == "ssm_a_log":   # float32 whatever the served dtype
+        return jnp.broadcast_to(
+            jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)), shape)
     if kind in _CONSTANT_KINDS:
         return jnp.full(shape, _CONSTANT_KINDS[kind], dtype)
     raise ValueError(f"unknown init kind {kind!r}")
@@ -473,6 +512,8 @@ def layer_kind(layer: Dict) -> str:
     """A layer's type, told from its leaves."""
     if "kda_fa" in layer:
         return KDA
+    if "ssm_in" in layer:
+        return MAMBA
     return LINEAR_ATTENTION if "lin_wq" in layer else FULL_ATTENTION
 
 
@@ -1097,31 +1138,42 @@ def _block_full(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
     return x + _norm_out(spec, y, layer["attn_norm"]), new_entry
 
 
+def _causal_conv(u: jax.Array, taps: jax.Array, tail: jax.Array,
+                 valid: Optional[jax.Array], bias: Optional[jax.Array] = None):
+    """The depthwise causal conv of a recurrent mixer, then silu: channel
+    ``c`` of position ``t`` is ``silu(sum_i taps[i, c] u[t - K + 1 + i, c]
+    (+ bias[c]))`` over its ``K`` taps, the positions before the chunk
+    read from ``tail`` [B, K-1, C] (the last K - 1 inputs so far, zeros
+    before a sequence starts).  A pad position's input is zero.  Returns
+    the output [B, T, C] float32 and the new tail, in the tail's dtype."""
+    T, f32 = u.shape[1], jnp.float32
+    if valid is not None:
+        u = jnp.where(valid[..., None], u, 0)
+    window = jnp.concatenate([tail, u.astype(tail.dtype)], axis=1)
+    taps = taps.astype(f32)                                        # [K, C]
+    y = sum(taps[i] * window[:, i:i + T].astype(f32) for i in range(taps.shape[0]))
+    if bias is not None:
+        y = y + bias.astype(f32)
+    return jax.nn.silu(y), window[:, T:]
+
+
 def _short_conv(layer: Dict, spec: ModelSpec, h: jax.Array, entry: Dict, ctx: _Ctx):
     """A delta-rule mixer's q, k, v: the three projections through the
-    depthwise causal conv of ``K`` taps and silu, q and k L2-normalised
-    per head and q scaled by ``dk^-1/2``.  A pad position's conv input
-    is zero.  Returns q, k [B, T, H, dk], v [B, T, H, dv] (float32) and
-    the conv's window (its last K - 1 inputs are the new tail)."""
+    depthwise causal conv (:func:`_causal_conv`, no bias), q and k
+    L2-normalised per head and q scaled by ``dk^-1/2``.  Returns q, k
+    [B, T, H, dk], v [B, T, H, dv] (float32) and the conv's new tail."""
     B, T, _ = h.shape
     H = spec.linear_num_value_heads
     dk, dv = spec.linear_key_head_dim, spec.linear_value_head_dim
-    K = spec.linear_conv_kernel_dim
-    f32 = jnp.float32
     u = jnp.concatenate(
         [dense(h, layer["lin_wq"]), dense(h, layer["lin_wk"]),
          dense(h, layer["lin_wv"])], axis=-1)                     # [B, T, C]
-    if ctx.valid is not None:
-        u = jnp.where(ctx.valid[..., None], u, 0)
-    window = jnp.concatenate([entry["conv"], u.astype(entry["conv"].dtype)], axis=1)
-    taps = layer["lin_conv"].astype(f32)                           # [K, C]
-    y = sum(taps[i] * window[:, i:i + T].astype(f32) for i in range(K))
-    y = jax.nn.silu(y)
+    y, tail = _causal_conv(u, layer["lin_conv"], entry["conv"], ctx.valid)
     q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
     q, k = q.reshape(B, T, H, dk), k.reshape(B, T, H, dk)
     v = v.reshape(B, T, H, dv)
     unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)  # noqa: E731
-    return unit(q) * dk ** -0.5, unit(k), v, window
+    return unit(q) * dk ** -0.5, unit(k), v, tail
 
 
 @jax.named_scope(scopes.LINEAR_MIXER)
@@ -1143,7 +1195,7 @@ def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
     H, dv = spec.linear_num_value_heads, spec.linear_value_head_dim
     f32 = jnp.float32
     h = _norm_in(spec, x, layer["attn_norm"])
-    q, k, v, window = _short_conv(layer, spec, h, entry, ctx)
+    q, k, v, tail = _short_conv(layer, spec, h, entry, ctx)
 
     gate = lambda w: jnp.dot(h, w, preferred_element_type=f32)    # noqa: E731  [B, T, H]
     beta = jax.nn.sigmoid(gate(layer["lin_wb"]))
@@ -1161,7 +1213,7 @@ def _block_gated_delta(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
         o, S = gated_delta_prefill(
             q.astype(x.dtype), k.astype(x.dtype), v.astype(x.dtype), g, beta,
             entry["S"], impl=ctx.impl.delta)
-    new_entry = {"S": S, "conv": window[:, T:]}
+    new_entry = {"S": S, "conv": tail}
 
     o = rms_norm(o.astype(x.dtype), layer["lin_out_norm"], spec.rms_eps)
     o = o * jax.nn.silu(dense(h, layer["lin_wg"])).reshape(B, T, H, dv)
@@ -1185,7 +1237,7 @@ def _block_kda(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
                  spec.linear_value_head_dim)
     f32 = jnp.float32
     h = _norm_in(spec, x, layer["attn_norm"])
-    q, k, v, window = _short_conv(layer, spec, h, entry, ctx)
+    q, k, v, tail = _short_conv(layer, spec, h, entry, ctx)
 
     def low_rank(a, b):       # [B, T, out] float32 through the rank-r pair
         return jnp.dot(jnp.dot(h, a, preferred_element_type=f32), b.astype(f32))
@@ -1205,7 +1257,7 @@ def _block_kda(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
         o, S = kda_prefill(
             q.astype(x.dtype), k.astype(x.dtype), v.astype(x.dtype), g, beta,
             entry["S"], impl=ctx.impl.delta)
-    new_entry = {"S": S, "conv": window[:, T:]}
+    new_entry = {"S": S, "conv": tail}
 
     o = rms_norm(o.astype(x.dtype), layer["lin_out_norm"], spec.rms_eps)
     gate = jax.nn.sigmoid(low_rank(layer["kda_ga"], layer["kda_gb"]))
@@ -1214,12 +1266,56 @@ def _block_kda(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
     return x + _norm_out(spec, y, layer["attn_norm"]), new_entry
 
 
+@jax.named_scope(scopes.LINEAR_MIXER)
+def _block_mamba(layer: Dict, spec: ModelSpec, x: jax.Array, entry: Dict,
+                 ctx: _Ctx) -> Tuple[jax.Array, Dict]:
+    """A hybrid's Mamba-1 mixer (``ops/selective_scan.py``).  Its cache
+    entry is the scan state ``S`` [B, N, channels] float32 and the conv
+    tail ``conv`` [B, K-1, channels].  ``[u, z] = h W_in``; ``u`` through
+    the conv with its bias and silu; ``[d, B, C] = u W_x``, each RMS-normed
+    by its own vector; the step size ``delta = softplus(d W_dt + b_dt)``;
+    the scan's output ``y`` gated by ``silu(z)`` and projected by
+    ``W_out``.  The discretisation and the scan run under the free
+    sub-scope ``scan``.  A pad position leaves the state and the tail as
+    they were: its conv input is zero and its step size 0."""
+    from bcg_tpu.ops.selective_scan import mamba_prefill, mamba_step
+
+    R, N = spec.mamba_dt_rank, spec.mamba_d_state
+    f32 = jnp.float32
+    h = _norm_in(spec, x, layer["attn_norm"])
+    u, z = jnp.split(dense(h, layer["ssm_in"]), 2, axis=-1)
+    u, tail = _causal_conv(u, layer["ssm_conv"], entry["conv"], ctx.valid,
+                           layer["ssm_conv_bias"])
+    with jax.named_scope("scan"):
+        dbc = jnp.dot(u.astype(x.dtype), layer["ssm_x"], preferred_element_type=f32)
+        d, Bm, Cm = jnp.split(dbc, [R, R + N], axis=-1)
+        d = rms_norm(d, layer["ssm_dt_norm"], spec.rms_eps)
+        Bm = rms_norm(Bm, layer["ssm_b_norm"], spec.rms_eps)
+        Cm = rms_norm(Cm, layer["ssm_c_norm"], spec.rms_eps)
+        delta = jax.nn.softplus(
+            jnp.dot(d.astype(x.dtype), layer["ssm_dt"], preferred_element_type=f32)
+            + layer["ssm_dt_bias"].astype(f32))
+        A = -jnp.exp(layer["ssm_a_log"].astype(f32))                # [channels, N]
+        if ctx.valid is None:       # one decoded token: the recurrence itself
+            y, S = mamba_step(u[:, 0], delta[:, 0], Bm[:, 0], Cm[:, 0], A,
+                              layer["ssm_d"], entry["S"])
+            y = y[:, None]
+        else:
+            delta = jnp.where(ctx.valid[..., None], delta, 0.0)
+            y, S = mamba_prefill(u, delta, Bm, Cm, A, layer["ssm_d"], entry["S"],
+                                 impl=ctx.impl.ssm)
+    y = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype)
+    out = dense(y, layer["ssm_out"])
+    return x + _norm_out(spec, out, layer["attn_norm"]), {"S": S, "conv": tail}
+
+
 # Layer type -> its mixer, and the MLP half -> its sublayer.  A further
 # mechanism adds a function and an entry.
 _HYBRID_BLOCKS = {
     FULL_ATTENTION: _block_full,
     LINEAR_ATTENTION: _block_gated_delta,
     KDA: _block_kda,
+    MAMBA: _block_mamba,
     "swiglu": _mlp_sublayer,
     "moe": _moe_sublayer,
 }
@@ -1420,7 +1516,7 @@ def _logits(params: TransformerParams, spec: ModelSpec, x: jax.Array) -> jax.Arr
         return dense(h, params["lm_head"], out_dtype=jnp.float32)
     if not spec.tie_embeddings:
         raise KeyError(f"params for untied model {spec.name!r} lack 'lm_head'")
-    return (h @ params["embed"].T).astype(jnp.float32)
+    return jnp.dot(h, params["embed"].T, preferred_element_type=jnp.float32)
 
 
 def init_kv_cache(
@@ -1449,7 +1545,8 @@ def init_kv_cache(
     A spec with ``layer_types`` gets K/V entries for its
     full-attention layers only and, for each linear layer, ``{"S":
     [B, H, dv, dk] float32, "conv": [B, K-1, channels]}`` (zeros: the
-    state before a sequence starts).  Stacked, its cache is ``{kind:
+    state before a sequence starts); a Mamba layer's ``S`` is [B, N,
+    channels].  Stacked, its cache is ``{kind:
     leaves [layers of that kind, ...]}``; :func:`cache_bytes` counts
     either form by kind of state.
 
@@ -1497,7 +1594,16 @@ def init_kv_cache(
                             spec.linear_conv_size), dtype),
             }
 
-        make = {FULL_ATTENTION: entry, LINEAR_ATTENTION: linear, KDA: linear}
+        def mamba(lead=()):
+            return {
+                "S": jnp.zeros(lead + (batch, spec.mamba_d_state, spec.mamba_inner),
+                               jnp.float32),
+                "conv": jnp.zeros(
+                    lead + (batch, spec.mamba_d_conv - 1, spec.mamba_inner), dtype),
+            }
+
+        make = {FULL_ATTENTION: entry, LINEAR_ATTENTION: linear, KDA: linear,
+                MAMBA: mamba}
         if stacked:
             return {kind: make[kind](lead=(spec.layers_of(kind),))
                     for kind in dict.fromkeys(spec.layer_period)}

@@ -15,12 +15,13 @@ bcg.layers/while/body/bcg.attn/dot_general`` is attention.  So
 ``bcg.layers`` holds only what the layer scan does outside the three
 sublayers, ``bcg.head`` only what a model call does outside the layers,
 and ``bcg.sample`` only what the decode loop does outside the model.
-Scopes below a part (``bcg.ffn/router``) are free; nothing reads them.
+Scopes below a part (``bcg.ffn/router``, ``bcg.linear_mixer/scan``) are
+free; nothing reads them.
 
 | Part | What it holds |
 | --- | --- |
 | ``bcg.attn`` | a full-attention mixer: pre-norm, q/k/v, q/k norms, rope, the output gate, the cache write, the flash or int8 decode kernel, the output projection |
-| ``bcg.linear_mixer`` | a gated delta-rule or KDA mixer: projections, short conv, chunk kernel or one-token update, output norm and gate, and its state's slice from and write back to the layer scan's carry (the one-token update fuses into the write) |
+| ``bcg.linear_mixer`` | a gated delta-rule, KDA or Mamba mixer: projections, short conv, chunk kernel or one-token update, output norm and gate, and its state's slice from and write back to the layer scan's carry (the one-token update fuses into the write); a Mamba mixer's x-projection, its norms, the step size's projection and softplus (the discretisation) and the selective scan (``mamba_prefill`` or the one-token update) under the free sub-scope ``scan`` |
 | ``bcg.ffn`` | SwiGLU or routed experts with the shared one, with its pre-norm |
 | ``bcg.layers`` | the layer scan outside the sublayers: weight slices, carries |
 | ``bcg.head`` | a model call outside the layers: embedding, rope tables, masks, final norm, logits |

@@ -24,13 +24,15 @@ class PallasTP(NamedTuple):
 class HybridImpl(NamedTuple):
     """``impl`` marker of a model with two kinds of layer: what its
     full-attention layers run (any marker above, or a plain name), what
-    its delta-rule layers run (an ``ops.gated_delta`` marker) and what
-    its routed experts run (an ``ops.moe`` marker).  Resolved by the
-    engine at boot, like the rest."""
+    its delta-rule layers run (an ``ops.gated_delta`` marker), what its
+    routed experts run (an ``ops.moe`` marker) and what its state-space
+    layers' chunk scan runs (an ``ops.selective_scan`` marker).
+    Resolved by the engine at boot, like the rest."""
 
     attention: object
     delta: str
     moe: str = "xla"
+    ssm: str = "xla"
 
 
 def is_pallas(impl) -> bool:

@@ -30,7 +30,7 @@ def cell_engine_options():
     changed, so a tier-1 engine runs the code path the chip runs."""
     import json
 
-    from bcg_tpu.models.configs import spec_for_model
+    from bcg_tpu.models.configs import MAMBA, spec_for_model
 
     configs = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -46,6 +46,7 @@ def cell_engine_options():
             "prefill_chunk": 64,
             "model_name": (
                 "bcg-tpu/tiny-solar" if spec.moe
+                else "bcg-tpu/tiny-jamba" if spec.layers_of(MAMBA)
                 else "bcg-tpu/tiny-hybrid" if spec.hybrid else "bcg-tpu/tiny-test"
             ),
         }

@@ -1,6 +1,6 @@
 """Every device operation of the engine's programs names the model part
-it belongs to (``bcg_tpu/obs/scopes.py``): the tiny dense, hybrid and
-Solar engines under their cells' options, their chunk program and
+it belongs to (``bcg_tpu/obs/scopes.py``): the tiny dense, hybrid,
+Solar and Jamba engines under their cells' options, their chunk program and
 decode loop compiled on the CPU and read as the device trace reads
 them, by the innermost ``bcg.*`` segment of each operation's
 ``op_name``.  ``tests/test_tpu_compile.py::TestKernelParts`` holds the
@@ -20,7 +20,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 from lib import scopes as lib_scopes  # noqa: E402
 
-CELLS = ("qwen3-8b-int8", "olmo-hybrid-7b-int8", "solar-open2-250b-int8")
+CELLS = ("qwen3-8b-int8", "olmo-hybrid-7b-int8", "solar-open2-250b-int8", "jamba2-3b-bf16")
 KERNEL_OPS = ("fusion", "custom-call", "dot", "convolution")
 
 # instruction name and opcode

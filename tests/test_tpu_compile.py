@@ -218,6 +218,56 @@ class TestOneChip:
         ).as_text()
         assert f"f32[{B},{heads},{T},{d}]" in text   # how trace_names finds it
 
+    def test_mamba_prefill_chunk(self, one):
+        # One 512-token prefill chunk of a Mamba layer at Jamba2-3B's
+        # widths: 5,120 channels, state 16 a channel, float32 state.
+        from bcg_tpu.ops import selective_scan
+
+        C, N, T = 5120, 16, 512
+
+        def chunk(u, delta, Bm, Cm, A, D, S):
+            return selective_scan.mamba_prefill(u, delta, Bm, Cm, A, D, S,
+                                                impl=selective_scan.PALLAS)
+
+        text = _compile(
+            chunk, one,
+            ((B, T, C), jnp.float32), ((B, T, C), jnp.float32),
+            ((B, T, N), jnp.float32), ((B, T, N), jnp.float32),
+            ((C, N), jnp.float32), ((C,), jnp.float32), ((B, N, C), jnp.float32),
+        ).as_text()
+        assert list(_kernels(text)) == ["mamba_prefill"]
+        # the [T, N, C] expansion of the discretised A and B is never made
+        assert f"f32[{B},{T},{N},{C}]" not in text and f"f32[{B},{T},{C},{N}]" not in text
+
+    @pytest.mark.parametrize("phase", ["flash", "decode"])
+    def test_mqa_group_of_20(self, one, phase):
+        # Jamba2-3B's attention: 20 query heads on one KV head of 128.
+        # Flash prefill takes the whole group's query block in each grid
+        # step; the decode kernel pads the group's 20 rows to 32.
+        from bcg_tpu.ops.decode_attention import LiveMask, decode_attention
+
+        hq, hkv, T, s_cache = 20, 1, 512, 5120
+        if phase == "flash":
+            text = _compile(
+                _flash, one,
+                ((B, T, hq, DH), jnp.bfloat16), ((B, 2048 + T, hkv, DH), jnp.bfloat16),
+                ((B, 2048 + T, hkv, DH), jnp.bfloat16), ((B, T, 2048 + T), jnp.bool_),
+            ).as_text()
+            assert f"bf16[{B},{hq},{T},{DH}]" in text
+            return
+
+        def stacked(q, k, v, ks, vs, mask, layer, slots):
+            return decode_attention(q, k, v, LiveMask(mask, slots), SCALE,
+                                    k_scale=ks, v_scale=vs, layer=layer)
+
+        kv = ((2, B, hkv, s_cache, DH), jnp.int8)
+        sc = ((2, B, hkv, s_cache), jnp.float32)
+        text = _compile(
+            stacked, one, ((B, hq, DH), jnp.bfloat16), kv, kv, sc, sc,
+            ((B, s_cache), jnp.bool_), ((), jnp.int32), ((2, B), jnp.int32),
+        ).as_text()
+        assert f"bf16[{B},{hkv},32,{DH}]" in text
+
     @pytest.mark.parametrize("decode", [False, True], ids=["grouped", "decode"])
     def test_expert_kernels(self, one, decode):
         # The held experts of Solar-Open2's share, 40 of 320 (top 8),
@@ -318,7 +368,8 @@ class TestKernelParts:
     def one(self, topo):
         return SingleDeviceSharding(topo.devices[0])
 
-    @pytest.mark.parametrize("kernel", ["flash_prefill", "decode_attention", "guided_sampler"])
+    @pytest.mark.parametrize("kernel", ["flash_prefill", "decode_attention", "guided_sampler",
+                                        "mamba_prefill"])
     def test_named_kernels_keep_their_trace_patterns(self, one, kernel):
         import json
         import sys
@@ -340,6 +391,15 @@ class TestKernelParts:
             text = _compile(
                 _decode_int8, one, *_int8_cache_shapes(((B, H, DH), jnp.bfloat16)),
                 ((B, S_CACHE), jnp.bool_)).as_text()
+        elif kernel == "mamba_prefill":
+            from bcg_tpu.ops import selective_scan
+
+            C, N, T = 5120, 16, 512
+            text = _compile(
+                functools.partial(selective_scan.mamba_prefill, impl=selective_scan.PALLAS),
+                one, ((B, T, C), jnp.float32), ((B, T, C), jnp.float32),
+                ((B, T, N), jnp.float32), ((B, T, N), jnp.float32),
+                ((C, N), jnp.float32), ((C,), jnp.float32), ((B, N, C), jnp.float32)).as_text()
         else:
             _, vs = gs.vocab_rows(VOCAB)
             text = _compile(
@@ -353,12 +413,14 @@ class TestKernelParts:
                     if re.match(rf"\s*(ROOT\s+)?%{kernel}(\.\d+)? = ", ln))
         event = trace.op_name(line)
         assert event.startswith(kernel)
-        for name in ("qwen3-8b-int8", "olmo-hybrid-7b-int8", "solar-open2-250b-int8"):
+        for name in ("qwen3-8b-int8", "olmo-hybrid-7b-int8", "solar-open2-250b-int8",
+                     "jamba2-3b-bf16"):
             with open(os.path.join(bench, "configs", name + ".json")) as f:
                 patterns = json.load(f)["trace_names"]
-            found = [k for k in ("flash_prefill", "decode_attention")
-                     if re.search(patterns[k], event)]
-            assert found == ([] if kernel == "guided_sampler" else [kernel]), (name, event)
+            found = [k for k in ("flash_prefill", "decode_attention", "mamba_prefill")
+                     if k in patterns and re.search(patterns[k], event)]
+            assert found == ([] if kernel == "guided_sampler"
+                             else [kernel] if kernel in patterns else []), (name, event)
 
     # (preset, layer index, phase) -> the kernels the layer calls
     CASES = {
@@ -369,9 +431,13 @@ class TestKernelParts:
         ("bcg-tpu/tiny-solar", 0, "prefill"): {"flash_prefill", "moe_prefill"},
         ("bcg-tpu/tiny-solar", 1, "prefill"): {"kda_prefill", "moe_prefill"},
         ("bcg-tpu/tiny-solar", 0, "decode"): {"decode_attention", "moe_decode"},
+        ("bcg-tpu/tiny-jamba", 0, "prefill"): {"mamba_prefill"},
+        ("bcg-tpu/tiny-jamba", 7, "prefill"): {"flash_prefill"},
+        ("bcg-tpu/tiny-jamba", 7, "decode"): {"decode_attention"},
     }
     PART = {"flash_prefill": "bcg.attn", "decode_attention": "bcg.attn",
             "gated_delta_prefill": "bcg.linear_mixer", "kda_prefill": "bcg.linear_mixer",
+            "mamba_prefill": "bcg.linear_mixer",
             "moe_prefill": "bcg.ffn", "moe_decode": "bcg.ffn"}
 
     @pytest.mark.parametrize("case", list(CASES), ids=lambda c: f"{c[0][8:]}-{c[1]}-{c[2]}")
@@ -386,7 +452,7 @@ class TestKernelParts:
         from bcg_tpu.models.configs import MODEL_SPECS
         from bcg_tpu.models.quantize import quantize_leaf_transform
         from bcg_tpu.obs import scopes
-        from bcg_tpu.ops import HybridImpl, gated_delta, moe
+        from bcg_tpu.ops import HybridImpl, gated_delta, moe, selective_scan
 
         preset, li, phase = case
         spec = dataclasses.replace(
@@ -400,8 +466,10 @@ class TestKernelParts:
         cache = jax.eval_shape(lambda: T.init_kv_cache(spec, rows, S, quantized="int8"))
         layer, entry = params["layers"][li], cache[li]
         mask_shape = (rows, S) if phase == "decode" else (rows, Tq, hist + Tq)
-        impl = (HybridImpl("pallas", "xla" if phase == "decode" else gated_delta.PALLAS,
-                           moe.PALLAS) if spec.hybrid else "pallas")
+        prefill_phase = phase == "prefill"
+        impl = (HybridImpl("pallas", gated_delta.PALLAS if prefill_phase else "xla", moe.PALLAS,
+                           selective_scan.PALLAS if prefill_phase else "xla")
+                if spec.hybrid else "pallas")
 
         def run(layer, x, entry, mask, valid):
             if not spec.hybrid:
